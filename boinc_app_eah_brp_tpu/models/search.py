@@ -296,7 +296,7 @@ def bank_params_host(P, tau, psi0, dt) -> tuple[np.ndarray, ...]:
 
 # sentinel below any real summed power: padded batch slots are masked to
 # this before the block reduction so they can never claim a bin
-NEG_SENTINEL = jnp.float32(-3.0e38)
+NEG_SENTINEL = np.float32(-3.0e38)
 
 # bank device arrays are padded to at least this capacity so the compiled
 # step's input shapes (and the persistent-cache key) are stable across
@@ -824,13 +824,13 @@ def bank_step_layouts(geom: SearchGeometry, with_health: bool, device):
     both sides of the donation makes every window executable agree, so
     the buffers alias through unchanged.  Chip-free verifiable: the
     layouts compile against a deviceless TPU topology
-    (tests/test_pallas_sumspec.py)."""
-    from jax.experimental.layout import DeviceLocalLayout, Layout
+    (tests/test_tpu_compile.py)."""
+    from jax.experimental.layout import Format, Layout
     from jax.sharding import SingleDeviceSharding
 
     sh = SingleDeviceSharding(device)
-    v1 = Layout(DeviceLocalLayout(major_to_minor=(0,)), sh)
-    m2 = Layout(DeviceLocalLayout(major_to_minor=(0, 1)), sh)
+    v1 = Format(Layout(major_to_minor=(0,)), sh)
+    m2 = Format(Layout(major_to_minor=(0, 1)), sh)
     ts = tuple(v1 for _ in range(2 if geom.parity_split else 1))
     in_sh = [ts, v1, v1, v1, v1, sh, sh, m2, m2]
     if geom.exact_mean:

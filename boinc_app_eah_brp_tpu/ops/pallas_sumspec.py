@@ -225,7 +225,7 @@ def sumspec_pallas_batch(
     planes = pl.pallas_call(
         lambda *refs: _fold_kernel_body(harm_hi, refs),
         grid=(T, n_tiles),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[

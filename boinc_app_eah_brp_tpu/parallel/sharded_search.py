@@ -34,7 +34,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.search import (
     ExactMeanPrefetch,
@@ -51,25 +51,7 @@ from ..runtime import watchdog as hangdog
 from ..runtime.devicecost import stage_scope
 from .mesh import TEMPLATE_AXIS
 
-_NEG = jnp.float32(-3.0e38)  # sentinel below any real summed power
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-spanning shard_map: new-style ``jax.shard_map(...,
-    check_vma=...)`` when present, else the experimental module's
-    ``check_rep=`` spelling (same semantics: the ppermute butterfly yields
-    replicated outputs the checker can't prove)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
+_NEG = np.float32(-3.0e38)  # sentinel below any real summed power
 
 
 def _merge_take(oM, oT, M, T):
@@ -194,8 +176,11 @@ def make_sharded_batch_step(
     if geom.exact_mean:
         in_specs += [P(axis_name), P(axis_name)]  # n_steps, mean
     out_specs = (P(), P(), P()) if with_health else (P(), P())
-    sharded = _shard_map(
-        local_step, mesh, tuple(in_specs), out_specs
+    # check_vma off: the ppermute butterfly yields replicated outputs the
+    # checker can't prove
+    sharded = jax.shard_map(
+        local_step, mesh=mesh, in_specs=tuple(in_specs),
+        out_specs=out_specs, check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(7, 8))
 
@@ -321,6 +306,10 @@ def _run_bank_sharded_attempt(
     params = bank_params_host(bank_P, bank_tau, bank_psi0, geom.dt)
     faultinject.fault_point("h2d", loop="run_bank_sharded")
     dev_bank = upload_bank(params, B)
+    # the series, bank and state live replicated on every mesh device
+    # (P() specs): placed once here, not re-sent from device 0 per step
+    rep = NamedSharding(mesh, P())
+    ts_args, dev_bank, M, T = jax.device_put((ts_args, dev_bank, M, T), rep)
     n_total = jnp.int32(n_stop)
     lookahead = max(1, int(lookahead))
     starts = range(start_template, n_stop, B)

@@ -282,10 +282,6 @@ def main(argv: list[str] | None = None) -> int:
     parsed = parse_args(argv)
     if isinstance(parsed, int):
         return parsed
-    # after arg parsing so --help/bad-flag paths never pay the jax import
-    from .jaxenv import honor_jax_platforms
-
-    honor_jax_platforms()
     # Exit-code contract with the native wrapper (native/erp_wrapper.cpp):
     # code 1 (RADPUL_EMEM) means out-of-memory and triggers a temporary-exit
     # retry backoff — so a genuine OOM must map to it, and *no other* failure

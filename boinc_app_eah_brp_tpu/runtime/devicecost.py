@@ -157,7 +157,7 @@ def stage_time_model(
     from .roofline import _CHIPS, chip_generation, pipeline_costs
 
     gen = chip or chip_generation()
-    peak, bw = _CHIPS.get(gen, _CHIPS["v5e"])
+    peak, bw = _CHIPS[gen]
     # roofline stage name -> registry scope carrying its traffic
     scope_of = {
         "resample_split": "resample",
@@ -305,6 +305,28 @@ class ProfilerRecords:
         return bool(self.records)
 
 
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HLO_NAME_RE = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+)")
+
+
+def hlo_op_scopes(module_text: str) -> dict[str, str]:
+    """HLO instruction name -> its ``op_name`` metadata, for every
+    instruction of a compiled module (``compiled.as_text()``) whose
+    op_name holds a registered scope.  A TPU profile names each device
+    event after the HLO instruction it ran (``%fusion.42 = ...``) and
+    carries no op metadata (seen on a v5e, PR 21), so this map is what
+    attributes those events to their stage (:func:`stage_records`)."""
+    out: dict[str, str] = {}
+    for line in module_text.splitlines():
+        if " = " not in line:
+            continue
+        src = _OP_NAME_RE.search(line)
+        if src is None or stage_of_op_name(src.group(1)) is None:
+            continue
+        out[_HLO_NAME_RE.match(line).group(1)] = src.group(1)
+    return out
+
+
 def decode_profile_planes(data) -> list[dict]:
     """Best-effort decode of a ``jax.profiler.ProfileData`` object into
     plain plane dicts ``[{name, lines: [{name, events: [{name, start_ns,
@@ -331,6 +353,10 @@ def decode_profile_planes(data) -> list[dict]:
     return planes
 
 
+def is_device_plane(name: str) -> bool:
+    return "device" in name.lower() or "TPU" in name
+
+
 def parse_plane_dicts(planes: list[dict]) -> list[dict]:
     """Pure parse of decoded xplane plane dicts into normalized device
     records in ``tracing.add_device_records`` form.
@@ -345,7 +371,7 @@ def parse_plane_dicts(planes: list[dict]) -> list[dict]:
     records: list[dict] = []
     for plane in planes:
         pname = str(plane.get("name", ""))
-        if "device" not in pname.lower() and "TPU" not in pname:
+        if not is_device_plane(pname):
             continue
         for line in plane.get("lines", []) or []:
             lane = f"device:{line.get('name') or pname}"
@@ -373,16 +399,26 @@ def parse_plane_dicts(planes: list[dict]) -> list[dict]:
     return records
 
 
-def stage_records(records: list[dict], lane: str = "device:measured") -> list[dict]:
+def stage_records(
+    records: list[dict],
+    lane: str = "device:measured",
+    op_scopes: dict[str, str] | None = None,
+) -> list[dict]:
     """Fold raw profiler device records into per-STAGE measured records:
     events whose op name resolves through :func:`stage_of_op_name` are
     renamed to their ``erp.<stage>`` scope and moved onto ``lane`` (the
     measured counterpart of the ``device:estimated`` roofline lane);
     unattributed events are dropped — the raw records still carry them.
-    Pure record construction, no jax."""
+    An event named after an HLO instruction (the TPU's) resolves through
+    ``op_scopes`` (:func:`hlo_op_scopes`).  Pure record construction,
+    no jax."""
     out = []
     for r in records:
-        stage = stage_of_op_name(r.get("name"))
+        name = r.get("name") or ""
+        op = name
+        if op_scopes:
+            op = op_scopes.get(_HLO_NAME_RE.match(name).group(1), name)
+        stage = stage_of_op_name(op)
         if stage is None:
             continue
         out.append(
@@ -406,7 +442,8 @@ def collect_profiler_device_records(logdir: str) -> ProfilerRecords:
     :func:`parse_plane_dicts` over the decoded planes.
 
     Returns a :class:`ProfilerRecords`; every failure mode (ProfileData
-    unavailable, no protos, unreadable file, decode error) sets
+    unavailable, no protos, unreadable file, decode error, no device
+    events in the decoded planes) sets
     ``warning`` and logs it instead of silently returning ``[]`` —
     a missing profile should be diagnosable, not invisible."""
     import glob as _glob
@@ -442,7 +479,17 @@ def collect_profiler_device_records(logdir: str) -> ProfilerRecords:
         planes = decode_profile_planes(data)
     except Exception as e:
         return _warn(f"failed to decode xplane proto {path!r}: {e}", path)
-    return ProfilerRecords(records=parse_plane_dicts(planes), path=path)
+    records = parse_plane_dicts(planes)
+    if not records:
+        names = [p["name"] for p in planes]
+        what = (
+            "its device planes hold no events"
+            if any(is_device_plane(n) for n in names)
+            else "it holds no device plane"
+        )
+        return _warn(f"xplane {path!r} decoded but {what} (planes: {names})",
+                     path)
+    return ProfilerRecords(records=records, path=path)
 
 
 # ---------------------------------------------------------------------------

@@ -87,148 +87,54 @@ class DriverArgs:
     metrics_file: str | None = None
 
 
-def _host_fingerprint() -> str:
-    """Short stable id of this host's CPU capability set.
-
-    XLA's CPU cache entries are AOT-compiled against the *build* host's
-    machine features, and its loader only warns (not rejects) on
-    mismatch: a cache written on an AVX-512 box and read on a lesser one
-    "could lead to execution errors such as SIGILL" (cpu_aot_loader
-    warning, observed live when this repo's user cache migrated
-    containers). Keying the default cache path by the feature set makes
-    a migrated/cloned home directory start a fresh cache instead."""
-    import hashlib
-    import platform
-
-    feats = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                # x86 reports "flags", aarch64 reports "Features"
-                if line.startswith(("flags", "Features")):
-                    feats = " ".join(sorted(line.split(":", 1)[1].split()))
-                    break
-    except OSError:
-        pass
-    # NOTE: the raw flag list includes kernel/microcode-dependent entries
-    # (mitigation flags), so a kernel update can rotate the fingerprint
-    # and cold-start the cache.  That trade is deliberate — a spurious
-    # recompile is minutes, a SIGILL from a stale AOT entry kills the
-    # worker — and enable_compilation_cache prunes rotated-out dirs.
-    key = f"{platform.machine()}|{feats}"
-    return hashlib.sha1(key.encode()).hexdigest()[:10]
+_REPO = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
 def default_cache_dir() -> str:
-    """Default persistent-cache location (XDG layout), keyed by host
-    capability so AOT entries never migrate across machine types."""
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache"
-    )
-    return os.path.join(base, "eah_brp_tpu", f"xla-cache-{_host_fingerprint()}")
+    """The persistent compilation cache's one fixed home when
+    ``JAX_COMPILATION_CACHE_DIR`` is unset: ``<repo>/.erp_cache/xla``
+    (git-ignored).  The path is part of the cache key, so it never
+    moves with the host or the run."""
+    return os.path.join(_REPO, ".erp_cache", "xla")
 
 
-_PRUNE_GRACE_S = 24 * 3600
-
-
-def _prune_stale_caches(current: str) -> None:
-    """Remove sibling ``xla-cache*`` dirs whose fingerprint is not this
-    host's (incl. the legacy unsuffixed dir): their CPU AOT entries were
-    compiled for a different capability set and risk SIGILL if ever
-    pointed at again, and fingerprint rotations would otherwise leak
-    cache dirs without bound.
-
-    Guard rails (ADVICE r04): only dirs matching the generated
-    fingerprint FORMAT (``xla-cache-<10 hex>``, or the legacy bare
-    ``xla-cache``) are candidates — a process whose explicit
-    ``ERP_COMPILATION_CACHE`` happens to live under the same parent with
-    a different name is never touched — and dirs written to within the
-    last 24 h are skipped: a still-running worker started before a
-    kernel update (old fingerprint) keeps its live cache until it has
-    plausibly exited."""
-    import re
-    import shutil
-    import time
-
-    parent = os.path.dirname(current)
-    keep = os.path.basename(current)
-    try:
-        entries = os.listdir(parent)
-    except OSError:
-        return
-    for name in entries:
-        if name == keep:
-            continue
-        if not re.fullmatch(r"xla-cache(-[0-9a-f]{10})?", name):
-            continue
-        path = os.path.join(parent, name)
-        try:
-            if time.time() - os.path.getmtime(path) < _PRUNE_GRACE_S:
-                erplog.debug(
-                    "Keeping recently used stale cache %s (grace window)\n",
-                    name,
-                )
-                continue
-            shutil.rmtree(path)
-            erplog.debug("Pruned stale compilation cache %s\n", name)
-        except OSError:
-            pass
+def compilation_cache_dir() -> str | None:
+    """Where the persistent cache lives: None under
+    ``ERP_COMPILATION_CACHE=off``, else ``$JAX_COMPILATION_CACHE_DIR`` or
+    :func:`default_cache_dir`."""
+    if os.environ.get("ERP_COMPILATION_CACHE", "").strip().lower() == "off":
+        return None
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or default_cache_dir()
 
 
 def enable_compilation_cache() -> None:
-    """Point JAX's persistent compilation cache at $ERP_COMPILATION_CACHE.
+    """Turn on JAX's persistent compilation cache.
 
     The FFTW-wisdom analogue (``create_wisdomf_eah_brp.sh``): the costly
     artifact here is the XLA compilation of the batched search step; with
     the cache warm (``tools/create_wisdom.py``) worker start-up skips the
-    minutes-long compile.  The reference treats wisdom as mandatory
-    deployment plumbing, so the cache is ON by default (at
-    ``~/.cache/eah_brp_tpu/xla-cache-<host-fingerprint>`` or under
-    ``$XDG_CACHE_HOME``); set ``ERP_COMPILATION_CACHE=off`` to opt out,
-    or to a path to relocate it.  When the default location is used,
-    sibling ``xla-cache*`` dirs from rotated-out fingerprints (kernel
-    update, migrated home dir) are pruned so stale AOT entries neither
-    accumulate nor get loaded.
+    minutes-long compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    reads it itself and no directory is set here; otherwise the cache is
+    at :func:`default_cache_dir`.  ``ERP_COMPILATION_CACHE=off`` opts out.
     """
-    cache = os.environ.get("ERP_COMPILATION_CACHE")
-    if cache is not None and cache.strip().lower() in ("off", "none", "0"):
+    cache = compilation_cache_dir()
+    if cache is None:
         erplog.debug("XLA compilation cache disabled by request.\n")
         return
-    if not cache:
-        cache = default_cache_dir()
-        _prune_stale_caches(cache)
     import jax
 
-    try:
-        os.makedirs(cache, exist_ok=True)
-    except OSError as e:
-        # cache trouble must never take down the search — run cold instead
-        erplog.warn("Compilation cache unavailable (%s); running cold.\n", e)
-        return
-    jax.config.update("jax_compilation_cache_dir", cache)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        try:
+            os.makedirs(cache, exist_ok=True)
+        except OSError as e:
+            # cache trouble must never take down the search — run cold
+            erplog.warn("Compilation cache unavailable (%s); running cold.\n", e)
+            return
+        jax.config.update("jax_compilation_cache_dir", cache)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    global _active_cache_dir
-    _active_cache_dir = cache
-    touch_active_cache()  # liveness mark: see _prune_stale_caches
     erplog.debug("XLA compilation cache: %s\n", cache)
-
-
-_active_cache_dir: str | None = None
-
-
-def touch_active_cache() -> None:
-    """Refresh the active cache dir's mtime.  The prune grace window
-    keys on dir mtime, which cache READS never update — a long-running
-    worker that stopped compiling would look abandoned after 24 h and a
-    newer-fingerprint process could delete its live cache.  Called at
-    enable time and from the session's checkpoint path, so any live
-    worker re-marks its cache at checkpoint cadence (minutes)."""
-    if _active_cache_dir is None:
-        return
-    try:
-        os.utime(_active_cache_dir, None)
-    except OSError:
-        pass
 
 
 def run_search(args: DriverArgs, adapter: BoincAdapter | None = None) -> int:

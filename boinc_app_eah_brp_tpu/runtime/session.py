@@ -682,9 +682,6 @@ class Session:
         )
 
         def checkpoint_now(n_done: int, M_now, T_now) -> None:
-            from .driver import touch_active_cache
-
-            touch_active_cache()  # keep the live cache out of prune's reach
             if not allow_global_ckpt:
                 return
             if not args.checkpointfile and rescorer is None:

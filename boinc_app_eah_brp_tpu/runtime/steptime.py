@@ -417,12 +417,16 @@ class ProfileCapture:
 
 
 @contextmanager
-def capture_profile(logdir: str, lane: str = "device:measured"):
+def capture_profile(
+    logdir: str, lane: str = "device:measured", op_scopes: dict | None = None
+):
     """First-class device-profiling orchestrator: ``jax.profiler``
     start/stop around the with-block (N dispatch windows), xplane parse
     into per-stage *measured* device records via the
     ``devicecost.stage_of_op_name`` registry, merged into the Chrome
-    export as ``lane`` alongside the estimated one.
+    export as ``lane`` alongside the estimated one.  On a TPU, whose
+    events are named after HLO instructions, pass ``op_scopes``
+    (``devicecost.hlo_op_scopes`` of the profiled executable's text).
 
     Yields a :class:`ProfileCapture` filled on exit.  Chip-free runs
     yield an empty capture with ``warning`` set (the CPU backend's
@@ -447,7 +451,9 @@ def capture_profile(logdir: str, lane: str = "device:measured"):
         cap.warning = cap.warning or parsed.warning
         if cap.warning:
             erplog.warn("steptime.capture_profile: %s\n", cap.warning)
-        cap.stage_records = devicecost.stage_records(cap.records, lane=lane)
+        cap.stage_records = devicecost.stage_records(
+            cap.records, lane=lane, op_scopes=op_scopes
+        )
         for r in cap.stage_records:
             stage = r["args"].get("stage")
             cap.stage_ms[stage] = round(

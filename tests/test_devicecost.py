@@ -22,7 +22,7 @@ import cost_ledger  # noqa: E402
 import metrics_report  # noqa: E402
 import trace_report  # noqa: E402
 
-# hlo_attrib calls force_cpu_reexec() at import, which exports
+# hlo_attrib calls use_cpu_backend() at import, which exports
 # ERP_FORCE_CASCADE=1 for the AOT tools' sake; restore the test
 # process's env so the whiten/fft native-path tests keep their meaning
 _cascade = os.environ.get("ERP_FORCE_CASCADE")
@@ -493,6 +493,52 @@ def test_stage_records_attribution():
     assert all(r["args"]["measured"] is True for r in staged)
     # timing carries through untouched
     assert staged[0]["dur_us"] == 400.0
+
+
+def test_tpu_op_events_attribute_through_the_executable_text():
+    """A TPU names each device event after the HLO instruction it ran,
+    with no op metadata (as a v5e showed, PR 21): the compiled module's
+    text maps the instruction to its erp.* scope."""
+    module = "\n".join([
+        "%fused_computation.8 (p: f32[8]) -> f32[8] {",
+        '  ROOT %sine.1 = f32[8]{0} sine(f32[8]{0} %p), '
+        'metadata={op_name="jit(step)/erp.power/sin"}',
+        "}",
+        '  %fusion.42 = f32[8]{0:T(256)} fusion(f32[8]{0} %a), kind=kLoop, '
+        'calls=%fused_computation.8, metadata={op_name="jit(step)/erp.fft/dot" '
+        'source_file="ops/fft.py"}',
+        '  %copy.3 = f32[8]{0} copy(f32[8]{0} %b), '
+        'metadata={op_name="jit(step)/transpose"}',
+    ])
+    scopes = devicecost.hlo_op_scopes(module)
+    assert scopes == {"sine.1": "jit(step)/erp.power/sin",
+                      "fusion.42": "jit(step)/erp.fft/dot"}
+    recs = [
+        {"name": n, "ts_us": 0.0, "dur_us": 1.0, "end_us": 1.0}
+        for n in ("%fusion.42 = f32[8]{0:T(256)} fusion(f32[8]{0} %a), "
+                  "kind=kLoop, calls=%fused_computation.8",
+                  "%copy.3 = f32[8]{0} copy(f32[8]{0} %b)", "sine.1")
+    ]
+    staged = devicecost.stage_records(recs, op_scopes=scopes)
+    assert [r["args"]["stage"] for r in staged] == ["fft", "power"]
+    assert devicecost.stage_records(recs) == []
+
+
+def test_hlo_op_scopes_of_a_compiled_scoped_function():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def f(x):
+        with devicecost.stage_scope("fft"):
+            y = jnp.sin(x) * 2.0
+        with devicecost.stage_scope("merge"):
+            return jnp.maximum(y, x)
+
+    text = f.lower(jnp.ones(64)).compile().as_text()
+    stages = {devicecost.stage_of_op_name(v)
+              for v in devicecost.hlo_op_scopes(text).values()}
+    assert stages == {"fft", "merge"}
 
 
 def test_collect_profiler_device_records_typed_empty_on_failure(tmp_path):

@@ -2,7 +2,8 @@
 interpret-mode bit-parity against the production XLA path
 (ops/harmonic.py), end-to-end goldens against the CPU oracle at the
 existing tolerances, the ERP_PALLAS_SUMSPEC / ERP_PRECISION gating
-contracts, layout pinning (zero recompiles across dispatch windows),
+contracts, layout pinning (zero recompiles across dispatch windows; the
+v5e compile of the pinned step is in tests/test_tpu_compile.py),
 and named-scope attribution (the kernel's bytes must land under
 erp.sumspec, not "compiler-generated")."""
 
@@ -319,76 +320,6 @@ def test_run_bank_pallas_fallback_is_byte_identical(monkeypatch):
         resilience._run_policy = None  # don't leak spent budget
     np.testing.assert_array_equal(np.asarray(M), np.asarray(M_ref))
     np.testing.assert_array_equal(np.asarray(T), np.asarray(T_ref))
-
-
-@pytest.mark.slow  # deviceless topology init + Mosaic compile: minutes
-def test_layout_pinned_bank_step_compiles_for_tpu_topology(monkeypatch):
-    """Chip-free verification of the TPU layout pinning: the donated,
-    layout-pinned bank step — with the REAL Mosaic fold kernel, not
-    interpret mode — compiles against a deviceless v5e topology, and the
-    executable's I/O layouts honor the pinned row-major orders (so the
-    (M, T) buffers alias through every dispatch window unchanged)."""
-    monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
-    monkeypatch.setenv("ERP_PALLAS_INTERPRET", "0")
-    try:
-        from jax.experimental import topologies
-
-        td = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-        devs = td.devices if not callable(
-            getattr(td, "devices", None)
-        ) else td.devices()
-    except Exception as e:  # no libtpu on this host
-        pytest.skip(f"deviceless TPU topology unavailable: {e}")
-    dev = devs[0]
-
-    from boinc_app_eah_brp_tpu.models.search import (
-        bank_params_host,
-        init_state,
-        prepare_ts,
-        upload_bank,
-    )
-
-    geom = _tiny_geom()
-    B = 4
-    params = tuple(np.zeros(8, np.float32) for _ in range(4))
-    bp = upload_bank(params, batch_size=B)
-    ts_args = prepare_ts(geom, np.zeros(4096, np.float32))
-    M, T = init_state(geom)
-
-    def ab(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(
-                np.shape(a), np.asarray(a).dtype
-            ),
-            tree,
-        )
-
-    fn = make_bank_step(geom, batch_size=B).__wrapped__
-    in_sh, out_sh = bank_step_layouts(geom, with_health=False, device=dev)
-    comp = (
-        jax.jit(
-            fn,
-            donate_argnums=(7, 8),
-            in_shardings=in_sh,
-            out_shardings=out_sh,
-        )
-        .lower(
-            ab(ts_args),
-            *ab(bp),
-            jax.ShapeDtypeStruct((), np.int32),
-            jax.ShapeDtypeStruct((), np.int32),
-            *ab((M, T)),
-        )
-        .compile()
-    )
-    assert "erp.sumspec" in comp.as_text()
-    in_l, _ = comp.input_layouts
-    out_l = comp.output_layouts
-    # the donated (M, T) operands and the step results agree: row-major
-    for lay in (in_l[7], in_l[8], out_l[0], out_l[1]):
-        assert lay.device_local_layout.major_to_minor == (0, 1)
 
 
 # --- named-scope attribution -------------------------------------------------

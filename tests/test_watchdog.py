@@ -322,6 +322,33 @@ def test_strip_supervised_flag():
     )
 
 
+def test_supervised_parent_stays_off_the_device(tmp_path):
+    """The ``--supervised`` parent may import JAX but must never start a
+    backend: it would hold the chip, and the worker it re-execs would then
+    fail or hang on it.  The real parent runs one worker pass (a worker
+    that exits on a missing input) and then reports its backend state."""
+    import os
+    import subprocess
+
+    code = (
+        "import sys\n"
+        "from boinc_app_eah_brp_tpu.runtime import cli\n"
+        "rc = cli.main(['--supervised', '0', '-i', sys.argv[1], '-o', "
+        "sys.argv[2], '-t', sys.argv[1]])\n"
+        "from jax._src import xla_bridge\n"
+        "print('RC', rc, 'BACKEND', xla_bridge.backends_are_initialized())\n"
+    )
+    r = subprocess.run(
+        [sys.executable, "-c", code, str(tmp_path / "absent.bin4"),
+         str(tmp_path / "out.cand")],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, ERP_SUPERVISE_BACKOFF_S="0"),
+    )
+    last = r.stdout.strip().splitlines()[-1]
+    assert last.startswith("RC ") and not last.startswith("RC 0 "), r.stderr
+    assert last.endswith("BACKEND False"), last
+
+
 def test_self_cmd_reexecs_this_package():
     cmd = supervise.self_cmd(["-i", "wu", "-o", "out"])
     assert cmd[0] == sys.executable

@@ -42,14 +42,13 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from _aot_common import (  # noqa: E402
     PRODUCTION_BANK,
-    REPO,
     compile_step,
-    force_cpu_reexec,
     production_geometry,
     topology_devices,
+    use_cpu_backend,
 )
 
-force_cpu_reexec()
+use_cpu_backend()
 
 _DT = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1, "u8": 1,
        "s8": 1, "f16": 2, "s64": 8, "u64": 8, "f64": 8}
@@ -116,7 +115,7 @@ def layout_hotspots(module_text: str, top: int = 20):
 def main() -> int:
     ap = argparse.ArgumentParser(prog="aot_analyze")
     ap.add_argument("--batch", type=int, default=32)
-    ap.add_argument("--topology", default=None)
+    ap.add_argument("--topology", default="v5e:2x2")
     ap.add_argument("--json", default=None)
     ap.add_argument("--hlo-out", default=None)
     ap.add_argument("--nsamples", type=int, default=1 << 22)
@@ -124,14 +123,8 @@ def main() -> int:
     ap.add_argument("--bank", default=PRODUCTION_BANK)
     args = ap.parse_args()
 
-    from boinc_app_eah_brp_tpu.runtime.jaxenv import honor_jax_platforms
-
-    honor_jax_platforms()
     from boinc_app_eah_brp_tpu.runtime.driver import enable_compilation_cache
 
-    os.environ.setdefault(
-        "ERP_COMPILATION_CACHE", os.path.join(REPO, ".erp_cache")
-    )
     enable_compilation_cache()
 
     devs = topology_devices(args.topology)

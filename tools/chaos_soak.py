@@ -117,8 +117,6 @@ def child_env(work: str, fault_spec: str | None) -> dict:
             # checkpoint after every batch: maximizes kill/resume coverage
             "ERP_CHECKPOINT_PERIOD": "0",
             "ERP_LOOKAHEAD": "1",
-            # shared warm cache so every resume skips the XLA compile
-            "ERP_COMPILATION_CACHE": os.path.join(work, "xla-cache"),
             "ERP_RESULT_DATE": RESULT_DATE,
             # generous budget: the p-triggered EIO faults also hit retries
             "ERP_RETRY_BUDGET": "16",

@@ -177,7 +177,6 @@ def main(argv: list[str] | None = None) -> int:
     env_base.update(
         {
             "JAX_PLATFORMS": "cpu",
-            "ERP_COMPILATION_CACHE": os.path.join(work, "jit-cache"),
             "ERP_RESULT_DATE": RESULT_DATE,
             "PYTHONPATH": REPO + os.pathsep + env_base.get("PYTHONPATH", ""),
         }
@@ -203,9 +202,6 @@ def main(argv: list[str] | None = None) -> int:
         # before anything imports jax
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         os.environ["ERP_RESULT_DATE"] = RESULT_DATE
-        os.environ.setdefault(
-            "ERP_COMPILATION_CACHE", os.path.join(work, "jit-cache")
-        )
         server = fb.ServerBackend(name="fabric-ref")
         print("fabric-soak: compute backend = server (in-process fleet tier)")
     t0 = time.monotonic()

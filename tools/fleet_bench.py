@@ -263,9 +263,6 @@ def main(argv: list[str] | None = None) -> int:
     os.environ["ERP_RESULT_DATE"] = RESULT_DATE
     work = args.workdir or tempfile.mkdtemp(prefix="erp-fleet-bench-")
     os.makedirs(work, exist_ok=True)
-    os.environ.setdefault(
-        "ERP_COMPILATION_CACHE", os.path.join(work, "jit-cache")
-    )
     # measured-time observatory ON by default (runtime/steptime.py +
     # serving/slo.py): the byte-identity and zero-recompile gates below
     # then double as proof that measuring is free of numeric effect, and

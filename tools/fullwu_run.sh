@@ -19,8 +19,6 @@ WRAPPER=$REPO/native/build/erp_wrapper
 mkdir -p "$OUT"
 cd "$OUT"
 export PYTHONPATH="${PYTHONPATH:-}:$REPO"
-# warm-start across the three runs (wisdom analogue, repo-local cache)
-export ERP_COMPILATION_CACHE="${ERP_COMPILATION_CACHE:-$REPO/.erp_cache}"
 if [ "${ERP_FULLWU_PLATFORM:-}" = "cpu" ]; then export JAX_PLATFORMS=cpu; fi
 
 run_wrapper() { # $1=out $2=cp $3=log   (call in a subshell: it execs)

@@ -32,7 +32,6 @@ cd "$OUT"
 export PYTHONPATH="${PYTHONPATH:-}:$REPO"
 export JAX_PLATFORMS=cpu
 export XLA_FLAGS="--xla_force_host_platform_device_count=$NDEV ${XLA_FLAGS:-}"
-export ERP_COMPILATION_CACHE="${ERP_COMPILATION_CACHE:-$REPO/.erp_cache_meshcpu}"
 
 S0=$(date +%s)
 python -m boinc_app_eah_brp_tpu \

@@ -51,14 +51,13 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from _aot_common import (  # noqa: E402
     PRODUCTION_BANK,
-    REPO,
     compile_step,
-    force_cpu_reexec,
     production_geometry,
     topology_devices,
+    use_cpu_backend,
 )
 
-force_cpu_reexec()
+use_cpu_backend()
 
 from aot_analyze import shape_bytes  # noqa: E402
 from boinc_app_eah_brp_tpu.runtime.devicecost import (  # noqa: E402
@@ -245,14 +244,8 @@ def render(doc: dict) -> str:
 
 
 def build_artifact(args) -> dict:
-    from boinc_app_eah_brp_tpu.runtime.jaxenv import honor_jax_platforms
-
-    honor_jax_platforms()
     from boinc_app_eah_brp_tpu.runtime.driver import enable_compilation_cache
 
-    os.environ.setdefault(
-        "ERP_COMPILATION_CACHE", os.path.join(REPO, ".erp_cache")
-    )
     enable_compilation_cache()
 
     geom, derived = production_geometry(
@@ -298,7 +291,7 @@ def main() -> int:
         help="deviceless TPU topology compile (default) or the local CPU "
         "backend (the chip-free CI gate)",
     )
-    ap.add_argument("--topology", default=None)
+    ap.add_argument("--topology", default="v5e:2x2")
     ap.add_argument("--nsamples", type=int, default=1 << 22)
     ap.add_argument("--tsample-us", type=float, default=65.476)
     ap.add_argument("--bank", default=PRODUCTION_BANK)

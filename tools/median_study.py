@@ -45,10 +45,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from boinc_app_eah_brp_tpu.runtime.jaxenv import honor_jax_platforms  # noqa: E402
-
-honor_jax_platforms()
-
 N_PRODUCTION = 6291457  # fft_size for 3*2^22 padded samples
 WINDOW = 1000
 

@@ -83,7 +83,6 @@ def serve_env(work: str, fault_spec: str | None, state_name: str,
         {
             "ERP_CHECKPOINT_PERIOD": "0",
             "ERP_LOOKAHEAD": "1",
-            "ERP_COMPILATION_CACHE": os.path.join(work, "xla-cache"),
             "ERP_RESULT_DATE": RESULT_DATE,
             "ERP_RETRY_BUDGET": "16",
             "ERP_RETRY_BASE_S": "0.01",

@@ -150,9 +150,6 @@ def run_hosts_smoke(args, work: str) -> int:
         env.update(
             {
                 "JAX_PLATFORMS": "cpu",
-                # share one compile cache across the emulated hosts: they
-                # trace identical shard programs
-                "ERP_COMPILATION_CACHE": os.path.join(work, "jit-cache"),
                 "ERP_NUM_PROCESSES": str(hosts),
                 "ERP_PROCESS_ID": str(i),
                 "ERP_LOCAL_DEVICES": "4",  # forced 4-device CPU platform
@@ -256,7 +253,6 @@ def run_fabric_smoke(args, work: str) -> int:
     env.update(
         {
             "JAX_PLATFORMS": "cpu",
-            "ERP_COMPILATION_CACHE": os.path.join(work, "jit-cache"),
             "ERP_RESULT_DATE": date,
             "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
         }

@@ -127,9 +127,6 @@ def measure(work: str) -> tuple[dict, list[dict], object, str]:
     scheduler's dispatch loop records every window."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("ERP_RESULT_DATE", RESULT_DATE)
-    os.environ.setdefault(
-        "ERP_COMPILATION_CACHE", os.path.join(work, "jit-cache")
-    )
     import fleet_bench
 
     from boinc_app_eah_brp_tpu.runtime import steptime
