@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..oracle.pipeline import DerivedParams
-from ..runtime import faultinject, flightrec, metrics, profiling, steptime, tracing
+from ..runtime import faultinject, flightrec, metrics, steptime, tracing
 from ..runtime import watchdog as hangdog
 from ..runtime.devicecost import stage_scope
 from ..ops.harmonic import (
@@ -1371,9 +1371,7 @@ def _run_bank_attempt(
             args = [ts_args, *dev_bank, jnp.int32(start), n_total, M, T]
             if prefetch is not None:
                 t0 = time.perf_counter()
-                with tracing.span(
-                    "prefetch-wait", start=start
-                ), profiling.annotate("erp:prefetch-wait"):
+                with tracing.span("prefetch-wait", start=start):
                     ns, mn = prefetch.get(start)
                 m_prefetch_s.inc(time.perf_counter() - t0)
                 ns, mn = np.asarray(ns), np.asarray(mn)
@@ -1383,9 +1381,7 @@ def _run_bank_attempt(
             t0 = time.perf_counter()
             with hangdog.guard("dispatch", start=start, stop=stop):
                 faultinject.fault_point("dispatch", start=start, stop=stop)
-                with tracing.span(
-                    "dispatch", start=start, stop=stop
-                ), profiling.annotate("erp:dispatch"):
+                with tracing.span("dispatch", start=start, stop=stop):
                     if wd is not None:
                         M, T, health_vec = step(*args)
                         wd.push(start, stop, health_vec)
@@ -1415,7 +1411,7 @@ def _run_bank_attempt(
                 t0 = time.perf_counter()
                 with hangdog.guard("drain", stop=stop), tracing.span(
                     "drain", stop=stop
-                ), profiling.annotate("erp:drain"):
+                ):
                     jax.block_until_ready(M)
                 dt_stall = time.perf_counter() - t0
                 m_stall_s.inc(dt_stall)
@@ -1434,7 +1430,9 @@ def _run_bank_attempt(
                 # boundary's cost model (ERP_HEALTH_EVERY is the knob)
                 wd.maybe_check("run_bank")
             if progress_cb is not None:
-                if progress_cb(stop, n, M, T) is False:
+                with tracing.span("progress", stop=stop):
+                    go_on = progress_cb(stop, n, M, T)
+                if go_on is False:
                     break
         if wd is not None:
             wd.check("run_bank")

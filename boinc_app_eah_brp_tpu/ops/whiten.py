@@ -17,6 +17,7 @@ import numpy as np
 from ..oracle.gslrng import Taus2  # noqa: F401  (re-exported for callers)
 from ..oracle.pipeline import DerivedParams, SearchConfig
 from ..oracle.whiten import seed_from_samples, zap_noise
+from ..runtime import tracing
 from ..runtime.devicecost import stage_scope
 from .fft import (
     backend_has_native_fft,
@@ -50,7 +51,8 @@ def _native_median_overlapped(ps_dev, window: int, chunks: int = 4) -> np.ndarra
             a, b = int(edges[c]), int(edges[c + 1])
             if b <= a:
                 continue
-            seg = np.asarray(ps_dev[a : b + window - 1])  # blocking d2h
+            with tracing.span("whiten-d2h"):
+                seg = np.asarray(ps_dev[a : b + window - 1])  # blocking d2h
             if fut is not None:
                 outs.append(fut.result())
             fut = pool.submit(running_median_native, seg, window)
@@ -154,21 +156,24 @@ def whiten_and_zap(
             # the parity halves directly (ops/unpack.py)
             from .unpack import nibble_lut, unpack_4bit_split_device
 
-            raw_d = jnp.asarray(np.asarray(packed_payload, dtype=np.uint8))
-            lut_d = jnp.asarray(nibble_lut(packed_scale))
+            with tracing.span("whiten-h2d"):
+                raw_d = jnp.asarray(np.asarray(packed_payload, dtype=np.uint8))
+                lut_d = jnp.asarray(nibble_lut(packed_scale))
             ev_u, od_u = unpack_4bit_split_device(raw_d, lut_d)
             ev_d = jnp.concatenate([ev_u, pad])
             od_d = jnp.concatenate([od_u, pad])
         else:
             samples32 = np.asarray(samples, dtype=np.float32)
-            ev_d = jnp.concatenate([jnp.asarray(samples32[0::2].copy()), pad])
-            od_d = jnp.concatenate([jnp.asarray(samples32[1::2].copy()), pad])
+            with tracing.span("whiten-h2d"):
+                ev_d = jnp.concatenate([jnp.asarray(samples32[0::2].copy()), pad])
+                od_d = jnp.concatenate([jnp.asarray(samples32[1::2].copy()), pad])
         _mark("h2d+pad", ev_d, od_d)
         re, im = rfft_packed_split(ev_d, od_d)
     else:
-        padded = jnp.zeros(nsamples, dtype=jnp.float32).at[:n_unpadded].set(
-            jnp.asarray(samples, dtype=jnp.float32)
-        )
+        with tracing.span("whiten-h2d"):
+            padded = jnp.zeros(nsamples, dtype=jnp.float32).at[:n_unpadded].set(
+                jnp.asarray(samples, dtype=jnp.float32)
+            )
         _mark("h2d+pad", padded)
         # split (real, imag) spectrum: complex64 never touches the device
         # (the TPU backend here has neither XLA FFT nor complex64; ops/fft.py)
@@ -258,11 +263,13 @@ def whiten_and_zap(
         if return_device_split:
             return ev_b[: n_unpadded // 2], od_b[: n_unpadded // 2]
         out = np.empty(n_unpadded, dtype=np.float32)
-        out[0::2] = np.asarray(ev_b[: n_unpadded // 2])
-        out[1::2] = np.asarray(od_b[: n_unpadded // 2])
+        with tracing.span("whiten-d2h"):
+            out[0::2] = np.asarray(ev_b[: n_unpadded // 2])
+            out[1::2] = np.asarray(od_b[: n_unpadded // 2])
     else:
         back = irfft_split(re, im, nsamples) * renorm
         _mark("irfft", back)
-        out = np.asarray(back[:n_unpadded], dtype=np.float32)
+        with tracing.span("whiten-d2h"):
+            out = np.asarray(back[:n_unpadded], dtype=np.float32)
     _mark("d2h")
     return out

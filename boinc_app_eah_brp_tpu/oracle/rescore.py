@@ -174,7 +174,8 @@ def rescore_winners(
             todo[tpl] = missing
 
     def one(tpl):
-        return tpl, _score_template(ts, derived, tpl, todo[tpl])
+        with tracing.span("rescore-template"):
+            return tpl, _score_template(ts, derived, tpl, todo[tpl])
 
     workers = max_workers or min(8, os.cpu_count() or 1, len(todo) or 1)
     if workers > 1 and len(todo) > 1:

@@ -46,7 +46,7 @@ from ..models.search import (
     upload_bank,
     validate_bank_bounds,
 )
-from ..runtime import faultinject, flightrec, metrics, profiling, tracing
+from ..runtime import faultinject, flightrec, metrics, tracing
 from ..runtime import watchdog as hangdog
 from ..runtime.devicecost import stage_scope
 from .mesh import TEMPLATE_AXIS
@@ -350,9 +350,7 @@ def _run_bank_sharded_attempt(
             args = [ts_args, *dev_bank, jnp.int32(start), n_total, M, T]
             if prefetch is not None:
                 t0 = time.perf_counter()
-                with tracing.span(
-                    "prefetch-wait", start=start
-                ), profiling.annotate("erp:prefetch-wait"):
+                with tracing.span("prefetch-wait", start=start):
                     ns, mn = prefetch.get(start)
                 m_prefetch_s.inc(time.perf_counter() - t0)
                 ns, mn = np.asarray(ns), np.asarray(mn)
@@ -361,9 +359,7 @@ def _run_bank_sharded_attempt(
             t0 = time.perf_counter()
             with hangdog.guard("dispatch", start=start, stop=stop):
                 faultinject.fault_point("dispatch", start=start, stop=stop)
-                with tracing.span(
-                    "dispatch", start=start, stop=stop
-                ), profiling.annotate("erp:dispatch"):
+                with tracing.span("dispatch", start=start, stop=stop):
                     if wd is not None:
                         M, T, health_vec = step(*args)
                         wd.push(start, stop, health_vec)
@@ -390,7 +386,7 @@ def _run_bank_sharded_attempt(
                 t0 = time.perf_counter()
                 with hangdog.guard("drain", stop=stop), tracing.span(
                     "drain", stop=stop
-                ), profiling.annotate("erp:drain"):
+                ):
                     jax.block_until_ready(M)
                 dt_stall = time.perf_counter() - t0
                 m_stall_s.inc(dt_stall)
@@ -406,7 +402,9 @@ def _run_bank_sharded_attempt(
             if wd is not None:
                 wd.maybe_check("run_bank_sharded")
             if progress_cb is not None:
-                if progress_cb(stop, n_stop, M, T) is False:
+                with tracing.span("progress", stop=stop):
+                    go_on = progress_cb(stop, n_stop, M, T)
+                if go_on is False:
                     break
         if wd is not None:
             wd.check("run_bank_sharded")

@@ -134,7 +134,7 @@ def ledger_stage(stage: str) -> str:
 
 
 # ---------------------------------------------------------------------------
-# estimated per-stage device timeline (chip-free; tentpole c)
+# roofline stage model (tools/step_report.py)
 
 
 def stage_time_model(
@@ -148,10 +148,10 @@ def stage_time_model(
     """Roofline-estimated per-template device time per pipeline stage:
     ``[{stage, scope, t_ms, fraction, bound}, ...]`` in pipeline order.
 
-    This is the cost model behind the SYNTHESIZED device timeline when
-    no chip is attached: each stage's time is ``max(t_mxu, t_hbm)`` from
-    ``runtime/roofline.py``, normalized to fractions so a dispatch
-    window's device occupancy can be split across stages.  Imports jax
+    Each stage's time is ``max(t_mxu, t_hbm)`` from
+    ``runtime/roofline.py``, also normalized to fractions of the whole
+    step (``tools/step_report.py`` joins it against measured step
+    times).  Imports jax
     transitively (roofline pulls ops.fft for the plan) — call from
     jax-using code only."""
     from .roofline import _CHIPS, chip_generation, pipeline_costs
@@ -184,103 +184,6 @@ def stage_time_model(
     for r in rows:
         r["fraction"] = (r["t_ms"] / 1e3 / total) if total > 0 else 0.0
     return rows
-
-
-def estimate_device_records(
-    windows: list[tuple],
-    model: list[dict],
-    lane: str = "device:estimated",
-) -> list[dict]:
-    """Synthesized device-lane span records for ``tracing``'s Chrome
-    export: each ``(ctx, ts_us, end_us)`` dispatch window is filled with
-    one span per pipeline stage, widths proportional to the roofline
-    fractions in ``model`` (:func:`stage_time_model`).
-
-    Pure record construction — no jax, no tracing state; the caller
-    hands the result to ``tracing.add_device_records``.  The estimate is
-    honest about what it is: every span carries ``estimated: True`` and
-    the lane name says so, so a Perfetto reader can't mistake it for a
-    measured profile."""
-    records = []
-    for ctx, ts_us, end_us in windows:
-        span = max(0.0, float(end_us) - float(ts_us))
-        if span <= 0.0:
-            continue
-        t = float(ts_us)
-        for row in model:
-            dur = round(span * row["fraction"], 1)
-            if dur < 0.1:  # sub-µs stage: a 0-width B/E pair helps nobody
-                continue
-            records.append(
-                {
-                    "name": SCOPE_PREFIX + row["scope"],
-                    "tid": lane,
-                    "ctx": ctx,
-                    "ts_us": round(t, 1),
-                    "dur_us": dur,
-                    "end_us": round(t + dur, 1),
-                    "args": {"estimated": True, "bound": row["bound"]},
-                }
-            )
-            t += dur
-    return records
-
-
-def dispatch_windows(spans: list[dict]) -> list[tuple]:
-    """(ctx, ts_us, end_us) device-occupancy windows from a host span
-    list: each dispatch span opens its window, the next drain span (or
-    the next dispatch, when lookahead keeps the device saturated) closes
-    it.  Used by the chip-free synthesized timeline; with a chip the
-    profiler's measured events replace this entirely."""
-    timeline = sorted(
-        (s for s in spans if s.get("name") in ("dispatch", "drain")),
-        key=lambda s: s.get("ts_us", 0.0),
-    )
-    out = []
-    open_win = None  # (ctx, start_us)
-    for s in timeline:
-        if s.get("name") == "dispatch":
-            if open_win is not None:
-                out.append((open_win[0], open_win[1], s.get("ts_us", 0.0)))
-            open_win = (s.get("ctx"), s.get("ts_us", 0.0))
-        else:  # drain: the device caught up; close the open window
-            if open_win is not None:
-                out.append(
-                    (open_win[0], open_win[1],
-                     s.get("end_us", s.get("ts_us", 0.0)))
-                )
-                open_win = None
-    if open_win is not None:
-        last = max((s.get("end_us", 0.0) for s in timeline), default=0.0)
-        if last > open_win[1]:
-            out.append((open_win[0], open_win[1], last))
-    return [(c, a, b) for c, a, b in out if b > a]
-
-
-def emit_estimated_timeline(geom) -> int:
-    """Chip-free tentpole-c glue: derive dispatch windows from the live
-    trace ring, split them by the roofline stage model, and register the
-    synthesized device lane with ``tracing`` for the Chrome export.
-
-    Returns the number of device records added (0 when tracing is off
-    or no dispatch windows exist).  Called by the driver after the
-    search phase when no TPU is attached; with a chip the measured
-    profiler events take this lane's place."""
-    from . import tracing
-
-    if not tracing.enabled():
-        return 0
-    spans = [r for r in tracing.events() if r.get("kind") == "span"]
-    windows = dispatch_windows(spans)
-    if not windows:
-        return 0
-    model = stage_time_model(
-        geom.nsamples, geom.n_unpadded, geom.fund_hi, geom.harm_hi,
-        max_slope=geom.max_slope,
-    )
-    records = estimate_device_records(windows, model)
-    tracing.add_device_records(records)
-    return len(records)
 
 
 @dataclass
@@ -406,8 +309,7 @@ def stage_records(
 ) -> list[dict]:
     """Fold raw profiler device records into per-STAGE measured records:
     events whose op name resolves through :func:`stage_of_op_name` are
-    renamed to their ``erp.<stage>`` scope and moved onto ``lane`` (the
-    measured counterpart of the ``device:estimated`` roofline lane);
+    renamed to their ``erp.<stage>`` scope and moved onto ``lane``;
     unattributed events are dropped — the raw records still carry them.
     An event named after an HLO instruction (the TPU's) resolves through
     ``op_scopes`` (:func:`hlo_op_scopes`).  Pure record construction,

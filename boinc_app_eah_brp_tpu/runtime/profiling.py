@@ -88,20 +88,23 @@ def device_memory_status(tag: str, level: erplog.Level = erplog.Level.DEBUG) -> 
 
 
 @contextlib.contextmanager
-def phase(name: str, level: erplog.Level = erplog.Level.DEBUG):
+def phase(
+    name: str, level: erplog.Level = erplog.Level.DEBUG,
+    span: str | None = None,
+):
     """Debug bracket: wall time + post-phase memory for one pipeline stage.
 
-    The wall time always lands in the metrics registry and — when the
-    host span tracer is armed — on the span timeline (both no-ops when
-    disabled); the log lines and the per-device memory walk only happen
-    when ``level`` clears the active log threshold."""
+    The wall time always lands in the metrics registry under ``name``
+    and on the span ``span`` (default ``name``), which the block gets to
+    attach args to; the log lines and the per-device memory walk only
+    happen when ``level`` clears the active log threshold."""
     loud = erplog.enabled(level)
     t0 = time.perf_counter()
     if loud:
         erplog.log_message(level, True, "phase %s: start\n", name)
     try:
-        with tracing.span(name):
-            yield
+        with tracing.span(span or name) as sp:
+            yield sp
     finally:
         dt = time.perf_counter() - t0
         metrics.record_phase(name, dt)
@@ -137,11 +140,3 @@ def trace(logdir: str | None = None):
         jax.profiler.stop_trace()
         erplog.info("Profiler trace written to %s\n", logdir)
 
-
-def annotate(name: str):
-    """Named region inside a trace (``jax.profiler.TraceAnnotation``) — shows
-    per-batch spans in XProf the way the reference's per-kernel debug lines
-    do in its logs."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

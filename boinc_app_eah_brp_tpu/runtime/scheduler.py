@@ -43,7 +43,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from . import faultinject, metrics, resilience, steptime, watchdog
+from . import faultinject, metrics, resilience, steptime, tracing, watchdog
 from . import logging as erplog
 from .obs import ObsContext
 from .session import Session, SessionEnv, exit_code_for
@@ -347,7 +347,7 @@ class Scheduler:
         rec0 = self._session_recompiles(session)
         gap_s: float | None = None
         step_cursor = steptime.count()
-        with self._exec_lock:
+        with self._exec_lock, tracing.span("wu", ticket=name, corr_id=corr_id):
             t0 = time.perf_counter()
             if self._last_exec_end is not None:
                 gap_s = t0 - self._last_exec_end
@@ -373,7 +373,8 @@ class Scheduler:
                 try:
                     if prep_future is not None:
                         t_p = time.perf_counter()
-                        prep_future.result()
+                        with tracing.span("prep-wait"):
+                            prep_future.result()
                         prep_s = time.perf_counter() - t_p
                     elif not session.prepared:
                         t_p = time.perf_counter()

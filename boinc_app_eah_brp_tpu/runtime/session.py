@@ -283,7 +283,6 @@ class Session:
         self.init_data = init_data
         self.prepared = False
         self.ts_scale = None  # deferred-renorm scale, set by prepare()
-        self._setup_span = None
 
     # -- scoped-observability helpers -------------------------------------
 
@@ -310,14 +309,16 @@ class Session:
         analogue of the exact-mean prefetch).  ``n_mesh``/``dist`` come
         from the caller because device selection is process-scoped, not
         Session-scoped."""
+        # one span on the thread that prepares (the scheduler's prep pool
+        # under the FleetServer), closed there
+        with tracing.span("prepare"):
+            self._prepare(n_mesh, dist)
+        return self
+
+    def _prepare(self, n_mesh: int, dist) -> None:
         args = self.args
         self._n_mesh = n_mesh
         self._dist = dist
-        # everything up to the template loop (bank/workunit parse,
-        # geometry build) on one timeline span; closed manually right
-        # before the search so an exception mid-setup leaves it on the
-        # open-span stack — exactly what the crash dump should show
-        self._setup_span = tracing.span("setup").__enter__()
         self._obs_record(
             "session-prepare", inputfile=args.inputfile,
             templatebank=args.templatebank,
@@ -325,7 +326,8 @@ class Session:
 
         # --- template bank: full parse doubles as validation
         # (demod_binary.c:507-544)
-        bank = read_template_bank(args.templatebank)
+        with tracing.span("parse"):
+            bank = read_template_bank(args.templatebank)
         template_total = len(bank)
         erplog.debug("Total amount of templates: %d\n", template_total)
         # fold out-of-range initial phases into [0, 2pi) once, up front:
@@ -421,7 +423,8 @@ class Session:
         self.quarantined = quarantined
 
         # --- workunit
-        wu = read_workunit(args.inputfile)
+        with tracing.span("parse"):
+            wu = read_workunit(args.inputfile)
         samples = wu.samples
         if args.debug:
             _dump_header(wu.header)
@@ -468,7 +471,7 @@ class Session:
             if not args.zaplistfile:
                 raise RadpulError(RADPUL_EFILE, "Whitening requires a zaplist file (-l).")
             zap_ranges = read_zaplist(args.zaplistfile)
-            with profiling.phase("whitening"):
+            with profiling.phase("whitening", span="whiten"):
                 # single-device searches keep the whitened parity halves
                 # resident on device (no d2h/h2d round-trip; ops/whiten.py);
                 # the mesh path still takes the host array for sharding.
@@ -577,7 +580,6 @@ class Session:
             "dispersion_measure": float(wu.header["DM"]),
         }
         self.prepared = True
-        return self
 
     # -- phase 2: the search + finalize -----------------------------------
 
@@ -686,9 +688,7 @@ class Session:
                 return
             if not args.checkpointfile and rescorer is None:
                 return
-            with tracing.span("checkpoint", n_done=n_done), profiling.annotate(
-                "erp:checkpoint"
-            ):
+            with tracing.span("checkpoint", n_done=n_done):
                 _checkpoint_now(n_done, M_now, T_now)
 
         def _checkpoint_now(n_done: int, M_now, T_now) -> None:
@@ -744,7 +744,7 @@ class Session:
                     )
                 )
             if sentinel is not None:
-                with profiling.annotate("erp:sentinel-probe"):
+                with tracing.span("sentinel-probe"):
                     sentinel.probe("checkpoint")
 
         import jax.numpy as jnp
@@ -811,9 +811,6 @@ class Session:
             return True
 
         profiling.device_memory_status("search setup")
-        if self._setup_span is not None:
-            self._setup_span.__exit__(None, None, None)
-            self._setup_span = None
         try:
             # per-chip attainable bound (runtime/roofline.py; the
             # reference logs its GFLOPS estimate the same way,
@@ -876,7 +873,7 @@ class Session:
             # device lane into the Chrome export (runtime/steptime.py)
             with steptime.maybe_capture_profile(), profiling.trace(
                 args.profile_dir
-            ), profiling.phase("template loop"):
+            ), profiling.phase("template loop", span="template-loop"):
                 if dist is not None:
                     # multi-host elastic search: this host runs (and, on
                     # peer death, adopts) template-range shards under
@@ -983,27 +980,6 @@ class Session:
                 rescorer.abort()
             raise
 
-        # chip-free runs: synthesize the per-stage device lane for the
-        # Chrome export from the dispatch windows + the roofline stage
-        # model (runtime/devicecost.py).  On a real chip the profiler's
-        # measured events are the device truth, so the estimate stays
-        # CPU-only.
-        if tracing.enabled():
-            try:
-                import jax
-
-                if jax.default_backend() == "cpu":
-                    from . import devicecost
-
-                    n_dev = devicecost.emit_estimated_timeline(geom)
-                    if n_dev:
-                        erplog.debug(
-                            "Synthesized %d estimated device-lane records.\n",
-                            n_dev,
-                        )
-            except Exception:
-                pass  # telemetry must never take down the search
-
         if interrupted or (elastic_result is not None and elastic_result.interrupted):
             erplog.warn("Quit requested! Exiting prematurely...\n")
             if rescorer is not None:
@@ -1070,7 +1046,7 @@ class Session:
         if args.rescore and rescore_enabled() and len(emitted):
             import time as _time
 
-            with profiling.phase("oracle rescore"):
+            with profiling.phase("oracle rescore", span="rescore") as sp:
                 t0 = _time.perf_counter()
                 # the overlap worker already fetched + interleaved the
                 # host series; don't pay the ~17 MB d2h a second time
@@ -1094,6 +1070,7 @@ class Session:
                 )
                 emitted = finalize_candidates(patched, derived.t_obs)
                 rescore_wall = _time.perf_counter() - t0
+                sp.set(n_winners=n_winners, n_eval=n_eval)
             if rescorer is not None:
                 erplog.info(
                     "Rescored %d of %d winning templates through the host "

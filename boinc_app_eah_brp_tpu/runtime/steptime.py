@@ -35,8 +35,8 @@ module's own ``erp-steptime/1`` JSONL artifact when
 wraps a block in a ``jax.profiler`` trace session, parses the xplane
 through ``runtime/devicecost.py`` into per-stage *measured* device
 records via the ``stage_of_op_name`` registry, and merges them into the
-Chrome export as a ``device:measured`` lane alongside the estimated
-one.  ``ERP_STEPTIME_PROFILE=<dir>`` arms it for the Session's template
+Chrome export as a ``device:measured`` lane.
+``ERP_STEPTIME_PROFILE=<dir>`` arms it for the Session's template
 loop without code changes (:func:`maybe_capture_profile`).
 
 Env surface: ``ERP_STEPTIME`` (truthy enables the bracket),
@@ -424,7 +424,7 @@ def capture_profile(
     start/stop around the with-block (N dispatch windows), xplane parse
     into per-stage *measured* device records via the
     ``devicecost.stage_of_op_name`` registry, merged into the Chrome
-    export as ``lane`` alongside the estimated one.  On a TPU, whose
+    export as ``lane``.  On a TPU, whose
     events are named after HLO instructions, pass ``op_scopes``
     (``devicecost.hlo_op_scopes`` of the profiled executable's text).
 

@@ -1,34 +1,40 @@
-"""Host-side span tracing with Perfetto-loadable Chrome trace export.
+"""Host-side span tracing: one span API for the profiler trace and for
+a Perfetto-loadable JSONL / Chrome trace export.
 
-Layer 7 of the observability stack (docs/observability.md).  Layer 6
-(``profiling.trace`` -> XProf) needs a live device session and a
-TensorBoard to read the result; this module answers the same "where did
-the wall clock go" question on ANY host with zero device dependency: a
-thread-aware span API over one shared timestamp base, recording into a
-bounded ring, streaming to JSONL when ``ERP_TRACE_FILE`` is set, and
-exporting a Chrome trace-event JSON (``<trace_file>.chrome.json``) that
-loads directly in Perfetto / ``chrome://tracing``.
+Every span is also a profiler annotation: while a ``jax.profiler``
+session records, a span named ``n`` opens ``TraceAnnotation("erp:" +
+n)`` on the thread that runs it, on the profiler's own clock, so the
+program's spans line up with the device planes in one xplane trace
+(``benchmark/trace.py`` reads them there).  Independently of that,
+``ERP_TRACE_FILE`` arms the JSONL layer: a thread-aware span timeline
+over one shared timestamp base, recorded into a bounded ring, streamed
+to JSONL, and exported as Chrome trace-event JSON
+(``<trace_file>.chrome.json``) that loads in Perfetto /
+``chrome://tracing``.  Span args go to the JSONL record only; the
+annotation carries the bare name.
 
-Span sites cover the critical path of the dispatch pipeline: the
-dispatch window (``models/search.py`` / ``parallel/sharded_search.py``
-dispatch / drain / prefetch-wait), the exact-mean prefetch thread, the
-rescorer's feed thread, checkpoint + retry-backoff paths, and the
-driver's coarse phases — so ``tools/trace_report.py`` can attribute the
-run wall to named stalls without a chip.  Device-side per-stage spans
-(measured from the profiler or estimated from the AOT roofline —
-``runtime/devicecost.py``) merge onto ``device:*`` lanes of the Chrome
-export via ``add_device_records``; they never enter the JSONL stream,
-whose records must stay strictly ordered by ``end_us``.  The work
-fabric reuses the same side channel for its per-workunit lifecycle
-lanes (``wu:*``): issue→compute→report→validate→grant spans assembled
-at grant time, correlated by workunit correlation id.
+Span sites cover the served WU's path: the scheduler's ``wu`` /
+``prep-wait``, the Session's ``prepare`` (``parse``, ``whiten``),
+``template-loop``, ``checkpoint``, ``finalize``, ``rescore`` and
+``result-write``, the oracle's ``rescore-template`` on its pool
+threads, and the dispatch loop's ``dispatch`` / ``drain`` /
+``prefetch-wait`` / ``progress`` (``models/search.py``,
+``parallel/sharded_search.py``).  A span opened before the profiler
+started is absent from the profiler trace, so long phases carry short
+child spans.  Side-channel records (the measured ``device:*`` lane of
+``runtime/steptime.py``, the work fabric's ``wu:*`` lifecycle lanes)
+merge into the Chrome export via ``add_device_records``; they never
+enter the JSONL stream, whose records must stay strictly ordered by
+``end_us``.
 
 Design rules (same contract as ``metrics`` / ``flightrec`` /
 ``faultinject``):
 
-* **Near-zero cost when disabled.**  ``span()`` is a flag test returning
-  one shared no-op context manager; no file is created, no thread-local
-  state touched, and ``import tracing`` never imports jax.
+* **Near-zero cost when disabled.**  With no trace file and no profiler
+  session, ``span()`` is a flag test plus, once jax is imported, one
+  ``TraceAnnotation.is_enabled()`` call, returning one shared no-op
+  context manager; no file is created, no thread-local state touched,
+  and ``import tracing`` never imports jax.
 * **Thread-safe.**  Spans open/close concurrently on the dispatch loop,
   prefetch worker, rescore feed and heartbeat threads; the ring and the
   stream share one lock, and the completion timestamp is taken INSIDE
@@ -53,11 +59,11 @@ thread; workers that service that window call ``set_context`` (or pass
 a drain stall with the prefetch/rescore work of the SAME batch even
 though they ran on different threads.
 
-Env surface: ``ERP_TRACE_FILE`` (JSONL stream path; enables the layer),
-``ERP_TRACE_EVENTS`` (ring capacity, default 16384), ``ERP_TRACE_LANE``
-(stable lane identity for merged fleet timelines; falls back to
-``host<$ERP_PROCESS_ID>`` then the correlation id).  Env fallbacks
-apply only to the default context.
+Env surface: ``ERP_TRACE_FILE`` (JSONL stream path; arms the JSONL
+layer), ``ERP_TRACE_EVENTS`` (ring capacity, default 16384),
+``ERP_TRACE_LANE`` (stable lane identity for merged fleet timelines;
+falls back to ``host<$ERP_PROCESS_ID>`` then the correlation id).  Env
+fallbacks apply only to the default context.
 """
 
 from __future__ import annotations
@@ -126,11 +132,54 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+ANNOTATION_PREFIX = "erp:"
+
+# jax's TraceAnnotation class, looked up once jax is imported (this
+# module never imports it)
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """A profiler annotation ``erp:<name>`` while a profiler session
+    records, else None (and always None before jax is imported)."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    if not cls.is_enabled():
+        return None
+    return cls(ANNOTATION_PREFIX + name)
+
+
+class _AnnotationSpan:
+    """A span while only the profiler records: the annotation alone."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
 
 class _Span:
-    __slots__ = ("owner", "name", "tid", "ctx", "args", "_start_us", "_depth")
+    __slots__ = ("owner", "name", "tid", "ctx", "args", "_start_us", "_depth",
+                 "_ann")
 
-    def __init__(self, owner, name, tid, ctx, args):
+    def __init__(self, owner, name, tid, ctx, args, ann):
         self.owner = owner
         self.name = name
         self.tid = tid
@@ -138,6 +187,7 @@ class _Span:
         self.args = args
         self._start_us = 0.0
         self._depth = 0
+        self._ann = ann
 
     def set(self, **args) -> None:
         """Attach/overwrite args after the span opened (e.g. the batch
@@ -159,11 +209,15 @@ class _Span:
                 o._open[t.ident] = stack
         self._depth = len(stack)
         stack.append(self)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._start_us = o._now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         o = self.owner
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = o._tls.stack
         if stack and stack[-1] is self:
             stack.pop()
@@ -290,11 +344,14 @@ class TraceContext:
     ):
         """Open a named span as a context manager.  ``tid`` overrides
         the timeline lane (defaults to the thread name), ``ctx`` the
-        trace context (defaults to the thread's current one).  Disabled
-        path: a shared inert object."""
+        trace context (defaults to the thread's current one).  While a
+        profiler session records, the span is also the annotation
+        ``erp:<name>`` on the thread that enters it.  Disabled path (no
+        JSONL layer, no profiler): a shared inert object."""
+        ann = _annotation(name)
         if not self._enabled:
-            return _NULL_SPAN
-        return _Span(self, name, tid, ctx, dict(args) if args else {})
+            return _NULL_SPAN if ann is None else _AnnotationSpan(ann)
+        return _Span(self, name, tid, ctx, dict(args) if args else {}, ann)
 
     def instant(self, name: str, tid: str | None = None, **args) -> None:
         """A zero-duration marker on the timeline (Chrome ``i``
@@ -322,9 +379,9 @@ class TraceContext:
     def add_device_records(self, records: list[dict]) -> int:
         """Merge side-channel span records into the timeline.
 
-        ``runtime/devicecost.py`` produces device-side spans — measured
-        (profiler xplane) or estimated (AOT roofline) — on lanes named
-        ``device:*``; the work fabric produces per-WU lifecycle spans on
+        ``runtime/steptime.py`` produces measured device-side spans
+        (profiler xplane) on lanes named ``device:*``; the work fabric
+        produces per-WU lifecycle spans on
         ``wu:*`` lanes.  They land ONLY in the Chrome export and the
         finish summary, never in the JSONL stream: their ``ts_us``
         values interleave with already-streamed host spans, so streaming

@@ -1,8 +1,8 @@
 """Device-cost observatory (runtime/devicecost.py, tools/hlo_attrib.py):
 stage-registry semantics, named scopes surviving into COMPILED HLO
 op_name metadata, zero recompiles and zero numeric effect from scoping,
-synthetic-module byte attribution, the estimated device timeline ->
-Chrome-export merge -> trace_report device section, the artifact
+synthetic-module byte attribution, device records -> Chrome-export
+merge -> trace_report device section, the artifact
 validators behind ``metrics_report --check``, and cost_ledger's
 attribution-artifact consumption."""
 
@@ -328,7 +328,7 @@ def test_metrics_report_check_dispatches_new_schemas(tmp_path, capsys):
     assert metrics_report.main(["--check", str(attrib)]) == 1
 
 
-# --- estimated device timeline ----------------------------------------------
+# --- device records in the Chrome export ----------------------------------
 
 
 def _span(name, ctx, ts, end, tid="MainThread"):
@@ -338,38 +338,8 @@ def _span(name, ctx, ts, end, tid="MainThread"):
     }
 
 
-def test_dispatch_windows_from_span_sequence():
-    spans = [
-        _span("dispatch", 1, 0.0, 10.0),
-        _span("dispatch", 2, 200.0, 210.0),  # lookahead: closes window 1
-        _span("drain", 2, 300.0, 350.0),  # drain end closes window 2
-        _span("checkpoint", 2, 360.0, 400.0),  # ignored
-    ]
-    assert devicecost.dispatch_windows(spans) == [
-        (1, 0.0, 200.0),
-        (2, 200.0, 350.0),
-    ]
-    assert devicecost.dispatch_windows([]) == []
-
-
-def test_estimate_device_records_partition_and_tagging():
-    model = [
-        {"stage": "a", "scope": "resample", "fraction": 0.25, "bound": "hbm"},
-        {"stage": "b", "scope": "fft", "fraction": 0.75, "bound": "mxu"},
-        {"stage": "c", "scope": "merge", "fraction": 0.0, "bound": "hbm"},
-    ]
-    recs = devicecost.estimate_device_records([(7, 1000.0, 2000.0)], model)
-    # the zero-fraction stage emits nothing; the rest partition the window
-    assert [r["name"] for r in recs] == ["erp.resample", "erp.fft"]
-    assert recs[0]["ts_us"] == 1000.0 and recs[0]["dur_us"] == 250.0
-    assert recs[1]["ts_us"] == 1250.0 and recs[1]["end_us"] == 2000.0
-    assert all(r["tid"] == "device:estimated" for r in recs)
-    assert all(r["args"]["estimated"] is True for r in recs)
-    assert all(r["ctx"] == 7 for r in recs)
-
-
 def test_device_records_merge_into_chrome_only(tmp_path):
-    """Tentpole c end-to-end without jax: host spans stream to JSONL,
+    """End to end without jax: host spans stream to JSONL,
     device records land ONLY in the Chrome export, and trace_report
     splits drain wall into device-bound vs host-stall."""
     stream = str(tmp_path / "run.trace.jsonl")
@@ -385,10 +355,10 @@ def test_device_records_merge_into_chrome_only(tmp_path):
         accepted = tracing.add_device_records(
             [
                 {
-                    "name": "erp.fft", "tid": "device:estimated", "ctx": 1,
+                    "name": "erp.fft", "tid": "device:measured", "ctx": 1,
                     "ts_us": drain["ts_us"], "dur_us": dur,
                     "end_us": drain["ts_us"] + dur,
-                    "args": {"estimated": True, "bound": "mxu"},
+                    "args": {"measured": True, "stage": "fft"},
                 },
                 {"name": 42},  # malformed: dropped, not crashed
             ]
@@ -418,16 +388,15 @@ def test_device_records_merge_into_chrome_only(tmp_path):
         trace_report.is_device_lane(t) for t in table["background_busy_s"]
     )
     dev = table["device"]
-    assert dev["estimated"] is True
-    assert "device:estimated" in dev["lane_busy_s"]
+    assert "device:measured" in dev["lane_busy_s"]
     assert dev["stages"]["fft"]["count"] == 1
-    # the synthetic device span covers the whole drain: all device-bound
+    # the device span covers the whole drain: all device-bound
     assert dev["drain_host_stall_s"] == pytest.approx(0.0, abs=1e-4)
     assert dev["drain_device_bound_s"] == pytest.approx(
         dev["drain_s"], rel=0.05
     )
     rendered = trace_report.render(table, "t")
-    assert "Device lanes (estimated):" in rendered
+    assert "Device lanes:" in rendered
     assert "drain split:" in rendered
 
 

@@ -56,11 +56,6 @@ def test_trace_writes_xplane(tmp_path):
     assert found, "expected an xplane trace file"
 
 
-def test_annotate_usable_inline():
-    with profiling.annotate("batch 0"):
-        jnp.ones(8).block_until_ready()
-
-
 def test_device_memory_status_early_returns_when_suppressed(monkeypatch):
     """With the level suppressed there must be NO device walk at all (the
     old code paid jax.local_devices() on every phase exit even with
@@ -98,6 +93,24 @@ def test_phase_suppressed_still_records_metrics(capsys):
         assert phases["silent stage"]["wall_s"] >= 0.0
     finally:
         erplog.set_level(saved)
+        metrics.finish(0)
+
+
+def test_phase_span_name_and_args_apart_from_its_phase_key():
+    """A phase keeps its run-report key and opens the span it names,
+    to which the block attaches args."""
+    from boinc_app_eah_brp_tpu.runtime import tracing
+
+    assert metrics.configure(force=True)
+    assert tracing.configure(force=True)
+    try:
+        with profiling.phase("oracle rescore", span="rescore") as sp:
+            sp.set(n_eval=3)
+        (ev,) = tracing.events()
+        assert ev["name"] == "rescore" and ev["args"] == {"n_eval": 3}
+        assert metrics.snapshot()["phases"]["oracle rescore"]["count"] == 1
+    finally:
+        tracing.finish(0)
         metrics.finish(0)
 
 

@@ -81,6 +81,89 @@ def test_disabled_span_overhead():
     assert dt / n < 2e-6, f"disabled span costs {dt / n * 1e9:.0f}ns"
 
 
+def test_disabled_span_with_jax_loaded_stays_shared_noop():
+    """Once jax is imported the disabled path adds one is_enabled()
+    check: with no profiler recording it is still the shared no-op, and
+    still within the bound."""
+    import jax  # noqa: F401
+
+    assert tracing.span("a") is tracing.span("b") is tracing._NULL_SPAN
+    n = 100_000
+    sp = tracing.span
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with sp("dispatch"):
+            pass
+    dt = time.perf_counter() - t0
+    assert dt / n < 2e-6, f"disabled span costs {dt / n * 1e9:.0f}ns"
+
+
+# ---------------------------------------------------------------------------
+# the profiler trace: every span is an erp:<name> annotation
+
+
+def _profiled(tmp_path, body):
+    """Run ``body`` under a profiler session; the host spans of the
+    xplane as ``benchmark/trace.py`` reads them."""
+    import jax
+
+    from benchmark import trace as bench_trace
+
+    logdir = str(tmp_path / "prof")
+    jax.profiler.start_trace(logdir)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return bench_trace.host_spans(bench_trace.load_planes(logdir))
+
+
+@pytest.mark.parametrize("armed", [False, True], ids=["unarmed", "jsonl"])
+def test_span_lands_in_profiler_trace_on_every_thread(tmp_path, armed):
+    """With or without the JSONL layer, a span on the main thread and on
+    a pool thread are both host events ``erp:x`` of the xplane, args left
+    out of the name."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    if armed:
+        assert tracing.configure(force=True)
+
+    def body():
+        with tracing.span("x", start=0):
+            time.sleep(0.002)
+
+        def on_pool():
+            with tracing.span("x", start=1):
+                time.sleep(0.002)
+
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            pool.submit(on_pool).result()
+
+    spans = _profiled(tmp_path, body)
+    assert [s[0] for s in spans].count("erp:x") == 2
+    assert all(e >= s for _, s, e in spans)
+    if armed:
+        assert [e["name"] for e in tracing.events()] == ["x", "x"]
+        assert tracing.events()[0]["args"] == {"start": 0}
+
+
+def test_span_opened_before_the_profiler_is_absent(tmp_path):
+    """The documented limit: a span already open when the profiler
+    starts is not in the trace, its child opened after is."""
+    outer = tracing.span("outer")
+
+    def body():
+        with tracing.span("inner"):
+            pass
+
+    outer.__enter__()
+    try:
+        spans = _profiled(tmp_path, body)
+    finally:
+        outer.__exit__(None, None, None)
+    assert [s[0] for s in spans] == ["erp:inner"]
+
+
 # ---------------------------------------------------------------------------
 # ring semantics (in-memory mode, no stream file)
 

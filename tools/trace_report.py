@@ -6,14 +6,14 @@ leaves a JSONL span stream plus a Chrome trace export
 run's wall clock to named stall categories — dispatch, drain-stall,
 prefetch-wait, checkpoint, rescore-feed, retry-backoff — using EXCLUSIVE
 self-time (a span's duration minus its nested children, so the
-"template loop" phase bracket doesn't double-count the dispatch windows
+"template-loop" phase bracket doesn't double-count the dispatch windows
 inside it).  Background lanes (the prefetch and rescore-feed threads)
 are reported separately: their busy time overlaps the main thread and is
-not part of the wall-clock attribution.  ``device:*`` lanes (measured or
-AOT-estimated per-stage device spans, ``runtime/devicecost.py``) get
-their own section: per-lane busy time, a per-stage breakdown, and a
-split of the host's drain-stall wall into device-bound time (the chip
-was computing under the drain) versus host-stall.
+not part of the wall-clock attribution.  ``device:*`` lanes (measured
+per-stage device spans, ``runtime/steptime.py``) get their own section:
+per-lane busy time, a per-stage breakdown, and a split of the host's
+drain-stall wall into device-bound time (the chip was computing under
+the drain) versus host-stall.
 
 Usage:
     python tools/trace_report.py RUN.trace.jsonl            # stall table
@@ -311,7 +311,6 @@ def _device_table(device_spans: list[dict], host_spans: list[dict]) -> dict:
     (host-stall: input starvation, transfer, dispatch gap)."""
     lanes: dict = {}
     stages: dict = {}
-    estimated = False
     for s in device_spans:
         lanes.setdefault(s.get("tid"), []).append(s)
         name = str(s.get("name", "?"))
@@ -320,8 +319,6 @@ def _device_table(device_spans: list[dict], host_spans: list[dict]) -> dict:
         row = stages.setdefault(name, {"busy_s": 0.0, "count": 0})
         row["busy_s"] += s.get("dur_us", 0.0) / 1e6
         row["count"] += 1
-        if (s.get("args") or {}).get("estimated"):
-            estimated = True
     for row in stages.values():
         row["busy_s"] = round(row["busy_s"], 6)
     busy = {tid: round(_union_us(ss) / 1e6, 6) for tid, ss in lanes.items()}
@@ -334,7 +331,6 @@ def _device_table(device_spans: list[dict], host_spans: list[dict]) -> dict:
     drain_us = _union_us(drains)
     overlap_us = _intersect_us(device_ivals, drain_ivals)
     return {
-        "estimated": estimated,
         "lane_busy_s": busy,
         "stages": stages,
         "drain_s": round(drain_us / 1e6, 6),
@@ -510,8 +506,7 @@ def render(table: dict, title: str) -> str:
         )
     dev = table.get("device")
     if dev:
-        tag = "estimated" if dev["estimated"] else "measured"
-        out.append(f"\nDevice lanes ({tag}):")
+        out.append("\nDevice lanes:")
         out.append(
             _table(
                 [
