@@ -508,18 +508,24 @@ def use_pallas_resample(geom: SearchGeometry) -> bool:
 
 
 def use_pallas_resident(geom: SearchGeometry) -> bool:
-    """Opt-in gate for the resident resample->FFT-prep chain
-    (``ops/pallas_resample.py::resample_fftprep_pallas_batch``):
-    ``ERP_PALLAS_RESIDENT=1`` AND the same geometry contract as the
-    two-stage fused resampler.  Supersedes ``ERP_PALLAS_RESAMPLE`` when
-    both are set (the resident chain contains the resampler).  Off by
-    default pending the on-chip A/B — same rollout shape as
-    :func:`use_pallas_resample`."""
+    """Gate for the resident resample->FFT-prep chain
+    (``ops/pallas_resample.py::resample_fftprep_pallas_batch``), the
+    step's resampler on a TPU backend wherever the geometry meets the
+    kernel's contract: parity split, the LUT sine, the device mean (not
+    ``exact_mean``) and ``pallas_applicable``.  Steep orbits, the exact
+    sine and unwhitened (``exact_mean``) searches keep the XLA resampler,
+    as does every other backend unless ``ERP_PALLAS_RESIDENT=1`` forces
+    the chain there (interpret-mode CPU tests, deviceless compiles for a
+    described TPU).  Supersedes ``ERP_PALLAS_RESAMPLE`` (the resident
+    chain contains the resampler)."""
     import os
 
-    if os.environ.get("ERP_PALLAS_RESIDENT") != "1":
-        return False
     if not (geom.parity_split and geom.use_lut and not geom.exact_mean):
+        return False
+    if (
+        os.environ.get("ERP_PALLAS_RESIDENT") != "1"
+        and jax.default_backend() != "tpu"
+    ):
         return False
     from ..ops.pallas_resample import pallas_applicable
 
@@ -871,10 +877,15 @@ def make_bank_step(
     With ``with_health`` the step additionally returns the
     :func:`batch_health_vec` float32[4] device scalars — the numerical-
     health watchdog's per-batch feed (``runtime/health.py``); donation
-    and the (M, T) contract are unchanged.  ``allow_pallas=False`` forces
-    the XLA path even when the Pallas resampler and/or the fused
-    sumspec fold are enabled and applicable — the degradation ladder's
-    fallback rung (``runtime/resilience.py``).
+    and the (M, T) contract are unchanged.
+
+    The resampler is the resident Pallas chain
+    (``resample_fftprep_pallas_batch``) wherever
+    :func:`use_pallas_resident` admits the geometry — by default on a
+    TPU — and the XLA gather (``ops/resample.py``) elsewhere.
+    ``allow_pallas=False`` forces the XLA path even when the Pallas
+    resampler and/or the fused sumspec fold are enabled and applicable —
+    the degradation ladder's fallback rung (``runtime/resilience.py``).
 
     On TPU the jitted step additionally pins explicit row-major device
     layouts on every array operand and result (:func:`bank_step_layouts`):
@@ -886,6 +897,7 @@ def make_bank_step(
     per_template = template_sumspec_fn(geom)
     per_ps = template_ps_fn(geom)
     fused = allow_pallas and use_pallas_sumspec(geom)
+    resident = allow_pallas and use_pallas_resident(geom)
     interpret = _pallas_interpret()
     batch_sums = _fused_sums_fn(geom, interpret) if fused else None
 
@@ -894,16 +906,21 @@ def make_bank_step(
         if jax.default_backend() != "tpu":
             # explicit layouts exist to stop TPU relayout copies; on CPU
             # they would only constrain the compiler for no gain
-            return jax.jit(step, donate_argnums=donate)
-        in_sh, out_sh = bank_step_layouts(
-            geom, with_health, jax.devices()[0]
-        )
-        return jax.jit(
-            step,
-            donate_argnums=donate,
-            in_shardings=in_sh,
-            out_shardings=out_sh,
-        )
+            jitted = jax.jit(step, donate_argnums=donate)
+        else:
+            in_sh, out_sh = bank_step_layouts(
+                geom, with_health, jax.devices()[0]
+            )
+            jitted = jax.jit(
+                step,
+                donate_argnums=donate,
+                in_shardings=in_sh,
+                out_shardings=out_sh,
+            )
+        # the resampler the step was built with, which the dispatch loop
+        # counts (search.templates_resident in _run_bank_attempt)
+        jitted.resident = resident
+        return jitted
 
     def merge(sums, valid, t_offset, M, T):
         with stage_scope("merge"):
@@ -922,7 +939,6 @@ def make_bank_step(
             sl = lambda a: jax.lax.dynamic_slice_in_dim(a, t_offset, B)
             return sl(btau), sl(bomega), sl(bpsi0), sl(bs0)
 
-    resident = allow_pallas and use_pallas_resident(geom)
     if resident or (allow_pallas and use_pallas_resample(geom)):
         from ..ops.pallas_resample import (
             resample_fftprep_pallas_batch,
@@ -930,7 +946,7 @@ def make_bank_step(
         )
 
         # resident chain: the resampled series goes straight to FFT-prep
-        # layout in VMEM (ERP_PALLAS_RESIDENT=1); both variants fold the
+        # layout in VMEM (use_pallas_resident); both variants fold the
         # deferred whitening renorm into the gather when the driver
         # shipped an unscaled series (geom.ts_prescaled=False)
         resample_fn = (
@@ -1151,6 +1167,7 @@ def run_bank(
     progress_cb=None,
     lookahead: int = 2,
     step_cache=None,
+    allow_pallas: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Resilient wrapper around the async dispatch loop; returns (M, T).
 
@@ -1174,6 +1191,9 @@ def run_bank(
     one cache across workunits so same-geometry searches skip both the
     retrace and the compile.  ``None`` (the default, and the one-process-
     per-WU driver path) rebuilds the step per call, exactly as before.
+
+    ``allow_pallas=False`` runs the whole range on the ladder's XLA rung
+    (:func:`make_bank_step`), whatever the Pallas gates say.
     """
     from ..runtime import resilience
 
@@ -1184,14 +1204,16 @@ def run_bank(
             state=state, start_template=start_template,
             stop_template=stop_template,
             progress_cb=progress_cb, lookahead=lookahead,
-            step_cache=step_cache,
+            step_cache=step_cache, allow_pallas=allow_pallas,
         )
     snap = resilience.DispatchSnapshot(state, start_template)
     ladder = resilience.DegradationLadder(
         pol, batch_size,
-        pallas_active=use_pallas_resample(geom)
-        or use_pallas_resident(geom)
-        or use_pallas_sumspec(geom),
+        pallas_active=allow_pallas and (
+            use_pallas_resample(geom)
+            or use_pallas_resident(geom)
+            or use_pallas_sumspec(geom)
+        ),
     )
     cur_state, cur_start = state, start_template
     while True:
@@ -1201,7 +1223,8 @@ def run_bank(
                 batch_size=ladder.batch_size, state=cur_state,
                 start_template=cur_start, stop_template=stop_template,
                 progress_cb=progress_cb,
-                lookahead=lookahead, allow_pallas=ladder.allow_pallas,
+                lookahead=lookahead,
+                allow_pallas=allow_pallas and ladder.allow_pallas,
                 snapshot=snap, step_cache=step_cache,
             )
         except Exception as e:
@@ -1333,6 +1356,11 @@ def _run_bank_attempt(
     # reads per batch either way (runtime/metrics.py)
     m_batches = metrics.counter("search.batches")
     m_templates = metrics.counter("search.templates")
+    # the share of templates the resident Pallas chain resampled: 0 where
+    # the geometry, the backend or the ladder's fallback rung keeps XLA
+    # (a wrapped step without the attribute counts as XLA)
+    m_resident = metrics.counter("search.templates_resident")
+    resident = getattr(step, "resident", False)
     m_dispatch_s = metrics.counter("search.dispatch_wall_s", unit="s")
     m_stall_s = metrics.counter("search.drain_stall_s", unit="s")
     m_prefetch_s = metrics.counter("search.prefetch_wait_s", unit="s")
@@ -1395,6 +1423,8 @@ def _run_bank_attempt(
             m_occupancy.observe(inflight)
             m_batches.inc()
             m_templates.inc(stop - start)
+            if resident:
+                m_resident.inc(stop - start)
             flightrec.record(
                 "dispatch", start=start, stop=stop,
                 ms=round(dt_dispatch * 1e3, 3),

@@ -1,4 +1,4 @@
-"""Fused parity-stream resampler as a single Pallas TPU kernel (candidate).
+"""Fused parity-stream resampler as a single Pallas TPU kernel.
 
 The XLA formulation (``ops/resample.py::resample_split``) builds the
 modulated index map, the per-block windows (vmapped dynamic slices) and the
@@ -11,21 +11,27 @@ DMAs one window from each parity half of the time series into VMEM and
 never touches HBM again until the output store.  HBM traffic per template
 drops to ~read-ts-once + write-out-once.
 
-Status: OPT-IN CANDIDATE, not wired into the production model.  The
-numerics transcribe ``_blocked_select_gather_split`` + ``_parity_stream``
-op for op (same float32 sequence), and ``tests/test_pallas_resample.py``
-proves bit-parity against the XLA path in interpret mode; Mosaic's
-codegen on real hardware may still contract differently than XLA-TPU, so
-adoption requires the on-chip A/B (``tools/pallas_ab.py``) plus the golden
-gates — the same measure-first bar that retired the Pallas median in r03.
+Status: the resident chain (``resample_fftprep_pallas_batch``) is the
+production step's resampler on a TPU backend wherever the geometry fits
+(``models/search.py::use_pallas_resident``); other backends keep the XLA
+resampler unless ``ERP_PALLAS_RESIDENT=1`` forces the chain, and the XLA
+path stays the degradation ladder's fallback rung and the path of
+geometries the gates below refuse.  The numerics transcribe
+``_blocked_select_gather_split`` + ``_parity_stream`` op for op (same
+float32 sequence), and ``tests/test_pallas_resample.py`` proves
+bit-parity against the XLA path in interpret mode.  Mosaic's codegen on
+the chip contracts a little differently from XLA-TPU: on one v5e the
+batch's (M, T) agreed with the XLA step's to 5.7e-7 relative, with
+identical candidates.
 
 Applicability gates (checked by ``pallas_applicable``): the fixed kernel
 block ``B_BLK`` must honor the select-window and LUT-window contracts for
 the geometry's static bounds, and the tiled sine table must fit VMEM.
 
 Template batching: ``resample_split_pallas_batch`` runs the whole batch
-as one launch over the grid (T, parity, block) — this is what the model's
-``ERP_PALLAS_RESAMPLE=1`` path uses; plain ``jax.vmap`` of the
+as one launch over the grid (T, parity, block) — pass 1 of the resident
+chain, and the model's two-stage ``ERP_PALLAS_RESAMPLE=1`` path; plain
+``jax.vmap`` of the
 single-template call also works (verified bit-equal) and lowers to the
 same batched grid.
 """
@@ -836,8 +842,9 @@ def resample_fftprep_pallas_batch(
     renorm: float | None = None,
     interpret: bool = False,
 ):
-    """Resident resample -> FFT-prep chain (``ERP_PALLAS_RESIDENT=1``):
-    pass 1 is the same batched stream launch as
+    """Resident resample -> FFT-prep chain, the bank step's resampler on
+    TPU where ``models/search.py::use_pallas_resident`` admits the
+    geometry: pass 1 is the same batched stream launch as
     ``resample_split_pallas_batch``; the only XLA ops between the kernels
     are the O(T) stream statistics (n_steps, mean), and pass 2
     (``_fftprep_kernel``) re-reads each raw tile once to emit the padded,

@@ -19,14 +19,16 @@ Phases, in order (each prints its wall seconds):
 * whiten     -- the same WU whitened once more in-process (the Pallas arm and
                 the oracle reuse it);
 * steady     -- the full bank through ``run_bank`` twice (the second run
-                times the warm step);
+                times the warm step): the production step, whose resampler
+                is the resident Pallas chain on a TPU;
 * oracle     -- the first 10 templates (null and injected among them) on the
                 chip vs the host float64 oracle: recall >= the floor of
                 ``PRECISION_BASELINE.json``;
 * profile    -- one short profiler window: device records per erp.* stage;
 * pallas     -- the full bank again with the fused sumspec and resident
-                resample kernels compiled for the chip: candidates
-                identical to the XLA arm, no fallback;
+                resample kernels compiled for the chip, and once on the
+                degradation ladder's XLA rung (``allow_pallas=False``):
+                candidates identical to the XLA rung's, no fallback;
 * served     -- the WU twice through ``serving.FleetServer``: both results
                 byte-identical to the driver's, zero recompiles the second
                 time.
@@ -113,8 +115,6 @@ class Ctx:
     cfg: object = None
     white: np.ndarray | None = None
     batch: int = 0
-    default_lines: list | None = None
-    default_state: tuple | None = None
     main_out: str = ""
     step_cache: dict = dataclasses.field(default_factory=dict)
 
@@ -169,7 +169,10 @@ def file_candidate_lines(path: str) -> list[str]:
     return [ln if ln.endswith("\n") else ln + "\n" for ln in lines]
 
 
-def run_bank(ctx: Ctx, stop: int | None = None, progress_cb=None):
+def run_bank(ctx: Ctx, stop: int | None = None, progress_cb=None,
+             allow_pallas: bool = True):
+    """The bank through the production dispatch loop; ``allow_pallas=False``
+    runs the degradation ladder's XLA rung instead."""
     import jax
 
     from boinc_app_eah_brp_tpu.models.search import run_bank as _run_bank
@@ -178,6 +181,7 @@ def run_bank(ctx: Ctx, stop: int | None = None, progress_cb=None):
     M, T = _run_bank(
         ctx.white, P, tau, psi, ctx.geom, batch_size=ctx.batch,
         stop_template=stop, progress_cb=progress_cb, step_cache=ctx.step_cache,
+        allow_pallas=allow_pallas,
     )
     return jax.block_until_ready((M, T))
 
@@ -298,6 +302,12 @@ def phase_main(ctx: Ctx) -> None:
     check(counter(report, "checkpoint.count") >= 1, "no checkpoint written")
     for name in ("resilience.pallas_fallback", "resilience.batch_halved"):
         check(counter(report, name) == 0, f"{name} = {counter(report, name)}")
+    # on a TPU the shipped geometry fits the resident chain: every template
+    # goes through it; the tiny CPU bank keeps the XLA resampler
+    n_res = counter(report, "search.templates_resident")
+    on_tpu = ctx.device is not None and ctx.device["platform"] == "tpu"
+    want = counter(report, "search.templates") if on_tpu else 0
+    check(n_res == want, f"search.templates_resident = {n_res}, not {want}")
     phases = report["metrics"]["phases"]
     ctx.info.update(
         main_compile_s=counter(report, "jax.compile_time_s"),
@@ -356,9 +366,8 @@ def phase_steady(ctx: Ctx) -> None:
     M, T = run_bank(ctx)
     wall = time.perf_counter() - t0
     ctx.info["steady_templates_per_s"] = ctx.shape.n_bank / wall
-    ctx.default_state = (np.asarray(M), np.asarray(T))
-    ctx.default_lines = candidate_lines(ctx, M, T)
-    check(ctx.default_lines, "the XLA arm emitted no candidates")
+    check(candidate_lines(ctx, M, T),
+          "the production step emitted no candidates")
 
 
 def phase_oracle(ctx: Ctx) -> None:
@@ -400,7 +409,8 @@ def phase_oracle(ctx: Ctx) -> None:
 
 def phase_pallas(ctx: Ctx, interpret: bool = False) -> None:
     """The full bank with the fused sumspec fold and the resident
-    resample->fftprep kernels, compiled for the device (interpret mode off)."""
+    resample->fftprep kernels, compiled for the device (interpret mode off),
+    against the degradation ladder's XLA rung."""
     import jax
 
     from boinc_app_eah_brp_tpu.models.search import (
@@ -436,8 +446,10 @@ def phase_pallas(ctx: Ctx, interpret: bool = False) -> None:
             else:
                 os.environ[k] = v
         jax.clear_caches()
+    M0, T0 = (np.asarray(a) for a in run_bank(ctx, allow_pallas=False))
+    xla_lines = candidate_lines(ctx, M0, T0)
     lines = candidate_lines(ctx, M, T)
-    (M0, T0), M, T = ctx.default_state, np.asarray(M), np.asarray(T)
+    M, T = np.asarray(M), np.asarray(T)
     ctx.info["pallas_state_bit_identical"] = bool(
         np.array_equal(M0, M) and np.array_equal(T0, T)
     )
@@ -447,11 +459,11 @@ def phase_pallas(ctx: Ctx, interpret: bool = False) -> None:
         "M_max_rel": float(np.max(np.abs(M - M0) / np.maximum(np.abs(M0), 1e-30))),
         "entries": int(M0.size),
     }
-    if lines != ctx.default_lines:
-        diff = sum(a != b for a, b in zip(lines, ctx.default_lines))
+    if lines != xla_lines:
+        diff = sum(a != b for a, b in zip(lines, xla_lines))
         raise SmokeError(
-            f"Pallas candidates differ from the XLA arm: {len(lines)} vs "
-            f"{len(ctx.default_lines)} lines, {diff} differing"
+            f"Pallas candidates differ from the XLA rung's: {len(lines)} vs "
+            f"{len(xla_lines)} lines, {diff} differing"
         )
 
 
@@ -503,7 +515,9 @@ def phase_profile(ctx: Ctx) -> None:
 def phase_four_chips(ctx: Ctx, n_dev: int = 4) -> None:
     """The driver with ``--mesh n_dev`` vs the single-device ``run_bank`` on
     device 0 in this process: (M, T) bit-identical, candidates identical,
-    and every device of the mesh holding the series, bank and state."""
+    and every device of the mesh holding the series, bank and state.  The
+    mesh step keeps the XLA resampler, so device 0 runs the ladder's XLA
+    rung too: the same algorithm, split across chips or not."""
     import jax
 
     from boinc_app_eah_brp_tpu.parallel import make_mesh, run_bank_sharded
@@ -520,7 +534,7 @@ def phase_four_chips(ctx: Ctx, n_dev: int = 4) -> None:
     from boinc_app_eah_brp_tpu.runtime.autobatch import choose_batch
 
     ctx.batch = choose_batch(ctx.geom.nsamples)
-    M1, T1 = run_bank(ctx)
+    M1, T1 = run_bank(ctx, allow_pallas=False)
     M1, T1 = np.asarray(M1), np.asarray(T1)
 
     seen = {}

@@ -1,7 +1,6 @@
 """Fused Pallas resampler (ops/pallas_resample.py): interpret-mode
-bit-parity against the production XLA path.  This is the correctness half
-of the measure-first bar; adoption additionally needs the on-chip A/B
-(tools/pallas_ab.py)."""
+bit-parity against the XLA path, and the gate that makes the resident
+chain the bank step's resampler on TPU where the geometry fits."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -263,8 +262,8 @@ def test_resident_gates(monkeypatch):
         derived, max_slope=0.5, lut_step=LUT_STEP
     )
     monkeypatch.delenv("ERP_PALLAS_RESIDENT", raising=False)
-    assert not use_pallas_resident(geom_ok)  # opt-in: off by default
-    monkeypatch.setenv("ERP_PALLAS_RESIDENT", "1")
+    assert not use_pallas_resident(geom_ok)  # CPU backend: XLA by default
+    monkeypatch.setenv("ERP_PALLAS_RESIDENT", "1")  # forces it off-TPU
     assert use_pallas_resident(geom_ok)
     assert not use_pallas_resident(geom_steep)  # select span gate
     # the driver defers whitening renorm only when the packed cascade FFT
@@ -275,6 +274,45 @@ def test_resident_gates(monkeypatch):
     assert resident_defers_renorm(geom_ok)
     monkeypatch.delenv("ERP_PALLAS_RESIDENT", raising=False)
     assert not resident_defers_renorm(geom_ok)  # gate off => no deferral
+
+
+def test_resident_gate_is_on_by_default_on_tpu(monkeypatch):
+    """On a TPU backend the resident chain needs no environment: it is
+    the step's resampler wherever the geometry fits, and the XLA
+    resampler where it does not (steep orbits, unwhitened exact_mean).
+    The CPU backend keeps XLA, and the step's residency key tells the
+    two apart."""
+    import dataclasses
+
+    import jax
+
+    from boinc_app_eah_brp_tpu.models.search import (
+        resident_defers_renorm,
+        step_cache_key,
+        use_pallas_resident,
+    )
+
+    for env in ("ERP_PALLAS_RESAMPLE", "ERP_PALLAS_RESIDENT",
+                "ERP_PALLAS_SUMSPEC", "ERP_FORCE_CASCADE"):
+        monkeypatch.delenv(env, raising=False)
+    geom_ok, _, _ = _prod_geom(1 << 13)
+    geom_steep = dataclasses.replace(geom_ok, max_slope=0.5)
+    geom_exact = dataclasses.replace(geom_ok, exact_mean=True)
+
+    assert not use_pallas_resident(geom_ok)
+    assert not resident_defers_renorm(geom_ok)
+    k_cpu = step_cache_key(geom_ok, 4, False, True)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert use_pallas_resident(geom_ok)
+    assert not use_pallas_resident(geom_steep)
+    assert not use_pallas_resident(geom_exact)
+    # the TPU's packed whitening path hands its renorm to the kernel
+    assert resident_defers_renorm(geom_ok)
+    k_tpu = step_cache_key(geom_ok, 4, False, True)
+    assert k_tpu != k_cpu
+    # the ladder's XLA rung keys apart from the resident step
+    assert step_cache_key(geom_ok, 4, False, False) != k_tpu
 
 
 def test_fftprep_is_registered_stage():
@@ -663,3 +701,56 @@ def test_run_bank_resident_fallback_is_byte_identical(monkeypatch):
         resilience._run_policy = None  # don't leak spent budget
     np.testing.assert_array_equal(np.asarray(M), np.asarray(M_ref))
     np.testing.assert_array_equal(np.asarray(T), np.asarray(T_ref))
+
+
+def test_run_bank_counts_resident_templates(monkeypatch):
+    """search.templates_resident counts the templates dispatched through a
+    step built on the resident chain (the step says which it was built
+    with), and ``run_bank(allow_pallas=False)`` runs the ladder's XLA rung:
+    no resident template, and the same (M, T) bits as the chain on this
+    backend."""
+    from boinc_app_eah_brp_tpu.models.search import (
+        SearchGeometry,
+        lut_step_for_bank,
+        make_bank_step,
+        max_slope_for_bank,
+        run_bank,
+    )
+    from boinc_app_eah_brp_tpu.oracle.pipeline import DerivedParams, SearchConfig
+    from boinc_app_eah_brp_tpu.runtime import metrics
+
+    n = 4096
+    ts = synthetic_timeseries(
+        n, f_signal=33.0, P_orb=400.0, tau=0.1, psi0=1.2, amp=7.0
+    )
+    bank = _fitted_bank()
+    derived = DerivedParams.derive(n, 500.0, SearchConfig(window=200))
+    geom = SearchGeometry.from_derived(
+        derived,
+        max_slope=max_slope_for_bank(bank.P, bank.tau),
+        lut_step=lut_step_for_bank(bank.P, derived.dt),
+    )
+    monkeypatch.setenv("ERP_PALLAS_RESIDENT", "1")
+    assert make_bank_step(geom, 3).resident
+    assert not make_bank_step(geom, 3, allow_pallas=False).resident
+
+    def counts():
+        c = metrics.snapshot()["counters"]
+        return tuple(
+            (c.get(k) or {}).get("value", 0)
+            for k in ("search.templates", "search.templates_resident")
+        )
+
+    assert metrics.configure(force=True)
+    try:
+        M1, T1 = run_bank(ts, bank.P, bank.tau, bank.psi0, geom, batch_size=3)
+        assert counts() == (4, 4)
+        M0, T0 = run_bank(
+            ts, bank.P, bank.tau, bank.psi0, geom, batch_size=3,
+            allow_pallas=False,
+        )
+        assert counts() == (8, 4)
+    finally:
+        metrics.finish(0)
+    np.testing.assert_array_equal(np.asarray(M1), np.asarray(M0))
+    np.testing.assert_array_equal(np.asarray(T1), np.asarray(T0))

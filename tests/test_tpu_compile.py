@@ -8,6 +8,7 @@ The topology is described only inside the module fixture: one process at a
 time may load the TPU library, so no import-time call and one file."""
 
 import os
+import re
 import sys
 
 import numpy as np
@@ -146,6 +147,93 @@ def test_layout_pinned_bank_step(topo, monkeypatch):
 
     stages = {stage_of_op_name(v) for v in hlo_op_scopes(text).values()}
     assert {"resample", "fft", "sumspec", "merge"} <= stages, stages
+    in_f, _ = comp.input_formats
+    for f in (in_f[7], in_f[8], *comp.output_formats):
+        assert f.layout.major_to_minor == (0, 1)
+
+
+_OPCODE_RE = re.compile(r" = .*? ([a-z][a-z0-9-]*)\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+# what XLA lowers the resampler's gather to: a while loop of
+# dynamic-slice / dynamic-update-slice, one iteration per output block
+_GATHER_LOOP_OPS = {"while", "dynamic-slice", "dynamic-update-slice"}
+
+
+def _ops_by_stage(text):
+    """{stage: {opcode, ...}} over the compiled module's instructions,
+    and the stages of its Mosaic kernels."""
+    from boinc_app_eah_brp_tpu.runtime.devicecost import stage_of_op_name
+
+    ops, kernels = {}, set()
+    for line in text.splitlines():
+        name, code = _OP_NAME_RE.search(line), _OPCODE_RE.search(line)
+        if name is None or code is None:
+            continue
+        stage = stage_of_op_name(name.group(1))
+        ops.setdefault(stage, set()).add(code.group(1))
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels.add(stage)
+    return ops, kernels
+
+
+@pytest.mark.parametrize("f0,padding,fA", [
+    (400.0, 3.0, 0.08),  # palfa_p3: FFT length 3 * 2^22
+    (250.0, 1.0, 0.04),  # refdefault_p1: FFT length 2^22
+])
+def test_resident_bank_step_at_production_widths(topo, monkeypatch, f0,
+                                                 padding, fA):
+    """The resident resample -> FFT-prep chain inside a donated,
+    layout-pinned bank step at the shipped WU's widths (the gate forced,
+    since this backend is the CPU), with the whitening renorm folded into
+    the kernel as the Session defers it.  Both kernels are there, the XLA
+    resampler's gather loop is not, and (M, T) stay row-major.  This is
+    not the whole production step: its harmonic sum here is the fused
+    fold kernel (``ERP_PALLAS_SUMSPEC=1``), where a TPU runs the XLA
+    harmonic sum by default, because that sum's deviceless compile at
+    these widths takes ~2 min and ~14 GB of host memory a case.  The
+    assertions read only the resampler's scopes and the state's layouts."""
+    import dataclasses
+
+    from boinc_app_eah_brp_tpu.models.search import (
+        SearchGeometry,
+        bank_step_layouts,
+        init_state,
+        lut_step_for_bank,
+        make_bank_step,
+        max_slope_for_bank,
+        resident_defers_renorm,
+        upload_bank,
+        use_pallas_resident,
+    )
+    from boinc_app_eah_brp_tpu.oracle.pipeline import DerivedParams, SearchConfig
+
+    monkeypatch.setenv("ERP_PALLAS_RESIDENT", "1")
+    monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
+    cfg = SearchConfig(f0=f0, padding=padding, fA=fA, window=1000, white=True)
+    derived = DerivedParams.derive(1 << 22, 65.476, cfg)
+    P, tau = np.array([660.0, 2231.0]), np.array([0.335, 0.0])  # PALFA
+    geom = SearchGeometry.from_derived(
+        derived, max_slope=max_slope_for_bank(P, tau),
+        lut_step=lut_step_for_bank(P, derived.dt),
+    )
+    assert use_pallas_resident(geom) and resident_defers_renorm(geom)
+    geom = dataclasses.replace(geom, ts_prescaled=False)
+    bank = upload_bank(tuple(np.zeros(8, np.float32) for _ in range(4)), BATCH)
+    M, T = jax.eval_shape(lambda: init_state(geom))
+    S = jax.ShapeDtypeStruct
+    ts = tuple(S((geom.n_unpadded // 2,), jnp.float32) for _ in range(2))
+
+    fn = make_bank_step(geom, batch_size=BATCH).__wrapped__
+    in_sh, out_sh = bank_step_layouts(geom, False, topo.devices[0])
+    comp = jax.jit(
+        fn, donate_argnums=(7, 8), in_shardings=in_sh, out_shardings=out_sh
+    ).lower(
+        ts, *(S(a.shape, a.dtype) for a in bank), S((), jnp.int32),
+        S((), jnp.int32), M, T,
+    ).compile()
+    ops, kernels = _ops_by_stage(comp.as_text())
+    assert {"resample", "fftprep"} <= kernels, kernels
+    assert not ops["resample"] & _GATHER_LOOP_OPS, ops["resample"]
     in_f, _ = comp.input_formats
     for f in (in_f[7], in_f[8], *comp.output_formats):
         assert f.layout.major_to_minor == (0, 1)
