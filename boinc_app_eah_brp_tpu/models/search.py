@@ -559,6 +559,17 @@ def use_pallas_sumspec(geom: SearchGeometry) -> bool:
     return sumspec_applicable(geom.fund_hi, geom.harm_hi)
 
 
+def uses_pallas(geom: SearchGeometry) -> bool:
+    """Whether a bank step built for ``geom`` runs a Pallas kernel (any
+    of the three gates above): what the degradation ladder's Pallas rung
+    watches in ``run_bank`` and ``run_bank_sharded``."""
+    return (
+        use_pallas_resample(geom)
+        or use_pallas_resident(geom)
+        or use_pallas_sumspec(geom)
+    )
+
+
 def _pallas_interpret() -> bool:
     """Whether Pallas kernels should lower in interpret mode.  Mosaic
     compiles only for TPU; on CPU (tests, oracle runs) interpret mode is
@@ -663,127 +674,26 @@ def make_batch_step(geom: SearchGeometry):
     device — and keeps this step as the synchronous reference for the
     equivalence tests (``tests/test_async_pipeline.py``) and the A/B
     tooling (bench legacy mode, ``tools/pallas_ab.py``).  No state
-    donation here: A/B callers reuse one (M, T) across step variants."""
+    donation here: A/B callers reuse one (M, T) across step variants.
+
+    The per-batch body is :func:`bank_batch_sums`, as in every bank step,
+    over the batch's own arrays (offset 0, every slot valid); it is built
+    when the step is traced for a batch size, so the Pallas gates are read
+    then."""
 
     erp_precision()  # bf16 requests fail at construction, not mid-run
-    per_template = template_sumspec_fn(geom)
-    per_ps = template_ps_fn(geom)
-    fused = use_pallas_sumspec(geom)
-    interpret = _pallas_interpret()
-    batch_sums = _fused_sums_fn(geom, interpret) if fused else None
-
-    resident = use_pallas_resident(geom)
-    if resident or use_pallas_resample(geom):
-        from ..ops.pallas_resample import (
-            resample_fftprep_pallas_batch,
-            resample_split_pallas_batch,
-        )
-
-        # the resident chain emits the padded mean-filled series straight
-        # from VMEM (bitwise identical to the two-stage form); both fold
-        # the deferred whitening renorm into the gather when the driver
-        # shipped an unscaled series (geom.ts_prescaled=False)
-        resample_fn = (
-            resample_fftprep_pallas_batch
-            if resident
-            else resample_split_pallas_batch
-        )
-        renorm = _ts_renorm(geom)
-
-        @jax.jit
-        def step(ts_args, tau, omega, psi0, s0, t_offset, M, T):
-            ev, od = resample_fn(
-                ts_args[0],
-                ts_args[1],
-                tau,
-                omega,
-                psi0,
-                s0,
-                nsamples=geom.nsamples,
-                n_unpadded=geom.n_unpadded,
-                dt=geom.dt,
-                max_slope=geom.max_slope,
-                lut_step=geom.lut_step,
-                lut_tiles=geom.lut_tiles,
-                renorm=renorm,
-                interpret=interpret,
-            )
-            if fused:
-                ps = jax.vmap(
-                    lambda e, o: power_spectrum_split(
-                        e, o, nsamples=geom.nsamples
-                    )
-                )(ev, od)
-                sums = batch_sums(ps)  # (B, 5, W)
-            else:
-                sums = jax.vmap(
-                    lambda e, o: harmonic_sumspec(
-                        power_spectrum_split(e, o, nsamples=geom.nsamples),
-                        window_2=geom.window_2,
-                        fund_hi=geom.fund_hi,
-                        harm_hi=geom.harm_hi,
-                        natural=False,
-                    )
-                )(ev, od)  # (B, 5, W)
-            with stage_scope("merge"):
-                bmax = jnp.max(sums, axis=0)
-                barg = jnp.argmax(sums, axis=0).astype(jnp.int32)
-                better = bmax > M
-                T = jnp.where(better, t_offset + barg, T)
-                M = jnp.where(better, bmax, M)
-            return M, T
-
-        return step
-
-    prep = _prep_ts_fn(geom)
-
-    if geom.exact_mean:
-
-        @jax.jit
-        def step(ts_args, tau, omega, psi0, s0, t_offset, M, T, n_steps, mean):
-            ts_args = prep(ts_args)
-            if fused:
-                ps = jax.vmap(
-                    lambda a, b, c, d, ns, mn: per_ps(
-                        ts_args, a, b, c, d, ns, mn
-                    )
-                )(tau, omega, psi0, s0, n_steps, mean)
-                sums = batch_sums(ps)  # (B, 5, W)
-            else:
-                sums = jax.vmap(
-                    lambda a, b, c, d, ns, mn: per_template(
-                        ts_args, a, b, c, d, ns, mn
-                    )
-                )(tau, omega, psi0, s0, n_steps, mean)  # (B, 5, W)
-            with stage_scope("merge"):
-                bmax = jnp.max(sums, axis=0)
-                barg = jnp.argmax(sums, axis=0).astype(jnp.int32)
-                better = bmax > M
-                T = jnp.where(better, t_offset + barg, T)
-                M = jnp.where(better, bmax, M)
-            return M, T
-
-        return step
 
     @jax.jit
-    def step(ts_args, tau, omega, psi0, s0, t_offset, M, T):
-        ts_args = prep(ts_args)
-        if fused:
-            ps = jax.vmap(lambda a, b, c, d: per_ps(ts_args, a, b, c, d))(
-                tau, omega, psi0, s0
-            )
-            sums = batch_sums(ps)  # (B, 5, W)
-        else:
-            sums = jax.vmap(
-                lambda a, b, c, d: per_template(ts_args, a, b, c, d)
-            )(tau, omega, psi0, s0)  # (B, 5, W)
+    def step(ts_args, tau, omega, psi0, s0, t_offset, M, T, *exact):
+        B = tau.shape[0]
+        body = bank_batch_sums(geom, B)
+        sums, valid = body(ts_args, tau, omega, psi0, s0, jnp.int32(0),
+                           jnp.int32(B), *exact)
+        bmax, barg = block_reduce(sums, valid)
         with stage_scope("merge"):
-            bmax = jnp.max(sums, axis=0)
-            barg = jnp.argmax(sums, axis=0).astype(jnp.int32)  # first max in batch
             better = bmax > M
-            T = jnp.where(better, t_offset + barg, T)
-            M = jnp.where(better, bmax, M)
-        return M, T
+            return (jnp.where(better, bmax, M),
+                    jnp.where(better, t_offset + barg, T))
 
     return step
 
@@ -879,7 +789,8 @@ def make_bank_step(
     health watchdog's per-batch feed (``runtime/health.py``); donation
     and the (M, T) contract are unchanged.
 
-    The resampler is the resident Pallas chain
+    The per-batch body is :func:`bank_batch_sums`, which the mesh's step
+    shares: its resampler is the resident Pallas chain
     (``resample_fftprep_pallas_batch``) wherever
     :func:`use_pallas_resident` admits the geometry — by default on a
     TPU — and the XLA gather (``ops/resample.py``) elsewhere.
@@ -892,14 +803,8 @@ def make_bank_step(
     the donated (M, T) state and the bank arrays flow between dispatch
     windows without compiler-inserted layout copies — the
     "compiler-generated" bucket of ``COST_LEDGER.json``."""
-    B = int(batch_size)
     erp_precision()  # bf16 requests fail at construction, not mid-run
-    per_template = template_sumspec_fn(geom)
-    per_ps = template_ps_fn(geom)
-    fused = allow_pallas and use_pallas_sumspec(geom)
-    resident = allow_pallas and use_pallas_resident(geom)
-    interpret = _pallas_interpret()
-    batch_sums = _fused_sums_fn(geom, interpret) if fused else None
+    body = bank_batch_sums(geom, batch_size, allow_pallas)
 
     def _jit(step):
         donate = (7, 8)
@@ -919,14 +824,12 @@ def make_bank_step(
             )
         # the resampler the step was built with, which the dispatch loop
         # counts (search.templates_resident in _run_bank_attempt)
-        jitted.resident = resident
+        jitted.resident = body.resident
         return jitted
 
     def merge(sums, valid, t_offset, M, T):
+        bmax, barg = block_reduce(sums, valid)
         with stage_scope("merge"):
-            masked = jnp.where(valid[:, None, None], sums, NEG_SENTINEL)
-            bmax = jnp.max(masked, axis=0)
-            barg = jnp.argmax(masked, axis=0).astype(jnp.int32)  # first max in batch
             better = bmax > M
             Mn = jnp.where(better, bmax, M)
             Tn = jnp.where(better, t_offset + barg, T)
@@ -934,10 +837,66 @@ def make_bank_step(
             return Mn, Tn, batch_health_vec(sums, valid, Mn)
         return Mn, Tn
 
-    def slice_bank(btau, bomega, bpsi0, bs0, t_offset):
+    def step(ts_args, btau, bomega, bpsi0, bs0, t_offset, n_total, M, T,
+             *exact):
+        # exact: the host-exact (n_steps, mean) iff geom.exact_mean
+        sums, valid = body(ts_args, btau, bomega, bpsi0, bs0, t_offset,
+                           n_total, *exact)
+        return merge(sums, valid, t_offset, M, T)
+
+    return _jit(step)
+
+
+def block_reduce(sums, valid):
+    """A batch's per-bin maximum ``bmax`` and the batch slot that first
+    reaches it, ``barg`` (``argmax`` resolves ties to the smallest slot).
+    Slots that are not ``valid`` (past ``n_total``) are masked to
+    :data:`NEG_SENTINEL` first, so they can never claim a bin."""
+    with stage_scope("merge"):
+        masked = jnp.where(valid[:, None, None], sums, NEG_SENTINEL)
+        bmax = jnp.max(masked, axis=0)
+        barg = jnp.argmax(masked, axis=0).astype(jnp.int32)  # first max in batch
+        return bmax, barg
+
+
+def bank_batch_sums(
+    geom: SearchGeometry, batch_size: int, allow_pallas: bool = True
+):
+    """The per-batch body of every bank step: one chip's
+    (:func:`make_bank_step`) and each shard's of the mesh
+    (``parallel/sharded_search.py::make_sharded_batch_step``).
+
+    Returns ``body(ts_args, btau, bomega, bpsi0, bs0, offset, n_total
+    [, n_steps[B], mean[B]]) -> (sums, valid)``: the ``batch_size``
+    templates from global index ``offset`` of the :func:`upload_bank`
+    arrays, each template's five harmonic-sum levels ``sums`` (B, 5, W),
+    and ``valid`` (B,), false for the slots at or past ``n_total``.  The
+    ``n_steps``/``mean`` host-exact overrides exist iff
+    ``geom.exact_mean``.
+
+    The resampler is the resident Pallas chain
+    (``resample_fftprep_pallas_batch``) wherever
+    :func:`use_pallas_resident` admits the geometry, and the XLA gather
+    (``ops/resample.py``) elsewhere; the harmonic sum is the fused fold
+    kernel where :func:`use_pallas_sumspec` admits it, and the XLA sum
+    elsewhere.  ``allow_pallas=False`` takes the XLA resampler and sum
+    whatever the gates say: the degradation ladder's fallback rung.
+    ``body.resident`` records whether the body runs the resident chain."""
+    B = int(batch_size)
+    per_template = template_sumspec_fn(geom)
+    per_ps = template_ps_fn(geom)
+    fused = allow_pallas and use_pallas_sumspec(geom)
+    resident = allow_pallas and use_pallas_resident(geom)
+    interpret = _pallas_interpret()
+    batch_sums = _fused_sums_fn(geom, interpret) if fused else None
+
+    def slice_bank(btau, bomega, bpsi0, bs0, offset):
         with stage_scope("bank-slice"):
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, t_offset, B)
+            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, offset, B)
             return sl(btau), sl(bomega), sl(bpsi0), sl(bs0)
+
+    def valid_slots(offset, n_total):
+        return offset + jnp.arange(B, dtype=jnp.int32) < n_total
 
     if resident or (allow_pallas and use_pallas_resample(geom)):
         from ..ops.pallas_resample import (
@@ -956,9 +915,9 @@ def make_bank_step(
         )
         renorm = _ts_renorm(geom)
 
-        def step(ts_args, btau, bomega, bpsi0, bs0, t_offset, n_total, M, T):
-            tau, omega, psi0, s0 = slice_bank(btau, bomega, bpsi0, bs0, t_offset)
-            valid = t_offset + jnp.arange(B, dtype=jnp.int32) < n_total
+        def body(ts_args, btau, bomega, bpsi0, bs0, offset, n_total):
+            tau, omega, psi0, s0 = slice_bank(btau, bomega, bpsi0, bs0, offset)
+            valid = valid_slots(offset, n_total)
             ev, od = resample_fn(
                 ts_args[0],
                 ts_args[1],
@@ -992,54 +951,28 @@ def make_bank_step(
                         natural=False,
                     )
                 )(ev, od)  # (B, 5, W)
-            return merge(sums, valid, t_offset, M, T)
+            return sums, valid
 
-        return _jit(step)
+    else:
+        prep = _prep_ts_fn(geom)
 
-    prep = _prep_ts_fn(geom)
-
-    if geom.exact_mean:
-
-        def step(
-            ts_args, btau, bomega, bpsi0, bs0, t_offset, n_total, M, T,
-            n_steps, mean,
-        ):
+        def body(ts_args, btau, bomega, bpsi0, bs0, offset, n_total, *exact):
+            # exact: the host-exact (n_steps, mean) iff geom.exact_mean
             ts_args = prep(ts_args)
-            tau, omega, psi0, s0 = slice_bank(btau, bomega, bpsi0, bs0, t_offset)
-            valid = t_offset + jnp.arange(B, dtype=jnp.int32) < n_total
+            tau, omega, psi0, s0 = slice_bank(btau, bomega, bpsi0, bs0, offset)
+            valid = valid_slots(offset, n_total)
             if fused:
-                ps = jax.vmap(
-                    lambda a, b, c, d, ns, mn: per_ps(
-                        ts_args, a, b, c, d, ns, mn
-                    )
-                )(tau, omega, psi0, s0, n_steps, mean)
-                sums = batch_sums(ps)  # (B, 5, W)
-            else:
-                sums = jax.vmap(
-                    lambda a, b, c, d, ns, mn: per_template(
-                        ts_args, a, b, c, d, ns, mn
-                    )
-                )(tau, omega, psi0, s0, n_steps, mean)  # (B, 5, W)
-            return merge(sums, valid, t_offset, M, T)
+                ps = jax.vmap(lambda *p: per_ps(ts_args, *p))(
+                    tau, omega, psi0, s0, *exact
+                )
+                return batch_sums(ps), valid  # (B, 5, W)
+            sums = jax.vmap(lambda *p: per_template(ts_args, *p))(
+                tau, omega, psi0, s0, *exact
+            )  # (B, 5, W)
+            return sums, valid
 
-        return _jit(step)
-
-    def step(ts_args, btau, bomega, bpsi0, bs0, t_offset, n_total, M, T):
-        ts_args = prep(ts_args)
-        tau, omega, psi0, s0 = slice_bank(btau, bomega, bpsi0, bs0, t_offset)
-        valid = t_offset + jnp.arange(B, dtype=jnp.int32) < n_total
-        if fused:
-            ps = jax.vmap(lambda a, b, c, d: per_ps(ts_args, a, b, c, d))(
-                tau, omega, psi0, s0
-            )
-            sums = batch_sums(ps)  # (B, 5, W)
-        else:
-            sums = jax.vmap(
-                lambda a, b, c, d: per_template(ts_args, a, b, c, d)
-            )(tau, omega, psi0, s0)  # (B, 5, W)
-        return merge(sums, valid, t_offset, M, T)
-
-    return _jit(step)
+    body.resident = resident
+    return body
 
 
 class ExactMeanPrefetch:
@@ -1208,12 +1141,7 @@ def run_bank(
         )
     snap = resilience.DispatchSnapshot(state, start_template)
     ladder = resilience.DegradationLadder(
-        pol, batch_size,
-        pallas_active=allow_pallas and (
-            use_pallas_resample(geom)
-            or use_pallas_resident(geom)
-            or use_pallas_sumspec(geom)
-        ),
+        pol, batch_size, pallas_active=allow_pallas and uses_pallas(geom),
     )
     cur_state, cur_start = state, start_template
     while True:

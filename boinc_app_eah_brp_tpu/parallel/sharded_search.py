@@ -3,14 +3,16 @@
 The reference runs one template at a time on one device
 (``demod_binary.c:1180-1443``); its only multi-device story is BOINC handing
 different *workunits* to different hosts. Here a global batch of ``n_dev *
-per_dev`` templates runs per step: each device slices its block of the
-device-resident parameter bank, vmaps it through the per-template pipeline,
-reduces it to per-bin (max power, first-achieving template index), and the
-shards are combined with a **recursive-doubling max/argmax all-reduce** over
-the mesh axis — ceil(log2(n)) ``ppermute`` exchanges of the tiny
-(5, fund_hi) state instead of gathering any spectra. The merged state is
-replicated, so the host sees one consistent (M, T) after every step and
-checkpointing/resume logic is identical to the single-chip path.
+per_dev`` templates runs per step: each device runs the one-chip step's
+per-batch body (``models.search.bank_batch_sums``: its block of the
+device-resident parameter bank through the resident Pallas chain on a TPU,
+or the XLA resampler), reduces it to per-bin (max power, first-achieving
+template index), and the shards are combined with a **recursive-doubling
+max/argmax all-reduce** over the mesh axis — ceil(log2(n)) ``ppermute``
+exchanges of the tiny (5, fund_hi) state instead of gathering any
+spectra. The merged state is replicated, so the host sees one consistent
+(M, T) after every step and checkpointing/resume logic is identical to
+the single-chip path.
 
 The feed contract matches ``models.search.run_bank``'s async pipeline: the
 whole bank is uploaded once (replicated), each step receives only two int32
@@ -37,21 +39,22 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.search import (
+    NEG_SENTINEL,
     ExactMeanPrefetch,
     SearchGeometry,
+    bank_batch_sums,
     bank_params_host,
+    block_reduce,
     init_state,
     prepare_ts,
-    template_sumspec_fn,
     upload_bank,
+    uses_pallas,
     validate_bank_bounds,
 )
 from ..runtime import faultinject, flightrec, metrics, tracing
 from ..runtime import watchdog as hangdog
 from ..runtime.devicecost import stage_scope
 from .mesh import TEMPLATE_AXIS
-
-_NEG = np.float32(-3.0e38)  # sentinel below any real summed power
 
 
 def _merge_take(oM, oT, M, T):
@@ -83,10 +86,15 @@ def make_sharded_batch_step(
     per_device_batch: int,
     axis_name: str = TEMPLATE_AXIS,
     with_health: bool = False,
+    allow_pallas: bool = True,
 ):
     """Jitted (ts_args, btau, bomega, bpsi0, bs0, t_offset, n_total, M, T
     [, n_steps[B], mean[B]]) -> (M, T): the sharded twin of
-    ``models.search.make_bank_step``.
+    ``models.search.make_bank_step``, around the same per-batch body
+    (``models.search.bank_batch_sums``): on a TPU, wherever
+    ``use_pallas_resident`` admits the geometry, each shard runs the
+    resident Pallas chain on its block; ``allow_pallas=False`` takes the
+    XLA rung, as on one chip.  ``step.resident`` records which.
 
     ``btau``.. are the :func:`upload_bank` device arrays of the whole bank,
     replicated over the mesh; each shard slices its ``per_device_batch``
@@ -100,34 +108,20 @@ def make_sharded_batch_step(
     consumed. The ``n_steps``/``mean`` host-exact overrides (iff
     ``geom.exact_mean``) stay per-batch sharded operands.
     """
-    per_template = template_sumspec_fn(geom)
     n_dev = mesh.shape[axis_name]
     per_dev = int(per_device_batch)
+    body = bank_batch_sums(geom, per_dev, allow_pallas)
 
     def local_step(ts_args, btau, bomega, bpsi0, bs0, t_offset, n_total,
-                   M, T, n_steps=None, mean=None):
-        # ts_args, bank, t_offset, M, T replicated; each shard slices its
-        # contiguous block of the bank
+                   M, T, *exact):
+        # ts_args, bank, t_offset, M, T replicated; each shard runs the
+        # shared body on its contiguous block of the bank
         shard = jax.lax.axis_index(axis_name).astype(jnp.int32)
         offset = t_offset + shard * per_dev
-        with stage_scope("bank-slice"):
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, offset, per_dev)
-            tau, omega, psi0, s0 = sl(btau), sl(bomega), sl(bpsi0), sl(bs0)
-        valid = offset + jnp.arange(per_dev, dtype=jnp.int32) < n_total
-        if geom.exact_mean:
-            sums = jax.vmap(
-                lambda a, b, c, d, ns, mn: per_template(
-                    ts_args, a, b, c, d, ns, mn
-                )
-            )(tau, omega, psi0, s0, n_steps, mean)
-        else:
-            sums = jax.vmap(
-                lambda a, b, c, d: per_template(ts_args, a, b, c, d)
-            )(tau, omega, psi0, s0)  # (per_dev, 5, W)
+        sums, valid = body(ts_args, btau, bomega, bpsi0, bs0, offset,
+                           n_total, *exact)
+        bmax, barg = block_reduce(sums, valid)
         with stage_scope("merge"):
-            masked = jnp.where(valid[:, None, None], sums, _NEG)
-            bmax = jnp.max(masked, axis=0)
-            barg = jnp.argmax(masked, axis=0).astype(jnp.int32)  # first max in block
             btidx = offset + barg
         bmax, btidx = _allreduce_merge(axis_name, n_dev, bmax, btidx)
         with stage_scope("merge"):
@@ -146,8 +140,8 @@ def make_sharded_batch_step(
             fin = jnp.isfinite(sums)
             nf_local = jnp.sum((validb & ~fin).astype(jnp.int32))
             ok = validb & fin
-            fmax_local = jnp.max(jnp.where(ok, sums, _NEG))
-            fmin_local = jnp.min(jnp.where(ok, sums, -_NEG))
+            fmax_local = jnp.max(jnp.where(ok, sums, NEG_SENTINEL))
+            fmin_local = jnp.min(jnp.where(ok, sums, -NEG_SENTINEL))
             nf_batch = jax.lax.psum(nf_local, axis_name)
             fmax = jax.lax.pmax(fmax_local, axis_name)
             fmin = jax.lax.pmin(fmin_local, axis_name)
@@ -182,7 +176,9 @@ def make_sharded_batch_step(
         local_step, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=out_specs, check_vma=False,
     )
-    return jax.jit(sharded, donate_argnums=(7, 8))
+    step = jax.jit(sharded, donate_argnums=(7, 8))
+    step.resident = body.resident
+    return step
 
 
 def run_bank_sharded(
@@ -202,12 +198,13 @@ def run_bank_sharded(
 ):
     """Resilient wrapper around the sharded dispatch loop.
 
-    Same recovery ladder as ``models.search.run_bank`` (minus the Pallas
-    rung — the sharded step has no Pallas path): transient failures
+    Same recovery ladder as ``models.search.run_bank``: transient failures
     restart from the last host snapshot, device OOM halves the
-    PER-DEVICE batch, all bounded by the shared per-run retry budget.
-    ``ERP_RETRY_BUDGET=0`` disables wrapper and snapshot d2h alike.  See
-    :func:`_run_bank_sharded_attempt` for the loop contract.
+    PER-DEVICE batch, repeated failures of a Pallas step fall back to the
+    XLA body (``resilience.pallas_fallback``), all bounded by the shared
+    per-run retry budget.  ``ERP_RETRY_BUDGET=0`` disables wrapper and
+    snapshot d2h alike.  See :func:`_run_bank_sharded_attempt` for the
+    loop contract.
 
     ``stop_template`` bounds the covered range to ``[start_template,
     stop_template)`` — the multi-host path runs one such window per shard
@@ -225,7 +222,10 @@ def run_bank_sharded(
             progress_cb=progress_cb, lookahead=lookahead,
         )
     snap = resilience.DispatchSnapshot(state, start_template)
-    ladder = resilience.DegradationLadder(pol, per_device_batch)
+    ladder = resilience.DegradationLadder(
+        pol, per_device_batch,
+        pallas_active=uses_pallas(geom),
+    )
     cur_state, cur_start = state, start_template
     while True:
         try:
@@ -235,6 +235,7 @@ def run_bank_sharded(
                 state=cur_state, start_template=cur_start,
                 stop_template=stop_template,
                 progress_cb=progress_cb, lookahead=lookahead,
+                allow_pallas=ladder.allow_pallas,
                 snapshot=snap,
             )
         except Exception as e:
@@ -269,6 +270,7 @@ def _run_bank_sharded_attempt(
     stop_template: int | None = None,
     progress_cb=None,
     lookahead: int = 2,
+    allow_pallas: bool = True,
     snapshot=None,
 ):
     """Async dispatch loop over mesh-wide template batches; same contract
@@ -291,7 +293,8 @@ def _run_bank_sharded_attempt(
 
     wd = _make_watchdog()
     step = make_sharded_batch_step(
-        geom, mesh, per_device_batch, axis_name, with_health=wd is not None
+        geom, mesh, per_device_batch, axis_name, with_health=wd is not None,
+        allow_pallas=allow_pallas,
     )
     if state is None:
         state = init_state(geom)
@@ -321,6 +324,9 @@ def _run_bank_sharded_attempt(
     metrics.gauge("sharded.per_device_batch").set(int(per_device_batch))
     m_batches = metrics.counter("search.batches")
     m_templates = metrics.counter("search.templates")
+    # as in run_bank: the templates a resident-chain step resampled
+    m_resident = metrics.counter("search.templates_resident")
+    resident = getattr(step, "resident", False)
     m_dispatch_s = metrics.counter("search.dispatch_wall_s", unit="s")
     m_stall_s = metrics.counter("search.drain_stall_s", unit="s")
     m_prefetch_s = metrics.counter("search.prefetch_wait_s", unit="s")
@@ -372,6 +378,8 @@ def _run_bank_sharded_attempt(
             m_occupancy.observe(inflight)
             m_batches.inc()
             m_templates.inc(stop - start)
+            if resident:
+                m_resident.inc(stop - start)
             flightrec.record(
                 "dispatch", start=start, stop=stop,
                 ms=round(dt_dispatch * 1e3, 3),

@@ -515,26 +515,41 @@ def phase_profile(ctx: Ctx) -> None:
 def phase_four_chips(ctx: Ctx, n_dev: int = 4) -> None:
     """The driver with ``--mesh n_dev`` vs the single-device ``run_bank`` on
     device 0 in this process: (M, T) bit-identical, candidates identical,
-    and every device of the mesh holding the series, bank and state.  The
-    mesh step keeps the XLA resampler, so device 0 runs the ladder's XLA
-    rung too: the same algorithm, split across chips or not."""
+    and every device of the mesh holding the series, bank and state.  Both
+    run the default step, whose per-batch body the mesh shares with one
+    chip: on a TPU the resident Pallas chain, on every shard and on device
+    0 alike, and the ``--mesh`` run's report counts every template
+    resident."""
     import jax
 
+    from boinc_app_eah_brp_tpu.models.search import use_pallas_resident
     from boinc_app_eah_brp_tpu.parallel import make_mesh, run_bank_sharded
     from boinc_app_eah_brp_tpu.runtime import cli
 
     check(len(jax.devices()) >= n_dev, f"{len(jax.devices())} devices < {n_dev}")
     out = os.path.join(ctx.workdir, "mesh.cand")
+    mfile = os.path.join(ctx.workdir, "mesh-metrics.jsonl")
     rc = cli.main(driver_argv(
         ctx, out, os.path.join(ctx.workdir, "mesh.cp"),
-        "--mesh", str(n_dev), "--no-rescore",
+        "--mesh", str(n_dev), "--no-rescore", "--metrics-file", mfile,
     ))
     check(rc == 0, f"driver --mesh {n_dev} exited {rc}")
     phase_whiten(ctx)
     from boinc_app_eah_brp_tpu.runtime.autobatch import choose_batch
 
+    report = read_report(mfile)
+    n_t = counter(report, "search.templates")
+    n_res = counter(report, "search.templates_resident")
+    want = n_t if use_pallas_resident(ctx.geom) else 0
+    check(n_t > 0 and n_res == want,
+          f"driver --mesh {n_dev}: search.templates_resident = {n_res}, not "
+          f"{want} of {n_t}")
+    check(counter(report, "resilience.pallas_fallback") == 0,
+          "the mesh fell back to the XLA rung")
+    ctx.info["mesh_templates_resident"] = n_res
+
     ctx.batch = choose_batch(ctx.geom.nsamples)
-    M1, T1 = run_bank(ctx, allow_pallas=False)
+    M1, T1 = run_bank(ctx)
     M1, T1 = np.asarray(M1), np.asarray(T1)
 
     seen = {}

@@ -59,9 +59,22 @@ def test_pallas_phase_in_interpret_mode(tmp_path):
     assert ctx.info["pallas_state_bit_identical"]
 
 
-def test_four_chip_phase_on_the_virtual_mesh(tmp_path):
-    ctx = _ctx(tmp_path)
+@pytest.mark.parametrize("resident", [False, True], ids=["xla", "resident"])
+def test_four_chip_phase_on_the_virtual_mesh(tmp_path, monkeypatch, resident):
+    """The tiny bank on the XLA resampler; and, with the resident chain
+    forced (interpret mode), a shallow bank whose orbits it admits, as a
+    TPU runs the shipped one: every template of the mesh's run on it."""
+    shape = chip_smoke.TINY
+    if resident:
+        monkeypatch.setenv("ERP_PALLAS_RESIDENT", "1")
+        shape = chip_smoke.dataclasses.replace(
+            shape, P_orb=200.0, tau=0.03, P_range=(100.0, 300.0),
+            tau_max=0.03,
+        )
+    ctx = _ctx(tmp_path, shape)
     chip_smoke.phase_four_chips(ctx, n_dev=4)
+    n_res = ctx.info["mesh_templates_resident"]
+    assert n_res == (shape.n_bank if resident else 0)
 
 
 def test_a_wrong_top_candidate_fails_the_main_phase(tmp_path):

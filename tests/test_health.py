@@ -91,7 +91,6 @@ def _poison_sumspec(monkeypatch):
     import jax.numpy as jnp
 
     from boinc_app_eah_brp_tpu.models import search as msearch
-    from boinc_app_eah_brp_tpu.parallel import sharded_search
 
     real = msearch.template_sumspec_fn
 
@@ -103,9 +102,9 @@ def _poison_sumspec(monkeypatch):
 
         return wrapper
 
+    # one chip and the mesh build their step around the same body
+    # (msearch.bank_batch_sums), which reads this name when it is built
     monkeypatch.setattr(msearch, "template_sumspec_fn", poisoned)
-    # the sharded loop binds the name at import time — patch its copy too
-    monkeypatch.setattr(sharded_search, "template_sumspec_fn", poisoned)
 
 
 def test_healthy_run_checks_without_violations(monkeypatch):

@@ -329,6 +329,11 @@ def test_fused_bytes_attribute_to_sumspec_stage(monkeypatch):
     """The fused kernel's traffic lands under its own erp.sumspec scope
     in the OPTIMIZED module — not the unattributed remainder that
     cost_ledger books as "compiler-generated"."""
+    # importing hlo_attrib forces the FFT cascade for the whole process
+    # (tools/_aot_common.py::use_cpu_backend): set it here so that it is
+    # undone after this test, and later tests in this worker keep XLA's FFT
+    monkeypatch.setenv("ERP_FORCE_CASCADE", "1")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     import hlo_attrib
 
     monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")

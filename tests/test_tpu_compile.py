@@ -237,3 +237,59 @@ def test_resident_bank_step_at_production_widths(topo, monkeypatch, f0,
     in_f, _ = comp.input_formats
     for f in (in_f[7], in_f[8], *comp.output_formats):
         assert f.layout.major_to_minor == (0, 1)
+
+
+def test_mesh_step_on_the_resident_chain(topo, monkeypatch):
+    """The four-chip mesh step (``make_sharded_batch_step``) over the
+    described 2x2 at the shipped WU's palfa_p3 widths: each shard runs the
+    resident chain (both kernels there, the XLA resampler's gather loop
+    not) and the (M, T) merge is ``collective-permute``s under
+    ``erp.allreduce``.  As in the one-chip test, the harmonic sum is the
+    fused fold, for the compile's time and memory here; the mesh keeps
+    its series prescaled, so the kernel folds no renorm."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from boinc_app_eah_brp_tpu.models.search import (
+        SearchGeometry,
+        init_state,
+        lut_step_for_bank,
+        max_slope_for_bank,
+        upload_bank,
+    )
+    from boinc_app_eah_brp_tpu.oracle.pipeline import DerivedParams, SearchConfig
+    from boinc_app_eah_brp_tpu.parallel import make_sharded_batch_step
+
+    monkeypatch.setenv("ERP_PALLAS_RESIDENT", "1")
+    monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
+    cfg = SearchConfig(f0=400.0, padding=3.0, fA=0.08, window=1000, white=True)
+    derived = DerivedParams.derive(1 << 22, 65.476, cfg)
+    P, tau = np.array([660.0, 2231.0]), np.array([0.335, 0.0])  # PALFA
+    geom = SearchGeometry.from_derived(
+        derived, max_slope=max_slope_for_bank(P, tau),
+        lut_step=lut_step_for_bank(P, derived.dt),
+    )
+    mesh = Mesh(np.array(topo.devices[:4]), ("templates",))
+    rep = NamedSharding(mesh, PartitionSpec())
+    step = make_sharded_batch_step(geom, mesh, BATCH)
+    assert step.resident
+    bank = upload_bank(
+        tuple(np.zeros(16, np.float32) for _ in range(4)), 4 * BATCH
+    )
+    M, T = jax.eval_shape(lambda: init_state(geom))
+    ts = tuple(
+        _spec((geom.n_unpadded // 2,), jnp.float32, rep) for _ in range(2)
+    )
+    text = step.lower(
+        ts, *(_spec(a.shape, a.dtype, rep) for a in bank),
+        _spec((), jnp.int32, rep), _spec((), jnp.int32, rep),
+        _spec(M.shape, M.dtype, rep), _spec(T.shape, T.dtype, rep),
+    ).compile().as_text()
+    ops, kernels = _ops_by_stage(text)
+    assert {"resample", "fftprep"} <= kernels, kernels
+    assert not ops["resample"] & _GATHER_LOOP_OPS, ops["resample"]
+    # a TPU runs the permute async: collective-permute-start / -done
+    permutes = {
+        k for k, v in ops.items()
+        if any(op.startswith("collective-permute") for op in v)
+    }
+    assert permutes == {"allreduce"}, ops.get("allreduce")
