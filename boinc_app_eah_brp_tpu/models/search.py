@@ -546,13 +546,18 @@ def resident_defers_renorm(geom: SearchGeometry) -> bool:
 
 
 def use_pallas_sumspec(geom: SearchGeometry) -> bool:
-    """Opt-in gate for the fused resident-spectrum fold kernel
-    (``ops/pallas_sumspec.py``): ``ERP_PALLAS_SUMSPEC=1`` AND the
-    geometry fits the kernel's static contract.  Off by default pending
-    the on-chip A/B — same rollout shape as :func:`use_pallas_resample`."""
+    """Gate for the fused harmonic fold (``ops/pallas_sumspec.py``), the
+    step's harmonic sum on a TPU backend wherever ``sumspec_applicable``
+    admits the geometry.  Every other backend keeps the XLA
+    ``harmonic_sumspec`` unless ``ERP_PALLAS_SUMSPEC=1`` forces the fold
+    there (interpret-mode CPU tests, deviceless compiles for a described
+    TPU), as :func:`use_pallas_resident` does for the resampler."""
     import os
 
-    if os.environ.get("ERP_PALLAS_SUMSPEC") != "1":
+    if (
+        os.environ.get("ERP_PALLAS_SUMSPEC") != "1"
+        and jax.default_backend() != "tpu"
+    ):
         return False
     from ..ops.pallas_sumspec import sumspec_applicable
 
@@ -613,9 +618,9 @@ def erp_precision() -> str:
 
 def _fused_sums_fn(geom: SearchGeometry, interpret: bool):
     """Batched ``(B, L) power spectra -> (B, 5, W)`` via the fused Pallas
-    fold kernel — the resident-spectrum replacement for the vmapped
-    ``harmonic_sumspec`` (whose per-template while loop round-trips
-    spectrum-sized accumulators through HBM)."""
+    fold, bit-equal to the vmapped ``harmonic_sumspec`` (whose
+    per-template while loop round-trips spectrum-sized accumulators
+    through HBM)."""
     from ..ops.pallas_sumspec import sumspec_pallas_batch
 
     def sums(ps_batch):
@@ -822,9 +827,11 @@ def make_bank_step(
                 in_shardings=in_sh,
                 out_shardings=out_sh,
             )
-        # the resampler the step was built with, which the dispatch loop
-        # counts (search.templates_resident in _run_bank_attempt)
+        # the resampler and harmonic sum the step was built with, which
+        # the dispatch loop counts (search.templates_resident and
+        # search.templates_sumspec in _run_bank_attempt)
         jitted.resident = body.resident
+        jitted.fused = body.fused
         return jitted
 
     def merge(sums, valid, t_offset, M, T):
@@ -881,7 +888,8 @@ def bank_batch_sums(
     kernel where :func:`use_pallas_sumspec` admits it, and the XLA sum
     elsewhere.  ``allow_pallas=False`` takes the XLA resampler and sum
     whatever the gates say: the degradation ladder's fallback rung.
-    ``body.resident`` records whether the body runs the resident chain."""
+    ``body.resident`` records whether the body runs the resident chain,
+    ``body.fused`` whether it runs the fold."""
     B = int(batch_size)
     per_template = template_sumspec_fn(geom)
     per_ps = template_ps_fn(geom)
@@ -972,6 +980,7 @@ def bank_batch_sums(
             return sums, valid
 
     body.resident = resident
+    body.fused = fused
     return body
 
 
@@ -1289,6 +1298,9 @@ def _run_bank_attempt(
     # (a wrapped step without the attribute counts as XLA)
     m_resident = metrics.counter("search.templates_resident")
     resident = getattr(step, "resident", False)
+    # likewise the share the fused harmonic fold summed
+    m_sumspec = metrics.counter("search.templates_sumspec")
+    fused = getattr(step, "fused", False)
     m_dispatch_s = metrics.counter("search.dispatch_wall_s", unit="s")
     m_stall_s = metrics.counter("search.drain_stall_s", unit="s")
     m_prefetch_s = metrics.counter("search.prefetch_wait_s", unit="s")
@@ -1353,6 +1365,8 @@ def _run_bank_attempt(
             m_templates.inc(stop - start)
             if resident:
                 m_resident.inc(stop - start)
+            if fused:
+                m_sumspec.inc(stop - start)
             flightrec.record(
                 "dispatch", start=start, stop=stop,
                 ms=round(dt_dispatch * 1e3, 3),

@@ -94,7 +94,9 @@ def make_sharded_batch_step(
     (``models.search.bank_batch_sums``): on a TPU, wherever
     ``use_pallas_resident`` admits the geometry, each shard runs the
     resident Pallas chain on its block; ``allow_pallas=False`` takes the
-    XLA rung, as on one chip.  ``step.resident`` records which.
+    XLA rung, as on one chip; so does the fused harmonic fold
+    (``use_pallas_sumspec``).  ``step.resident`` and ``step.fused`` record
+    which.
 
     ``btau``.. are the :func:`upload_bank` device arrays of the whole bank,
     replicated over the mesh; each shard slices its ``per_device_batch``
@@ -178,6 +180,7 @@ def make_sharded_batch_step(
     )
     step = jax.jit(sharded, donate_argnums=(7, 8))
     step.resident = body.resident
+    step.fused = body.fused
     return step
 
 
@@ -327,6 +330,9 @@ def _run_bank_sharded_attempt(
     # as in run_bank: the templates a resident-chain step resampled
     m_resident = metrics.counter("search.templates_resident")
     resident = getattr(step, "resident", False)
+    # and those the fused harmonic fold summed
+    m_sumspec = metrics.counter("search.templates_sumspec")
+    fused = getattr(step, "fused", False)
     m_dispatch_s = metrics.counter("search.dispatch_wall_s", unit="s")
     m_stall_s = metrics.counter("search.drain_stall_s", unit="s")
     m_prefetch_s = metrics.counter("search.prefetch_wait_s", unit="s")
@@ -380,6 +386,8 @@ def _run_bank_sharded_attempt(
             m_templates.inc(stop - start)
             if resident:
                 m_resident.inc(stop - start)
+            if fused:
+                m_sumspec.inc(stop - start)
             flightrec.record(
                 "dispatch", start=start, stop=stop,
                 ms=round(dt_dispatch * 1e3, 3),

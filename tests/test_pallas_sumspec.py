@@ -1,9 +1,10 @@
-"""Fused resident-spectrum fold kernel (ops/pallas_sumspec.py):
-interpret-mode bit-parity against the production XLA path
-(ops/harmonic.py), end-to-end goldens against the CPU oracle at the
-existing tolerances, the ERP_PALLAS_SUMSPEC / ERP_PRECISION gating
-contracts, layout pinning (zero recompiles across dispatch windows; the
-v5e compile of the pinned step is in tests/test_tpu_compile.py),
+"""Fused harmonic fold kernel (ops/pallas_sumspec.py):
+interpret-mode bit-parity against the XLA path (ops/harmonic.py),
+end-to-end goldens against the CPU oracle at the existing tolerances,
+the fold's gate (default on a TPU, ERP_PALLAS_SUMSPEC=1 elsewhere), its
+counter and cache key, the ERP_PRECISION contract, layout pinning (zero
+recompiles across dispatch windows; the v5e compile of the pinned step
+is in tests/test_tpu_compile.py),
 and named-scope attribution (the kernel's bytes must land under
 erp.sumspec, not "compiler-generated")."""
 
@@ -27,6 +28,7 @@ from boinc_app_eah_brp_tpu.models.search import (
     make_bank_step,
     make_batch_step,
     state_to_natural,
+    step_cache_key,
     use_pallas_sumspec,
 )
 from boinc_app_eah_brp_tpu.ops.harmonic import harmonic_sumspec
@@ -53,12 +55,68 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 
 
 def test_gates(monkeypatch):
+    """The fold is the step's harmonic sum on a TPU by default; on the CPU
+    only ERP_PALLAS_SUMSPEC=1 forces it (interpret mode)."""
     assert sumspec_applicable(240, 3800)
     monkeypatch.delenv("ERP_PALLAS_SUMSPEC", raising=False)
     geom = _tiny_geom()
-    assert not use_pallas_sumspec(geom)  # opt-in: off by default
+    assert not use_pallas_sumspec(geom)  # the CPU keeps the XLA sum
     monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
     assert use_pallas_sumspec(geom)
+    monkeypatch.delenv("ERP_PALLAS_SUMSPEC")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert use_pallas_sumspec(geom)
+
+
+def test_step_cache_key_parts_fold_from_xla_rung(monkeypatch):
+    """A fold-built step and the ladder's XLA rung never share a resident
+    executable, and the forced gate moves the key on the CPU."""
+    monkeypatch.delenv("ERP_PALLAS_SUMSPEC", raising=False)
+    monkeypatch.delenv("ERP_PALLAS_RESIDENT", raising=False)
+    geom = _tiny_geom()
+    k_xla = step_cache_key(geom, 4, False, True)
+    monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
+    k_fold = step_cache_key(geom, 4, False, True)
+    assert k_fold != k_xla
+    assert step_cache_key(geom, 4, False, False) != k_fold
+
+
+def test_run_bank_counts_sumspec_templates(monkeypatch):
+    """search.templates_sumspec counts the templates dispatched through a
+    fold-built step: all of them when the fold is forced, none on the
+    ladder's XLA rung (``allow_pallas=False``), with the same (M, T)."""
+    monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
+    monkeypatch.delenv("ERP_PALLAS_RESIDENT", raising=False)
+    n = 4096
+    ts = synthetic_timeseries(
+        n, f_signal=33.0, P_orb=2.2, tau=0.04, psi0=1.2, amp=7.0
+    )
+    geom = _tiny_geom(n)
+    bank = small_bank(P_true=2.2, tau_true=0.04, psi_true=1.2)
+    assert make_bank_step(geom, 3).fused
+    assert not make_bank_step(geom, 3, allow_pallas=False).fused
+
+    def counts():
+        c = metrics.snapshot()["counters"]
+        return tuple(
+            (c.get(k) or {}).get("value", 0)
+            for k in ("search.templates", "search.templates_sumspec")
+        )
+
+    n_t = len(bank.P)
+    assert metrics.configure(force=True)
+    try:
+        M1, T1 = run_bank(ts, bank.P, bank.tau, bank.psi0, geom, batch_size=3)
+        assert counts() == (n_t, n_t)
+        M0, T0 = run_bank(
+            ts, bank.P, bank.tau, bank.psi0, geom, batch_size=3,
+            allow_pallas=False,
+        )
+        assert counts() == (2 * n_t, n_t)
+    finally:
+        metrics.finish(0)
+    np.testing.assert_array_equal(np.asarray(M1), np.asarray(M0))
+    np.testing.assert_array_equal(np.asarray(T1), np.asarray(T0))
 
 
 def test_kernel_is_registered_stage():
@@ -102,15 +160,20 @@ def test_precision_rejects_unknown_mode(monkeypatch):
 @pytest.mark.parametrize(
     "window_2,fund_hi,harm_hi,L",
     [
-        (50, 240, 3800, 4096),  # single tile, production-like ratios
+        (50, 240, 3800, 4096),  # one 8-row tile, production-like ratios
         (16, 100, 1600, 2048),  # fund_hi not a multiple of anything nice
-        (8, 600, 9000, 8192),  # multi-tile: Q=600 > TQ=512
+        (8, 600, 9000, 8192),  # whole chunks past the mask's edge
         (0, 33, 513, 1024),  # harm_hi just past a 16q+r boundary
+        (0, 1100, 17000, 20000),  # harm_hi inside a chunk (q 1062, t 6)
+        (0, 1100, 16896, 20000),  # harm_hi at a chunk boundary (16*16*66)
+        (0, 2145, 34328, 65537),  # refdefault_p1's fund_hi : harm_hi : L
+        (0, 20000, 330000, 340000),  # two row tiles a chunk, carried wrap
     ],
 )
 def test_bit_parity_with_xla_reference(window_2, fund_hi, harm_hi, L):
     """Fused fold == ops/harmonic.py state-form output, bit for bit:
-    identical adds in identical order, identical run-max association."""
+    identical adds in identical order, identical run-max association,
+    across the chunks' halos and the row tiles' carried wrap."""
     rng = np.random.default_rng(11)
     ps = rng.exponential(1.0, size=(2, L)).astype(np.float32)
     kw = dict(window_2=window_2, fund_hi=fund_hi, harm_hi=harm_hi)

@@ -68,22 +68,75 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_sumspec_kernel_at_production_widths(geom, one_chip):
+_OPCODE_RE = re.compile(r" = .*? ([a-z][a-z0-9-]*)\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+# what XLA lowers the resampler's gather to: a while loop of
+# dynamic-slice / dynamic-update-slice, one iteration per output block
+_GATHER_LOOP_OPS = {"while", "dynamic-slice", "dynamic-update-slice"}
+
+
+def _ops_by_stage(text):
+    """{stage: {opcode, ...}} over the compiled module's instructions,
+    and the stages of its Mosaic kernels."""
+    from boinc_app_eah_brp_tpu.runtime.devicecost import stage_of_op_name
+
+    ops, kernels = {}, set()
+    for line in text.splitlines():
+        name, code = _OP_NAME_RE.search(line), _OPCODE_RE.search(line)
+        if name is None or code is None:
+            continue
+        stage = stage_of_op_name(name.group(1))
+        ops.setdefault(stage, set()).add(code.group(1))
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels.add(stage)
+    return ops, kernels
+
+
+_SLICE_RE = re.compile(r"slice=\{([^}]*)\}")
+
+
+def _strided_slices(text, stage):
+    """The compiled module's slices under ``stage`` whose minor dimension
+    takes a stride above 1 (``[start:limit:stride]``)."""
+    from boinc_app_eah_brp_tpu.runtime.devicecost import stage_of_op_name
+
+    out = []
+    for line in text.splitlines():
+        name, sl = _OP_NAME_RE.search(line), _SLICE_RE.search(line)
+        if name is None or sl is None or stage_of_op_name(name.group(1)) != stage:
+            continue
+        dims = re.findall(r"\[([^\]]*)\]", sl.group(1))
+        parts = dims[-1].split(":") if dims else []
+        if len(parts) == 3 and int(parts[2]) > 1:
+            out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("fund_hi,harm_hi,L", [
+    (329551, 5272824, 3 * (1 << 21) + 1),  # palfa_p3: FFT length 3 * 2^22
+    (68657, 1098505, (1 << 21) + 1),  # refdefault_p1: FFT length 2^22
+])
+def test_sumspec_kernel_at_production_widths(one_chip, fund_hi, harm_hi, L):
+    """The fused fold at the cells' widths: its Mosaic kernel is there,
+    and nothing under ``erp.sumspec`` rearranges the spectrum by strides
+    along the lane axis (its views are reshapes and 2D transposes)."""
     from boinc_app_eah_brp_tpu.ops.pallas_sumspec import (
         state_width,
         sumspec_applicable,
         sumspec_pallas_batch,
     )
 
-    assert sumspec_applicable(geom.fund_hi, geom.harm_hi)
+    assert sumspec_applicable(fund_hi, harm_hi)
     fn = jax.jit(lambda ps: sumspec_pallas_batch(
-        ps, window_2=geom.window_2, fund_hi=geom.fund_hi,
-        harm_hi=geom.harm_hi, interpret=False,
+        ps, window_2=500, fund_hi=fund_hi, harm_hi=harm_hi, interpret=False,
     ))
-    ps = _spec((BATCH, geom.nsamples // 2 + 1), jnp.float32, one_chip)
+    ps = _spec((BATCH, L), jnp.float32, one_chip)
     comp = fn.lower(ps).compile()
-    assert "tpu_custom_call" in comp.as_text()
-    assert comp.out_info.shape == (BATCH, 5, state_width(geom.fund_hi))
+    text = comp.as_text()
+    _, kernels = _ops_by_stage(text)
+    assert "sumspec" in kernels, kernels
+    assert not _strided_slices(text, "sumspec"), _strided_slices(text, "sumspec")
+    assert comp.out_info.shape == (BATCH, 5, state_width(fund_hi))
 
 
 def test_resident_resample_fftprep_kernel_at_production_widths(geom, one_chip):
@@ -152,46 +205,19 @@ def test_layout_pinned_bank_step(topo, monkeypatch):
         assert f.layout.major_to_minor == (0, 1)
 
 
-_OPCODE_RE = re.compile(r" = .*? ([a-z][a-z0-9-]*)\(")
-_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
-# what XLA lowers the resampler's gather to: a while loop of
-# dynamic-slice / dynamic-update-slice, one iteration per output block
-_GATHER_LOOP_OPS = {"while", "dynamic-slice", "dynamic-update-slice"}
-
-
-def _ops_by_stage(text):
-    """{stage: {opcode, ...}} over the compiled module's instructions,
-    and the stages of its Mosaic kernels."""
-    from boinc_app_eah_brp_tpu.runtime.devicecost import stage_of_op_name
-
-    ops, kernels = {}, set()
-    for line in text.splitlines():
-        name, code = _OP_NAME_RE.search(line), _OPCODE_RE.search(line)
-        if name is None or code is None:
-            continue
-        stage = stage_of_op_name(name.group(1))
-        ops.setdefault(stage, set()).add(code.group(1))
-        if 'custom_call_target="tpu_custom_call"' in line:
-            kernels.add(stage)
-    return ops, kernels
-
-
 @pytest.mark.parametrize("f0,padding,fA", [
     (400.0, 3.0, 0.08),  # palfa_p3: FFT length 3 * 2^22
     (250.0, 1.0, 0.04),  # refdefault_p1: FFT length 2^22
 ])
 def test_resident_bank_step_at_production_widths(topo, monkeypatch, f0,
                                                  padding, fA):
-    """The resident resample -> FFT-prep chain inside a donated,
-    layout-pinned bank step at the shipped WU's widths (the gate forced,
-    since this backend is the CPU), with the whitening renorm folded into
-    the kernel as the Session defers it.  Both kernels are there, the XLA
-    resampler's gather loop is not, and (M, T) stay row-major.  This is
-    not the whole production step: its harmonic sum here is the fused
-    fold kernel (``ERP_PALLAS_SUMSPEC=1``), where a TPU runs the XLA
-    harmonic sum by default, because that sum's deviceless compile at
-    these widths takes ~2 min and ~14 GB of host memory a case.  The
-    assertions read only the resampler's scopes and the state's layouts."""
+    """The production bank step at the shipped WU's widths: the resident
+    resample -> FFT-prep chain and the fused harmonic fold inside a
+    donated, layout-pinned step (the gates forced, since this backend is
+    the CPU), with the whitening renorm folded into the kernel as the
+    Session defers it.  All three kernels are there, the XLA resampler's
+    gather loop is not, the fold's scope holds no lane-strided slice, and
+    (M, T) stay row-major."""
     import dataclasses
 
     from boinc_app_eah_brp_tpu.models.search import (
@@ -231,9 +257,11 @@ def test_resident_bank_step_at_production_widths(topo, monkeypatch, f0,
         ts, *(S(a.shape, a.dtype) for a in bank), S((), jnp.int32),
         S((), jnp.int32), M, T,
     ).compile()
-    ops, kernels = _ops_by_stage(comp.as_text())
-    assert {"resample", "fftprep"} <= kernels, kernels
+    text = comp.as_text()
+    ops, kernels = _ops_by_stage(text)
+    assert {"resample", "fftprep", "sumspec"} <= kernels, kernels
     assert not ops["resample"] & _GATHER_LOOP_OPS, ops["resample"]
+    assert not _strided_slices(text, "sumspec")
     in_f, _ = comp.input_formats
     for f in (in_f[7], in_f[8], *comp.output_formats):
         assert f.layout.major_to_minor == (0, 1)
@@ -242,11 +270,10 @@ def test_resident_bank_step_at_production_widths(topo, monkeypatch, f0,
 def test_mesh_step_on_the_resident_chain(topo, monkeypatch):
     """The four-chip mesh step (``make_sharded_batch_step``) over the
     described 2x2 at the shipped WU's palfa_p3 widths: each shard runs the
-    resident chain (both kernels there, the XLA resampler's gather loop
-    not) and the (M, T) merge is ``collective-permute``s under
-    ``erp.allreduce``.  As in the one-chip test, the harmonic sum is the
-    fused fold, for the compile's time and memory here; the mesh keeps
-    its series prescaled, so the kernel folds no renorm."""
+    resident chain and the fused fold (their kernels there, the XLA
+    resampler's gather loop not) and the (M, T) merge is
+    ``collective-permute``s under ``erp.allreduce``.  The mesh keeps its
+    series prescaled, so the kernel folds no renorm."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     from boinc_app_eah_brp_tpu.models.search import (
@@ -271,7 +298,7 @@ def test_mesh_step_on_the_resident_chain(topo, monkeypatch):
     mesh = Mesh(np.array(topo.devices[:4]), ("templates",))
     rep = NamedSharding(mesh, PartitionSpec())
     step = make_sharded_batch_step(geom, mesh, BATCH)
-    assert step.resident
+    assert step.resident and step.fused
     bank = upload_bank(
         tuple(np.zeros(16, np.float32) for _ in range(4)), 4 * BATCH
     )
@@ -285,7 +312,7 @@ def test_mesh_step_on_the_resident_chain(topo, monkeypatch):
         _spec(M.shape, M.dtype, rep), _spec(T.shape, T.dtype, rep),
     ).compile().as_text()
     ops, kernels = _ops_by_stage(text)
-    assert {"resample", "fftprep"} <= kernels, kernels
+    assert {"resample", "fftprep", "sumspec"} <= kernels, kernels
     assert not ops["resample"] & _GATHER_LOOP_OPS, ops["resample"]
     # a TPU runs the permute async: collective-permute-start / -done
     permutes = {
