@@ -3,13 +3,10 @@
 
 PYTHON ?= python
 
-.PHONY: test smoke bench-history chaos chaos-hosts chaos-hang serving-chaos fabric-soak fabric-soak-server fleet-bench fleet-report fleet-timeline step-report precision-audit trace-report cost-ledger hlo-attrib
+.PHONY: test smoke bench-history chaos chaos-hosts chaos-hang serving-chaos fabric-soak fabric-soak-server fleet-bench fleet-report fleet-timeline step-report precision-audit trace-report
 
 # tier-1 suite (the gate every PR must keep green) + the benchmark-artifact
-# schema gate (--strict fails on malformed round artifacts) + the AOT
-# traffic ledger gate (--strict fails on per-template HBM-traffic growth
-# between consecutive rounds, total OR any single named stage) + the
-# named-scope attribution gate (hlo-attrib below) + the clean multi-host
+# schema gate (--strict fails on malformed round artifacts) + the clean multi-host
 # elastic gate (2 forced-4-device CPU driver processes over one shard
 # board; the host-KILL half lives in `make chaos-hosts`) + the hang-soak
 # gate (chaos-hang below: wedges must become supervised restarts) + the
@@ -39,32 +36,12 @@ test:
 	$(MAKE) step-report
 	$(MAKE) precision-audit
 	$(PYTHON) tools/bench_history.py --strict
-	$(PYTHON) tools/cost_ledger.py --strict --budget-gb 4.1
-	$(MAKE) hlo-attrib
 	env JAX_PLATFORMS=cpu $(PYTHON) tools/smoke.py --hosts 2
 	$(MAKE) chaos-hang
 	$(MAKE) serving-chaos
 	$(MAKE) fleet-timeline
 	$(MAKE) fabric-soak-server
 	$(MAKE) fleet-report
-
-# chip-free named-scope HBM attribution gate (tools/hlo_attrib.py): AOT
-# compile a small-geometry search step on the CPU backend with the fused
-# sumspec path + the resident resample->FFT-prep chain enabled, bucket
-# the optimized module's bytes by erp.* stage scope, fail when less than
-# 80% of the traffic attributes to a named pipeline stage (i.e. when the
-# instrumentation in ops/ stops covering the hot ops), then diff against
-# the committed r06-state baseline (HLO_ATTRIB_r06_cpu.json: same CI
-# geometry, sumspec fused, resident chain off) so any stage whose
-# per-template bytes grew back — including erp.resample, which the
-# resident chain cut ~6x at this geometry — fails naming the stage
-hlo-attrib:
-	env JAX_PLATFORMS=cpu ERP_PALLAS_SUMSPEC=1 ERP_PALLAS_RESIDENT=1 \
-		$(PYTHON) tools/hlo_attrib.py \
-		--platform cpu --batch 4 --nsamples 16384 --min-fraction 0.8 \
-		--quiet --json .erp_cache/hlo_attrib_ci.json
-	$(PYTHON) tools/hlo_attrib.py --diff HLO_ATTRIB_r06_cpu.json \
-		.erp_cache/hlo_attrib_ci.json
 
 # fast observability smoke: tiny end-to-end run with the health watchdog
 # at max cadence + metrics + flight recorder, then schema-check every
@@ -166,7 +143,7 @@ fleet-report:
 # measured-time reconciliation gate (tools/step_report.py, chip-free):
 # run the CI fixture with the runtime/steptime.py bracket armed, join
 # the measured per-window step times against the roofline stage model
-# and the committed cost ledger into erp-step-report/1, hold the run
+# into erp-step-report/1, hold the run
 # under the STEPTIME_BASELINE.json ceilings (same-backend only), then
 # schema-check the cached artifact with the common validator
 step-report:
@@ -199,8 +176,3 @@ bench-history:
 # (or its .chrome.json export); see docs/observability.md layer 7
 trace-report:
 	$(PYTHON) tools/trace_report.py $(TRACE)
-
-# per-stage HBM-traffic ledger from the committed AOT_COST_r*.json
-# artifacts -> COST_LEDGER.json (tools/cost_ledger.py; chip-free)
-cost-ledger:
-	$(PYTHON) tools/cost_ledger.py

@@ -22,7 +22,6 @@ synthetic WU).
 
 from __future__ import annotations
 
-import glob
 import json
 import os
 import subprocess
@@ -347,13 +346,6 @@ def run_bench() -> int:
         f"{roof['attainable_templates_per_sec']} t/s mfu={roof.get('mfu')} "
         f"hbm_util={roof.get('hbm_utilization')} bound={roof.get('bound')}"
     )
-    if roof.get("compiler_bound_templates_per_sec") is not None:
-        log(
-            f"bench: compiler-bound ceiling "
-            f"{roof['compiler_bound_templates_per_sec']} t/s "
-            f"({roof['compiler_bound']['gb_per_template']} GB/template "
-            f"from {roof['compiler_bound']['source']})"
-        )
 
     metric = METRIC if on_chip else METRIC + " [CPU backend, not a device number]"
     payload = {
@@ -375,35 +367,11 @@ def run_bench() -> int:
         "hbm_utilization": roof.get("hbm_utilization"),
         "bound": roof.get("bound"),
         "attainable_templates_per_sec": roof["attainable_templates_per_sec"],
-        # the compiler's ceiling (HBM bw / ledger GB-per-template): present
-        # in every payload so bench history can watch the gap close as the
-        # layout overhead comes down (None on checkouts without the ledger)
-        "compiler_bound_templates_per_sec": roof.get(
-            "compiler_bound_templates_per_sec"
-        ),
     }
     if not on_chip:
         # chip peaks say nothing about a CPU run
         for k in ("mfu", "hbm_utilization", "bound"):
             payload.pop(k)
-    # the round's scope-attribution artifact (tools/hlo_attrib.py): the
-    # payload links the per-stage HBM story next to the throughput number
-    try:
-        from boinc_app_eah_brp_tpu.runtime.artifacts import round_key
-
-        attribs = sorted(
-            glob.glob(
-                os.path.join(
-                    os.path.dirname(os.path.abspath(__file__)),
-                    "HLO_ATTRIB_r*.json",
-                )
-            ),
-            key=round_key,
-        )
-        if attribs:
-            payload["hlo_attrib_file"] = os.path.basename(attribs[-1])
-    except Exception:
-        pass
     # close the tracing window first and reduce the trace to its stall
     # breakdown — the payload then shows where the bench wall went
     # (dispatch vs drain vs host feed) next to the throughput number

@@ -3,7 +3,6 @@ from .search import (
     bank_params_host,
     init_state,
     make_bank_step,
-    make_batch_step,
     run_bank,
     template_params_host,
     template_sumspec_fn,
@@ -15,7 +14,6 @@ __all__ = [
     "bank_params_host",
     "init_state",
     "make_bank_step",
-    "make_batch_step",
     "run_bank",
     "template_params_host",
     "template_sumspec_fn",

@@ -491,22 +491,6 @@ def state_from_natural(arr: np.ndarray, geom: SearchGeometry) -> np.ndarray:
     return from_natural_order(np.asarray(arr), geom.fund_hi)
 
 
-def use_pallas_resample(geom: SearchGeometry) -> bool:
-    """Opt-in gate for the fused Pallas resampler
-    (``ops/pallas_resample.py``): ``ERP_PALLAS_RESAMPLE=1`` AND the
-    geometry fits the kernel's static contracts.  Off by default pending
-    the on-chip A/B (``tools/pallas_ab.py``)."""
-    import os
-
-    if os.environ.get("ERP_PALLAS_RESAMPLE") != "1":
-        return False
-    if not (geom.parity_split and geom.use_lut and not geom.exact_mean):
-        return False
-    from ..ops.pallas_resample import pallas_applicable
-
-    return pallas_applicable(geom.max_slope, geom.lut_step, geom.lut_tiles)
-
-
 def use_pallas_resident(geom: SearchGeometry) -> bool:
     """Gate for the resident resample->FFT-prep chain
     (``ops/pallas_resample.py::resample_fftprep_pallas_batch``), the
@@ -516,8 +500,7 @@ def use_pallas_resident(geom: SearchGeometry) -> bool:
     sine and unwhitened (``exact_mean``) searches keep the XLA resampler,
     as does every other backend unless ``ERP_PALLAS_RESIDENT=1`` forces
     the chain there (interpret-mode CPU tests, deviceless compiles for a
-    described TPU).  Supersedes ``ERP_PALLAS_RESAMPLE`` (the resident
-    chain contains the resampler)."""
+    described TPU)."""
     import os
 
     if not (geom.parity_split and geom.use_lut and not geom.exact_mean):
@@ -565,14 +548,10 @@ def use_pallas_sumspec(geom: SearchGeometry) -> bool:
 
 
 def uses_pallas(geom: SearchGeometry) -> bool:
-    """Whether a bank step built for ``geom`` runs a Pallas kernel (any
-    of the three gates above): what the degradation ladder's Pallas rung
-    watches in ``run_bank`` and ``run_bank_sharded``."""
-    return (
-        use_pallas_resample(geom)
-        or use_pallas_resident(geom)
-        or use_pallas_sumspec(geom)
-    )
+    """Whether a bank step built for ``geom`` runs a Pallas kernel (either
+    gate above): what the degradation ladder's Pallas rung watches in
+    ``run_bank`` and ``run_bank_sharded``."""
+    return use_pallas_resident(geom) or use_pallas_sumspec(geom)
 
 
 def _pallas_interpret() -> bool:
@@ -667,42 +646,6 @@ def _prep_ts_fn(geom: SearchGeometry):
     return prep
 
 
-def make_batch_step(geom: SearchGeometry):
-    """Jitted (ts_args, tau[B], omega[B], psi0[B], s0[B], t_offset, M, T
-    [, n_steps[B], mean[B]]) -> (M, T) with the batch folded in.
-    ``ts_args = prepare_ts(geom, ts)``; the trailing overrides exist iff
-    ``geom.exact_mean``.
-
-    This is the per-batch-upload formulation: the caller h2d-copies each
-    batch's parameters.  The production dispatch loop (``run_bank``) uses
-    :func:`make_bank_step` instead — bank-resident parameters sliced on
-    device — and keeps this step as the synchronous reference for the
-    equivalence tests (``tests/test_async_pipeline.py``) and the A/B
-    tooling (bench legacy mode, ``tools/pallas_ab.py``).  No state
-    donation here: A/B callers reuse one (M, T) across step variants.
-
-    The per-batch body is :func:`bank_batch_sums`, as in every bank step,
-    over the batch's own arrays (offset 0, every slot valid); it is built
-    when the step is traced for a batch size, so the Pallas gates are read
-    then."""
-
-    erp_precision()  # bf16 requests fail at construction, not mid-run
-
-    @jax.jit
-    def step(ts_args, tau, omega, psi0, s0, t_offset, M, T, *exact):
-        B = tau.shape[0]
-        body = bank_batch_sums(geom, B)
-        sums, valid = body(ts_args, tau, omega, psi0, s0, jnp.int32(0),
-                           jnp.int32(B), *exact)
-        bmax, barg = block_reduce(sums, valid)
-        with stage_scope("merge"):
-            better = bmax > M
-            return (jnp.where(better, bmax, M),
-                    jnp.where(better, t_offset + barg, T))
-
-    return step
-
-
 def batch_health_vec(sums, valid, M_new):
     """Device health scalars for one batch, as a float32[4] vector:
     ``[nonfinite_batch, nonfinite_state, finite_max, finite_min]``.
@@ -740,8 +683,7 @@ def bank_step_layouts(geom: SearchGeometry, with_health: bool, device):
     Without these the compiler is free to pick a different layout per
     dispatch-window executable for the SAME persistent buffers — the (M,
     T) state and the bank arrays — and reconciles its choices with
-    inserted copies, the 2.5 GB/template "compiler-generated" bucket the
-    r05 ledger attributes to no stage.  Pinning one explicit layout on
+    inserted copies that belong to no stage.  Pinning one explicit layout on
     both sides of the donation makes every window executable agree, so
     the buffers alias through unchanged.  Chip-free verifiable: the
     layouts compile against a deviceless TPU topology
@@ -776,10 +718,9 @@ def make_bank_step(
     so the steady-state loop performs no per-batch parameter h2d at all.
     Slots at global index ``>= n_total`` (the final partial batch) are
     masked to :data:`NEG_SENTINEL` before the block reduction — they can
-    never claim a bin, which is bit-equivalent to the legacy
-    duplicate-first-template padding (``make_batch_step``): in both
-    schemes ``bmax`` is the exact max over the real templates and
-    ``argmax`` resolves ties to the smallest batch index.
+    never claim a bin: ``bmax`` is the exact max over the real templates
+    and ``argmax`` resolves ties to the smallest batch index, as a
+    template-by-template strict ``>`` merge would.
 
     (M, T) are donated (``donate_argnums``): the maxima state updates in
     place on device, halving its HBM footprint and letting XLA alias the
@@ -806,8 +747,7 @@ def make_bank_step(
     On TPU the jitted step additionally pins explicit row-major device
     layouts on every array operand and result (:func:`bank_step_layouts`):
     the donated (M, T) state and the bank arrays flow between dispatch
-    windows without compiler-inserted layout copies — the
-    "compiler-generated" bucket of ``COST_LEDGER.json``."""
+    windows without compiler-inserted layout copies."""
     erp_precision()  # bf16 requests fail at construction, not mid-run
     body = bank_batch_sums(geom, batch_size, allow_pallas)
 
@@ -906,27 +846,19 @@ def bank_batch_sums(
     def valid_slots(offset, n_total):
         return offset + jnp.arange(B, dtype=jnp.int32) < n_total
 
-    if resident or (allow_pallas and use_pallas_resample(geom)):
-        from ..ops.pallas_resample import (
-            resample_fftprep_pallas_batch,
-            resample_split_pallas_batch,
-        )
+    if resident:
+        from ..ops.pallas_resample import resample_fftprep_pallas_batch
 
         # resident chain: the resampled series goes straight to FFT-prep
-        # layout in VMEM (use_pallas_resident); both variants fold the
-        # deferred whitening renorm into the gather when the driver
-        # shipped an unscaled series (geom.ts_prescaled=False)
-        resample_fn = (
-            resample_fftprep_pallas_batch
-            if resident
-            else resample_split_pallas_batch
-        )
+        # layout in VMEM; it folds the deferred whitening renorm into the
+        # gather when the driver shipped an unscaled series
+        # (geom.ts_prescaled=False)
         renorm = _ts_renorm(geom)
 
         def body(ts_args, btau, bomega, bpsi0, bs0, offset, n_total):
             tau, omega, psi0, s0 = slice_bank(btau, bomega, bpsi0, bs0, offset)
             valid = valid_slots(offset, n_total)
-            ev, od = resample_fn(
+            ev, od = resample_fftprep_pallas_batch(
                 ts_args[0],
                 ts_args[1],
                 tau,
@@ -1037,9 +969,8 @@ class ExactMeanPrefetch:
         ns, mn = host_exact_mean_params(self._ts, chunk, self._geom)
         pad = self._B - len(chunk)
         if pad:
-            # pad with the chunk's first element, mirroring the legacy
-            # duplicate-first-template batch padding; the device masks
-            # these slots regardless (make_bank_step n_total operand)
+            # pad with the chunk's first element; the device masks these
+            # slots (make_bank_step's n_total operand)
             ns = np.concatenate([ns, np.full(pad, ns[0], dtype=ns.dtype)])
             mn = np.concatenate([mn, np.full(pad, mn[0], dtype=mn.dtype)])
         return ns, mn
@@ -1081,13 +1012,12 @@ def step_cache_key(
     from ..ops.fft import backend_has_native_fft
 
     return (
-        "erp-bank-step/2",
+        "erp-bank-step/3",
         geom,
         int(batch_size),
         bool(with_health),
         bool(allow_pallas),
         erp_precision(),
-        bool(allow_pallas and use_pallas_resample(geom)),
         bool(allow_pallas and use_pallas_resident(geom)),
         bool(allow_pallas and use_pallas_sumspec(geom)),
         _pallas_interpret(),

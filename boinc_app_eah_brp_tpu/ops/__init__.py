@@ -1,9 +1,5 @@
 from .harmonic import harmonic_sumspec, harmonic_sumspec_batch
-from .pallas_resample import (
-    pallas_applicable,
-    resample_split_pallas,
-    resample_split_pallas_batch,
-)
+from .pallas_resample import pallas_applicable
 from .resample import resample, resample_batch, resample_split
 from .sincos import sin_lut, sincos_lut_lookup
 from .spectrum import (
@@ -16,8 +12,6 @@ __all__ = [
     "harmonic_sumspec",
     "harmonic_sumspec_batch",
     "pallas_applicable",
-    "resample_split_pallas",
-    "resample_split_pallas_batch",
     "resample",
     "resample_batch",
     "resample_split",

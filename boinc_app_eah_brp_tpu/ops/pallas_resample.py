@@ -28,12 +28,8 @@ Applicability gates (checked by ``pallas_applicable``): the fixed kernel
 block ``B_BLK`` must honor the select-window and LUT-window contracts for
 the geometry's static bounds, and the tiled sine table must fit VMEM.
 
-Template batching: ``resample_split_pallas_batch`` runs the whole batch
-as one launch over the grid (T, parity, block) — pass 1 of the resident
-chain, and the model's two-stage ``ERP_PALLAS_RESAMPLE=1`` path; plain
-``jax.vmap`` of the
-single-template call also works (verified bit-equal) and lowers to the
-same batched grid.
+Template batching: pass 1 (``_launch_stream_batch``) runs the whole
+batch as one launch over the grid (T, parity, block).
 """
 
 from __future__ import annotations
@@ -169,9 +165,8 @@ def _stream_block_body(
     renorm: float | None = None,
 ):
     """Shared per-block computation: phase -> LUT sine -> del_t -> index ->
-    window DMA -> shifted select -> output + trailing-run scalar.  Called by
-    the single-template kernel (block = program_id(0)) and the batched
-    kernel (template/parity/block from a 3-d grid).
+    window DMA -> shifted select -> output + trailing-run scalar, for one
+    block of the batched kernel (template/parity/block from a 3-d grid).
 
     The block computes in the native (SUB, 128) tiling (flat output index
     j = row * 128 + lane).  Both dynamic windows — the ts parity streams
@@ -319,36 +314,6 @@ def _stream_block_body(
     lf_ref[0, :] = jnp.full((128,), lf)
 
 
-def _parity_stream_kernel(
-    params_ref,  # SMEM float32[16]
-    sin_ref,
-    cos_ref,
-    ts_e_ref,
-    ts_o_ref,
-    out_ref,  # VMEM float32[1, SUB, 128]
-    lf_ref,  # VMEM float32[1, 1, 128]
-    win_e,
-    win_o,
-    sem_e,
-    sem_o,
-    sin_win,
-    cos_win,
-    sem_s,
-    sem_c,
-    **geom_kw,
-):
-    import jax.experimental.pallas as pl
-
-    _stream_block_body(
-        pl.program_id(0),
-        params_ref[0], params_ref[1], params_ref[2], params_ref[3],
-        params_ref[4], params_ref[5], params_ref[6], params_ref[7],
-        sin_ref, cos_ref, ts_e_ref, ts_o_ref, out_ref.at[0], lf_ref.at[0],
-        win_e, win_o, sem_e, sem_o, sin_win, cos_win, sem_s, sem_c,
-        **geom_kw,
-    )
-
-
 def _batched_stream_kernel(
     params_ref,  # SMEM float32[T, 16]: whole params table, row per template
     sin_ref,
@@ -367,10 +332,9 @@ def _batched_stream_kernel(
     sem_c,
     **geom_kw,
 ):
-    """Template-batched variant: grid = (T, 2, n_blocks); the parity comes
-    from the grid (program_id(1)), not from the params row, so one launch
-    covers the whole batch (vmap over pallas_call is unsupported — module
-    docstring).  The params table stays whole-array resident in SMEM and
+    """The stream kernel: grid = (T, 2, n_blocks); the parity comes from
+    the grid (program_id(1)), not from the params row, so one launch
+    covers the whole batch.  The params table stays whole-array resident in SMEM and
     the kernel rows into it with program_id(0): a (1, 16) block window over
     a (T, 16) SMEM operand violates Mosaic's block-divisibility rule, so
     per-template scalar streaming must index, not window."""
@@ -389,179 +353,6 @@ def _batched_stream_kernel(
         win_e, win_o, sem_e, sem_o, sin_win, cos_win, sem_s, sem_c,
         **geom_kw,
     )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "nsamples",
-        "n_unpadded",
-        "dt",
-        "max_slope",
-        "lut_step",
-        "lut_tiles",
-        "renorm",
-        "interpret",
-    ),
-)
-@scoped("resample")
-def resample_split_pallas(
-    ts_even: jnp.ndarray,
-    ts_odd: jnp.ndarray,
-    tau: jnp.ndarray,
-    omega: jnp.ndarray,
-    psi0: jnp.ndarray,
-    s0: jnp.ndarray,
-    *,
-    nsamples: int,
-    n_unpadded: int,
-    dt: float,
-    max_slope: float,
-    lut_step: float,
-    lut_tiles: int = 1024,
-    renorm: float | None = None,
-    interpret: bool = False,
-):
-    """Same contract as ``resample_split`` (device mean path, LUT only):
-    (even, odd) float32[nsamples//2] parity streams, resampled and
-    mean-padded.  One fused kernel per parity stream.  ``renorm`` folds the
-    deferred whitening renormalization into the gather (see
-    ``_stream_block_body``)."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if not pallas_applicable(max_slope, lut_step, lut_tiles):
-        raise ValueError("geometry outside the pallas kernel's gates")
-    if n_unpadded % 2 or nsamples % 2:
-        raise ValueError("resample_split_pallas requires even lengths")
-    half = n_unpadded // 2
-    E = _select_span(max_slope)
-    rows_l = _window_rows()
-    lpad = B_BLK + 2
-    n_blocks = -(-half // B_BLK)
-    rpad = n_blocks * B_BLK - half + rows_l * 128 + 2
-    # the padded stream must split into whole 1024-element tiles for the
-    # 2-D (rows, 128) DMA view
-    rpad += -(lpad + half + rpad) % 1024
-
-    sin_np, cos_np = _tiled_lut_tables(lut_tiles)
-    lut_limit = lut_tiles * 64
-
-    ts_e_pad = jnp.pad(ts_even.astype(jnp.float32), (lpad, rpad)).reshape(
-        -1, 128
-    )
-    ts_o_pad = jnp.pad(ts_odd.astype(jnp.float32), (lpad, rpad)).reshape(
-        -1, 128
-    )
-    edge_lo = ts_even[0]
-    edge_hi = ts_odd[(n_unpadded - 1) >> 1]
-
-    kern = functools.partial(
-        _parity_stream_kernel,
-        E=E,
-        lpad=lpad,
-        half=half,
-        n_unpadded=n_unpadded,
-        lut_limit=lut_limit,
-        renorm=renorm,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            # LUT tables live in ANY (HBM): the K-wide windows are DMA'd
-            # into SMEM at arbitrary dynamic offsets, which VMEM-resident
-            # memrefs cannot serve (slices must be tile-aligned)
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            # blocks whose trailing dims equal the array's (SUB, 128) /
-            # (1, 128) trailing dims satisfy Mosaic's
-            # (8, 128)-divisible-or-equal block rule
-            pl.BlockSpec((1, SUB, 128), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, 128), lambda b: (b, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((rows_l, 128), jnp.float32),
-            pltpu.VMEM((rows_l, 128), jnp.float32),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SMEM((LUT_W,), jnp.float32),
-            pltpu.SMEM((LUT_W,), jnp.float32),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-    )
-    call = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, SUB, 128), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, 1, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )
-
-    streams = []
-    lfs = []
-    for parity in (0, 1):
-        params = jnp.stack(
-            [
-                jnp.float32(tau),
-                jnp.float32(omega),
-                jnp.float32(psi0),
-                jnp.float32(s0),
-                jnp.float32(dt),
-                jnp.float32(parity),
-                jnp.float32(edge_lo),
-                jnp.float32(edge_hi),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-            ]
-        )
-        out, lf = call(
-            params,
-            jnp.asarray(sin_np),
-            jnp.asarray(cos_np),
-            ts_e_pad,
-            ts_o_pad,
-        )
-        streams.append(out.reshape(-1)[:half])
-        lf_local = lf[:, 0, 0].astype(jnp.int32)
-        offs = jnp.arange(n_blocks, dtype=jnp.int32) * B_BLK
-        # global last-false index in this parity stream (-1 if all True)
-        lfs.append(jnp.max(jnp.where(lf_local >= 0, offs + lf_local, -1)))
-    lf_e, lf_o = lfs
-    g_e, g_o = streams
-
-    n_steps = jnp.maximum(2 * lf_e, 2 * lf_o + 1)
-    m2 = jnp.arange(half, dtype=jnp.int32) * 2
-    mask_e = m2 < n_steps
-    mask_o = (m2 + 1) < n_steps
-    total = jnp.sum(jnp.where(mask_e, g_e, 0.0)) + jnp.sum(
-        jnp.where(mask_o, g_o, 0.0)
-    )
-    mean = total / n_steps.astype(jnp.float32)
-    head_e = jnp.where(mask_e, g_e, mean)
-    head_o = jnp.where(mask_o, g_o, mean)
-    half_out = nsamples // 2
-    if half_out > half:
-        tail = jnp.full((half_out - half,), 1.0, dtype=jnp.float32) * mean
-        return (
-            jnp.concatenate([head_e, tail]),
-            jnp.concatenate([head_o, tail]),
-        )
-    return head_e[:half_out], head_o[:half_out]
 
 
 def _launch_stream_batch(
@@ -645,9 +436,9 @@ def _launch_stream_batch(
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            # block trailing dims equal the array trailing dims — the legal
-            # form for one-block-per-step stores (see the single-template
-            # launch)
+            # blocks whose trailing dims equal the array's (SUB, 128) /
+            # (1, 128) trailing dims satisfy Mosaic's
+            # (8, 128)-divisible-or-equal block rule
             pl.BlockSpec(
                 (1, 1, 1, SUB, 128), lambda t, p, b: (t, p, b, 0, 0)
             ),
@@ -680,10 +471,10 @@ def _launch_stream_batch(
 
 def _batch_stats(out, lf, *, T: int, half: int, n_blocks: int):
     """Global per-template stream statistics from the pass-1 outputs: the
-    exact float32 op sequence the original epilogue used, shared by both
-    batched entries so the resident chain's mean/n_steps bits match the
-    two-stage path's.  Returns (g_e, g_o, n_steps, mask_e, mask_o, mean);
-    callers that only need (n_steps, mean) let XLA DCE the rest."""
+    exact float32 op sequence of the XLA resampler's mean
+    (``ops/resample.py``), so the resident chain's mean/n_steps bits match
+    it.  Returns (g_e, g_o, n_steps, mask_e, mask_o, mean); the chain needs
+    only (n_steps, mean) and lets XLA DCE the rest."""
     g = out.reshape(T, 2, n_blocks * B_BLK)[:, :, :half]  # (T, 2, half)
     lf_local = lf[:, :, :, 0, 0].astype(jnp.int32)  # (T, 2, n_blocks)
     offs = jnp.arange(n_blocks, dtype=jnp.int32)[None, None, :] * B_BLK
@@ -702,71 +493,6 @@ def _batch_stats(out, lf, *, T: int, half: int, n_blocks: int):
     )
     mean = total / n_steps.astype(jnp.float32)  # (T,)
     return g_e, g_o, n_steps, mask_e, mask_o, mean
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "nsamples",
-        "n_unpadded",
-        "dt",
-        "max_slope",
-        "lut_step",
-        "lut_tiles",
-        "renorm",
-        "interpret",
-    ),
-)
-@scoped("resample")
-def resample_split_pallas_batch(
-    ts_even: jnp.ndarray,
-    ts_odd: jnp.ndarray,
-    tau: jnp.ndarray,  # float32[T]
-    omega: jnp.ndarray,
-    psi0: jnp.ndarray,
-    s0: jnp.ndarray,
-    *,
-    nsamples: int,
-    n_unpadded: int,
-    dt: float,
-    max_slope: float,
-    lut_step: float,
-    lut_tiles: int = 1024,
-    renorm: float | None = None,
-    interpret: bool = False,
-):
-    """Template-batched fused resampler: one pallas launch over the grid
-    (T, parity, block) — the explicit-batch form the model's batched step
-    uses (``models/search.py``, ``ERP_PALLAS_RESAMPLE=1``).  Returns
-    (even, odd) float32[T, nsamples//2], semantics identical to a vmap of
-    ``resample_split`` with the device (pairwise) mean."""
-    if not pallas_applicable(max_slope, lut_step, lut_tiles):
-        raise ValueError("geometry outside the pallas kernel's gates")
-    if n_unpadded % 2 or nsamples % 2:
-        raise ValueError("resample_split_pallas_batch requires even lengths")
-    T = tau.shape[0]
-    half = n_unpadded // 2
-    out, lf, n_blocks = _launch_stream_batch(
-        ts_even, ts_odd, tau, omega, psi0, s0,
-        n_unpadded=n_unpadded, dt=dt, max_slope=max_slope,
-        lut_tiles=lut_tiles, renorm=renorm, interpret=interpret,
-    )
-
-    g_e, g_o, n_steps, mask_e, mask_o, mean = _batch_stats(
-        out, lf, T=T, half=half, n_blocks=n_blocks
-    )
-    head_e = jnp.where(mask_e, g_e, mean[:, None])
-    head_o = jnp.where(mask_o, g_o, mean[:, None])
-    half_out = nsamples // 2
-    if half_out > half:
-        tail = jnp.broadcast_to(
-            mean[:, None], (T, half_out - half)
-        ) * jnp.float32(1.0)
-        return (
-            jnp.concatenate([head_e, tail], axis=1),
-            jnp.concatenate([head_o, tail], axis=1),
-        )
-    return head_e[:, :half_out], head_o[:, :half_out]
 
 
 def _fftprep_kernel(
@@ -844,14 +570,15 @@ def resample_fftprep_pallas_batch(
 ):
     """Resident resample -> FFT-prep chain, the bank step's resampler on
     TPU where ``models/search.py::use_pallas_resident`` admits the
-    geometry: pass 1 is the same batched stream launch as
-    ``resample_split_pallas_batch``; the only XLA ops between the kernels
-    are the O(T) stream statistics (n_steps, mean), and pass 2
+    geometry: pass 1 is the batched stream launch
+    (``_launch_stream_batch``); the only XLA ops between the kernels are
+    the O(T) stream statistics (n_steps, mean), and pass 2
     (``_fftprep_kernel``) re-reads each raw tile once to emit the padded,
-    mean-filled series directly in FFT-prep layout.  Bitwise identical to
-    ``resample_split_pallas_batch`` at every geometry: the head is the
-    same select between the same slab bits and the same mean bits, the
-    tail is the same mean."""
+    mean-filled series directly in FFT-prep layout.  Returns (even, odd)
+    float32[T, nsamples//2], bitwise identical at every geometry to a vmap
+    of the XLA ``resample_split`` with the device (pairwise) mean: the
+    head is the same select between the same gathered bits, the mean
+    fill and the tail the same mean."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

@@ -29,9 +29,9 @@ import os
 from . import flightrec, metrics
 
 # Live float32 arrays of length ~nsamples per in-flight template.
-# ANCHORED by compiler-verified feasibility (AOT_HBM_r05.json, deviceless
-# AOT of the production step against the v5e topology): batch 64 fits the
-# 15.75 GB HBM, batch 72+ does not.  The gross bound including XLA's
+# ANCHORED by compiler-verified feasibility (a deviceless AOT compile of
+# the round-5 production step for a v5e: batch 64 fit the 15.75 GB HBM,
+# batches 72-128 needed 18.75-22.21 GB).  The gross bound including XLA's
 # actual layouts is 15.75e9 / 64 / (nsamples * 4) = 4.889; rounded DOWN
 # so the proven-feasible batch 64 satisfies its own bound.  The prior
 # 6.0 was an unanchored estimate (VERDICT r04 weak #5).
@@ -148,9 +148,10 @@ def choose_batch(nsamples: int, log=None) -> int:
         swept, sweep_kind, sweep_n = sweep
         # A rung that RAN in the sweep proved feasibility on the device
         # it ran on AT the problem size it swept — the strongest evidence
-        # available, stronger than any linear model (AOT_HBM_r05.json
-        # shows per-template HBM is NOT linear in batch, so a factor-based
-        # check is unsound in both directions).  Unguarded acceptance
+        # available, stronger than any linear model (per-template HBM is
+        # NOT linear in batch: in that v5e compile batch 128 needed 18.75
+        # GB and batch 96 22.21 GB, so a factor-based check is unsound in
+        # both directions).  Unguarded acceptance
         # therefore requires BOTH the device kind and nsamples to match:
         # a rung proven at 2^20 samples says nothing about fitting a 2^22
         # WU on the same chip.  Explicitly DIFFERENT kinds: reject.

@@ -1,17 +1,13 @@
-"""Device-cost observatory: the named-scope stage registry and the
-scope-based HBM attribution schema.
+"""Device-cost observatory: the named-scope stage registry, and the
+reduction of a profiler's device records to those stages.
 
 Layer 8 of the observability stack (docs/observability.md).  Layer 7
-attributes the HOST wall clock; the AOT ledger (``tools/cost_ledger.py``)
-bounds DEVICE traffic — but until now its largest bucket was
-2.5 GB/template of "compiler-generated" layout copies attributed to
-nothing, because the optimized HLO only carries whatever source metadata
-survives fusion.  This module closes that gap from the source side:
-every pipeline stage wraps its ops in a ``jax.named_scope`` drawn from
+attributes the HOST wall clock; this module attributes DEVICE time.
+Every pipeline stage wraps its ops in a ``jax.named_scope`` drawn from
 the single registry below, so the scope name rides the ``op_name``
 metadata of every derived HLO instruction — through vmap, jit and XLA
-fusion — and ``tools/hlo_attrib.py`` can bucket the optimized module's
-bytes by stage without a chip.
+fusion.  A TPU's profiler names its events after those instructions, so
+``hlo_op_scopes`` of the compiled text maps each event to its stage.
 
 Design rules (same contract as ``metrics`` / ``tracing`` /
 ``flightrec``):
@@ -21,9 +17,8 @@ Design rules (same contract as ``metrics`` / ``tracing`` /
   untouched, so compiled executables are bit-identical modulo metadata
   and adding/removing scopes can never change results
   (``tests/test_devicecost.py`` proves no extra recompiles either).
-* **No jax import at module import.**  The registry, the op_name
-  parser and the artifact validators are plain Python so the chip-free
-  tools (``cost_ledger``, ``metrics_report``) can import this module
+* **No jax import at module import.**  The registry and the op_name
+  parser are plain Python, so chip-free tools can import this module
   without dragging jax in; ``stage_scope`` imports jax lazily on first
   use inside already-jax-using code.
 
@@ -37,16 +32,12 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-# schema of the attribution artifact tools/hlo_attrib.py emits
-ATTRIB_SCHEMA = "erp-hlo-attrib/1"
-
 SCOPE_PREFIX = "erp."
 
-# The single stage registry: scope name (without prefix) -> the
-# COST_LEDGER.json stage bucket its traffic lands in.  Order is pipeline
-# order; tools render stages in this order.  Adding a stage here is the
-# ONLY step needed for it to appear in hlo_attrib / cost_ledger output —
-# the instrumentation sites just call stage_scope("<name>").
+# The single stage registry: scope name (without prefix) -> the coarser
+# stage bucket it is reported under (``ledger_stage``).  Order is pipeline
+# order; tools render stages in this order.  The instrumentation sites
+# just call stage_scope("<name>").
 STAGES: dict[str, str] = {
     "unpack": "unpack",  # ops/unpack.py 4-bit nibble split
     "resample": "resample",  # ops/resample.py + ops/pallas_resample.py
@@ -129,7 +120,7 @@ def stage_of_op_name(op_name: str | None) -> str | None:
 
 
 def ledger_stage(stage: str) -> str:
-    """COST_LEDGER.json bucket name for a registered stage."""
+    """The coarser bucket a registered stage is reported under."""
     return STAGES.get(stage, stage)
 
 
@@ -393,74 +384,3 @@ def collect_profiler_device_records(logdir: str) -> ProfilerRecords:
                      path)
     return ProfilerRecords(records=records, path=path)
 
-
-# ---------------------------------------------------------------------------
-# artifact validation (shared by tools/metrics_report.py --check)
-
-
-def _is_num(v) -> bool:
-    return isinstance(v, (int, float)) and not isinstance(v, bool)
-
-
-def validate_hlo_attrib(doc) -> list[str]:
-    """Structural check of an ``erp-hlo-attrib/1`` artifact; returns a
-    list of problems (empty = valid).  Hand-rolled: the container has no
-    jsonschema."""
-    errs: list[str] = []
-    if not isinstance(doc, dict):
-        return ["not a JSON object"]
-    if doc.get("schema") != ATTRIB_SCHEMA:
-        errs.append(
-            f"schema is {doc.get('schema')!r}, expected {ATTRIB_SCHEMA!r}"
-        )
-    for key in ("total_bytes", "attributed_bytes", "attributed_fraction"):
-        if not _is_num(doc.get(key)):
-            errs.append(f"missing numeric {key}")
-    if not _is_num(doc.get("batch")) or doc.get("batch", 0) <= 0:
-        errs.append("missing positive batch")
-    frac = doc.get("attributed_fraction")
-    if _is_num(frac) and not (0.0 <= frac <= 1.0):
-        errs.append(f"attributed_fraction {frac} outside [0, 1]")
-    stages = doc.get("stages")
-    if not isinstance(stages, dict):
-        errs.append("missing stages object")
-    else:
-        for name, row in stages.items():
-            if not isinstance(row, dict) or not _is_num(
-                row.get("out_bytes")
-            ):
-                errs.append(f"stage {name}: missing numeric out_bytes")
-    if not isinstance(doc.get("unattributed_top"), list):
-        errs.append("missing unattributed_top list")
-    return errs
-
-
-def validate_cost_ledger(doc) -> list[str]:
-    """Structural check of an ``erp-cost-ledger/1`` ledger document."""
-    errs: list[str] = []
-    if not isinstance(doc, dict):
-        return ["not a JSON object"]
-    if doc.get("schema") != "erp-cost-ledger/1":
-        errs.append(
-            f"schema is {doc.get('schema')!r}, expected 'erp-cost-ledger/1'"
-        )
-    rows = doc.get("rows")
-    if not isinstance(rows, list):
-        return errs + ["missing rows list"]
-    for i, row in enumerate(rows):
-        if not isinstance(row, dict):
-            errs.append(f"row {i}: not an object")
-            continue
-        if not row.get("file"):
-            errs.append(f"row {i}: missing file")
-        for key in ("gb_per_template", "ideal_gb_per_template"):
-            if not _is_num(row.get(key)):
-                errs.append(f"row {i}: missing numeric {key}")
-        stages = row.get("layout_gb_per_template")
-        if not isinstance(stages, dict) or not all(
-            _is_num(v) for v in stages.values()
-        ):
-            errs.append(
-                f"row {i}: layout_gb_per_template must map stages to numbers"
-            )
-    return errs

@@ -1,9 +1,10 @@
 """Precision observatory: per-stage numerical-error attribution, ULP
 histograms, and candidate-recall scoring against the f64 oracle.
 
-The platform can attribute HBM bytes per stage (``tools/hlo_attrib.py``)
-and wall time per stage (``tools/step_report.py``); this module adds the
-third axis — WHERE ERROR ENTERS.  It runs the real jitted pipeline and a
+The platform can attribute device time per stage (the profiler trace
+through ``runtime/devicecost.py``'s scopes) and wall time per stage
+(``tools/step_report.py``); this module adds the third axis — WHERE
+ERROR ENTERS.  It runs the real jitted pipeline and a
 float64 reference over the same workunit slice, taps every registered
 stage boundary (the ``runtime/devicecost.py`` stage registry is the
 single source of stage names), and scores the final toplist against the

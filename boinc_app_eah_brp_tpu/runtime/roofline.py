@@ -28,7 +28,6 @@ gap, per VERDICT r03 ("no MFU or roofline accounting exists anywhere").
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 
 from ..ops.fft import fft_plan
@@ -166,45 +165,6 @@ def pipeline_costs(
     return [resample, fft, hs, merge]
 
 
-def compiler_bound_templates_per_sec(
-    chip: str | None = None, ledger_path: str | None = None
-) -> dict | None:
-    """The COMPILER's throughput ceiling, as distinct from the analytic
-    model below: the AOT cost ledger (``tools/cost_ledger.py`` ->
-    ``COST_LEDGER.json``) records the HBM GB/template XLA *actually
-    schedules*, layout overhead included — so
-    ``HBM bandwidth / gb_per_template`` is the hard t/s bound for the
-    program as compiled today, not as formulated.  Returns None when no
-    ledger artifact exists (chip-free checkouts still bench fine)."""
-    import json
-
-    chip = chip or chip_generation()
-    _, bw = _CHIPS[chip]
-    if ledger_path is None:
-        repo = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-        ledger_path = os.path.join(repo, "COST_LEDGER.json")
-    try:
-        with open(ledger_path, encoding="utf-8") as fh:
-            doc = json.load(fh)
-        rows = [
-            r for r in doc.get("rows", []) if r.get("gb_per_template")
-        ]
-    except (OSError, ValueError):
-        return None
-    if not rows:
-        return None
-    row = max(rows, key=lambda r: r.get("round", 0))
-    gb = float(row["gb_per_template"])
-    return {
-        "chip": chip,
-        "gb_per_template": gb,
-        "compiler_bound_templates_per_sec": round(bw / (gb * 1e9), 1),
-        "source": f"{row.get('file')} (batch {row.get('batch')})",
-    }
-
-
 def roofline_report(
     nsamples: int,
     n_unpadded: int,
@@ -264,15 +224,6 @@ def roofline_report(
         for name, (p, b) in _CHIPS.items()
         if name != "cpu"
     }
-    # the compiler's own ceiling rides along when the cost ledger exists:
-    # analytic attainable says what the formulation could do, this says
-    # what TODAY'S compiled program can do — the gap is layout overhead
-    compiler = compiler_bound_templates_per_sec(chip=chip)
-    if compiler is not None:
-        out["compiler_bound_templates_per_sec"] = compiler[
-            "compiler_bound_templates_per_sec"
-        ]
-        out["compiler_bound"] = compiler
     if measured_templates_per_sec:
         r = measured_templates_per_sec
         # MFU: achieved matmul FLOP rate (at the 6-pass f32 cost) over peak

@@ -58,3 +58,26 @@ def small_bank(P_true: float = 2.1, tau_true: float = 0.05, psi_true: float = 1.
         np.asarray(tau, dtype=np.float64),
         np.asarray(psi, dtype=np.float64),
     )
+
+
+def one_bank_step(geom, ts_args, params, allow_pallas: bool = True):
+    """Host (M, T) after one ``make_bank_step`` over ``params`` (one
+    ``template_params_host`` tuple per template) as a single batch, from a
+    fresh state: the step donates (M, T), so no two calls share one."""
+    import jax.numpy as jnp
+
+    from boinc_app_eah_brp_tpu.models.search import (
+        init_state,
+        make_bank_step,
+        upload_bank,
+    )
+
+    B = len(params)
+    bank = upload_bank(
+        tuple(np.array([p[i] for p in params], dtype=np.float32)
+              for i in range(4)),
+        B,
+    )
+    step = make_bank_step(geom, B, allow_pallas=allow_pallas)
+    M, T = step(ts_args, *bank, jnp.int32(0), jnp.int32(B), *init_state(geom))
+    return np.asarray(M), np.asarray(T)

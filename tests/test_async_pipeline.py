@@ -1,13 +1,13 @@
 """Async dispatch pipeline equivalence (models/search.py::run_bank).
 
 The production loop — bank-resident parameters sliced on device, bounded
-in-flight dispatch window, donated (M, T) — must be BIT-identical to the
-legacy synchronous formulation (make_batch_step: per-batch host prep +
-upload, duplicate-first-template padding, drain every step) for every
-lookahead K, across early quit mid-window and checkpoint/resume, on both
-the whitened and the exact_mean (unwhitened) paths, single-chip and
-sharded.  The golden-WU variant runs where the reference fixture exists;
-the synthetic problem exercises the same code paths everywhere.
+in-flight dispatch window, donated (M, T) — must be BIT-identical to an
+independent oracle (each template's five-level sums on its own, folded
+into (M, T) on the host by a strict ``>`` that keeps the first maximum)
+for every lookahead K, across early quit mid-window and checkpoint/resume,
+on both the whitened and the exact_mean (unwhitened) paths, single-chip
+and sharded.  The golden-WU variant runs where the reference fixture
+exists; the synthetic problem exercises the same code paths everywhere.
 """
 
 import dataclasses
@@ -24,10 +24,10 @@ from boinc_app_eah_brp_tpu.models.search import (
     bank_params_host,
     host_exact_mean_params,
     init_state,
-    make_batch_step,
     prepare_ts,
     run_bank,
     template_params_host,
+    template_sumspec_fn,
 )
 from boinc_app_eah_brp_tpu.oracle import DerivedParams, SearchConfig
 from fixtures import synthetic_timeseries
@@ -51,33 +51,27 @@ def bank():
     return _bigger_bank(23)  # not batch-divisible -> final partial batch
 
 
-def legacy_run(ts, bank, geom, batch_size, state=None, start_template=0):
-    """The synchronous reference loop: per-batch host param prep + h2d,
-    duplicate-first-template padding, drained every step — exactly the
-    pre-pipeline ``run_bank`` formulation."""
-    step = make_batch_step(geom)
-    M, T = state if state is not None else init_state(geom)
+def legacy_run(ts, bank, geom):
+    """The synchronous reference: template by template, the five-level
+    sums of ``template_sumspec_fn`` (with the host-exact mean where
+    ``geom.exact_mean``), folded into (M, T) on the host with a strict
+    ``>`` that keeps the first maximum — the result any batching of the
+    bank must reproduce."""
+    sums_fn = jax.jit(template_sumspec_fn(geom))
+    M, T = (np.array(a) for a in init_state(geom))
     ts_np = np.asarray(ts, dtype=np.float32)
     ts_args = prepare_ts(geom, ts_np)
-    n = len(bank.P)
-    params = [
-        template_params_host(bank.P[t], bank.tau[t], bank.psi0[t], geom.dt)
-        for t in range(n)
-    ]
-    for start in range(start_template, n, batch_size):
-        chunk = params[start : min(start + batch_size, n)]
-        if len(chunk) < batch_size:
-            chunk = chunk + [chunk[0]] * (batch_size - len(chunk))
-        arrs = [
-            jnp.asarray(np.array([c[k] for c in chunk], dtype=np.float32))
-            for k in range(4)
-        ]
-        args = [ts_args, *arrs, jnp.int32(start), M, T]
+    for t in range(len(bank.P)):
+        p = template_params_host(bank.P[t], bank.tau[t], bank.psi0[t], geom.dt)
+        args = [ts_args, *(np.float32(x) for x in p)]
         if geom.exact_mean:
-            ns, mn = host_exact_mean_params(ts_np, chunk, geom)
-            args += [jnp.asarray(ns), jnp.asarray(mn)]
-        M, T = step(*args)
-    return np.asarray(M), np.asarray(T)
+            ns, mn = host_exact_mean_params(ts_np, [p], geom)
+            args += [ns[0], mn[0]]
+        s = np.asarray(sums_fn(*args))
+        better = s > M
+        M = np.where(better, s, M)
+        T = np.where(better, np.int32(t), T)
+    return M, T
 
 
 def test_bank_params_match_per_template_chain(problem, bank):
@@ -96,7 +90,7 @@ def test_bank_params_match_per_template_chain(problem, bank):
 @pytest.mark.parametrize("lookahead", [1, 2, 4])
 def test_async_matches_synchronous(problem, bank, lookahead):
     ts, geom = problem
-    Mref, Tref = legacy_run(ts, bank, geom, batch_size=4)
+    Mref, Tref = legacy_run(ts, bank, geom)
     M, T = run_bank(
         ts, bank.P, bank.tau, bank.psi0, geom, batch_size=4,
         lookahead=lookahead,
@@ -110,7 +104,7 @@ def test_async_exact_mean_matches_synchronous(problem, bank):
     inline host pass."""
     ts, geom = problem
     geom_em = dataclasses.replace(geom, exact_mean=True)
-    Mref, Tref = legacy_run(ts, bank, geom_em, batch_size=4)
+    Mref, Tref = legacy_run(ts, bank, geom_em)
     M, T = run_bank(
         ts, bank.P, bank.tau, bank.psi0, geom_em, batch_size=4, lookahead=2
     )
@@ -123,7 +117,7 @@ def test_early_quit_mid_window_and_resume(problem, bank):
     consistent with exactly `done` templates merged, and resuming from it
     must land bit-identical to an uninterrupted run."""
     ts, geom = problem
-    Mref, Tref = legacy_run(ts, bank, geom, batch_size=4)
+    Mref, Tref = legacy_run(ts, bank, geom)
 
     seen = {}
 
@@ -144,7 +138,7 @@ def test_early_quit_mid_window_and_resume(problem, bank):
     partial_bank = type(bank)(
         bank.P[:done], bank.tau[:done], bank.psi0[:done]
     )
-    Mp, Tp = legacy_run(ts, partial_bank, geom, batch_size=4)
+    Mp, Tp = legacy_run(ts, partial_bank, geom)
     np.testing.assert_array_equal(np.asarray(Mh), Mp)
     np.testing.assert_array_equal(np.asarray(Th), Tp)
 
@@ -194,7 +188,7 @@ def test_sharded_async_matches_single_device(problem):
 
     ts, geom = problem
     bank = _bigger_bank(23)
-    Mref, Tref = legacy_run(ts, bank, geom, batch_size=4)
+    Mref, Tref = legacy_run(ts, bank, geom)
     mesh = make_mesh(4)
     for lookahead in (1, 3):
         Ms, Ts = run_bank_sharded(
@@ -227,7 +221,7 @@ def test_golden_wu_async_matches_synchronous(problem, testwu_bank):
         lut_step=lut_step_for_bank(bank32.P, derived.dt),
         lut_tiles=lut_tiles_for_bank(bank32.P, bank32.psi0, derived.t_obs),
     )
-    Mref, Tref = legacy_run(ts, bank32, geom, batch_size=8)
+    Mref, Tref = legacy_run(ts, bank32, geom)
     for K in (1, 2, 4):
         M, T = run_bank(
             ts, bank32.P, bank32.tau, bank32.psi0, geom, batch_size=8,

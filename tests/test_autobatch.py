@@ -57,8 +57,8 @@ def test_unreadable_sweep_falls_through(tmp_path, monkeypatch):
 
 def test_model_batch_within_compiler_proven_bound():
     """The v5e model choice stays within the AOT-proven feasibility edge
-    (AOT_HBM_r05.json: the production step compiles at batch 64 on the
-    15.75 GB chip, OOMs at 72+); the anchored factor must not pick an
+    (a deviceless v5e compile of the production step fit batch 64 in the
+    15.75 GB chip, and not 72+); the anchored factor must not pick an
     infeasible batch nor collapse below the useful range."""
     b = autobatch.model_batch(3 * (1 << 22), int(15.75e9))
     assert 16 <= b <= 64
@@ -68,8 +68,8 @@ def test_sweep_accepted_on_same_device_kind_and_nsamples(tmp_path, monkeypatch):
     """A sweep rung measured on THIS device kind AT this problem size is
     the strongest feasibility proof and is accepted without a model gate:
     the AOT-proven batch 64 on v5e must be used even though the model
-    alone would pick 32 (AOT_HBM_r05.json; per-template HBM is not linear
-    in batch, so no factor-based check can arbitrate)."""
+    alone would pick 32 (per-template HBM is not linear in batch, so no
+    factor-based check can arbitrate)."""
     import json
 
     n = 3 * (1 << 22)

@@ -1,10 +1,9 @@
-"""Device-cost observatory (runtime/devicecost.py, tools/hlo_attrib.py):
-stage-registry semantics, named scopes surviving into COMPILED HLO
-op_name metadata, zero recompiles and zero numeric effect from scoping,
-synthetic-module byte attribution, device records -> Chrome-export
-merge -> trace_report device section, the artifact
-validators behind ``metrics_report --check``, and cost_ledger's
-attribution-artifact consumption."""
+"""Device-cost observatory (runtime/devicecost.py): stage-registry
+semantics, named scopes surviving into COMPILED HLO op_name metadata —
+down to every stage of the dispatched bank step, which the benchmark's
+per-stage device times read — zero recompiles and zero numeric effect
+from scoping, and device records -> Chrome-export merge -> trace_report
+device section."""
 
 import json
 import os
@@ -18,20 +17,7 @@ from boinc_app_eah_brp_tpu.runtime import devicecost, metrics, tracing
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-import cost_ledger  # noqa: E402
-import metrics_report  # noqa: E402
 import trace_report  # noqa: E402
-
-# hlo_attrib calls use_cpu_backend() at import, which exports
-# ERP_FORCE_CASCADE=1 for the AOT tools' sake; restore the test
-# process's env so the whiten/fft native-path tests keep their meaning
-_cascade = os.environ.get("ERP_FORCE_CASCADE")
-import hlo_attrib  # noqa: E402
-
-if _cascade is None:
-    os.environ.pop("ERP_FORCE_CASCADE", None)
-else:
-    os.environ["ERP_FORCE_CASCADE"] = _cascade
 
 
 # --- registry semantics -----------------------------------------------------
@@ -179,153 +165,84 @@ def test_oracle_path_untouched():
         assert "named_scope" not in src, f"oracle/{name} uses named_scope"
 
 
-# --- synthetic-module attribution (tools/hlo_attrib.py) ---------------------
+# --- every stage of the dispatched bank step carries its scope ------------
+
+# the stages each step path runs: the ladder's XLA rung (with the health
+# vector) and the Pallas path (resident resampler chain + fused fold)
+_XLA_STAGES = ("resample", "fft", "power", "harmonic", "bank-slice",
+               "merge", "health")
+_PALLAS_STAGES = ("resample", "fftprep", "fft", "power", "sumspec",
+                  "bank-slice", "merge")
 
 
-_SYNTH_HLO = """\
-HloModule synth
+def _bank_step_stages(env, geom, **step_kw):
+    """The stages named by the innermost ``erp.*`` scope of some
+    instruction of ``make_bank_step``'s compiled module (CPU), built and
+    lowered under ``env``."""
+    import jax
+    import jax.numpy as jnp
 
-fused_computation {
-  p0 = f32[1024,256]{1,0} parameter(0)
-  t = f32[256,1024]{0,1} transpose(p0), dimensions={1,0}, metadata={op_name="jit(step)/erp.resample/transpose"}
-  ROOT m = f32[256,1024]{1,0} multiply(t, t), metadata={op_name="jit(step)/erp.resample/mul"}
-}
-
-ENTRY main {
-  p = f32[1024,256]{1,0} parameter(0)
-  f = f32[256,1024]{1,0} fusion(p), kind=kLoop, calls=fused_computation, metadata={op_name="jit(step)/erp.resample/mul"}
-  h = f32[64]{0} add(p, p), metadata={op_name="jit(step)/erp.harmonic/add"}
-  c = f32[1024,256]{1,0} copy(p), metadata={op_name="jit(step)/transpose"}
-  ROOT r = f32[512]{0} add(c, c)
-}
-"""
-
-_MB = 256 * 1024 * 4  # bytes of one f32[1024,256]
-
-
-def test_walk_module_skips_plumbing_and_counts_bodies():
-    rows = list(hlo_attrib.walk_module(_SYNTH_HLO))
-    opcodes = [r[0] for r in rows]
-    # parameters, the fusion caller line: skipped; body instructions kept
-    assert "parameter" not in opcodes
-    assert "fusion" not in opcodes
-    assert opcodes.count("transpose") == 1
-    assert opcodes.count("copy") == 1
-
-
-def test_attribute_module_buckets_by_scope():
-    doc = hlo_attrib.attribute_module(_SYNTH_HLO, batch=2)
-    stages = doc["stages"]
-    assert set(stages) == {"resample", "harmonic"}
-    # transpose + multiply from the fusion body
-    assert stages["resample"]["out_bytes"] == 2 * _MB
-    assert stages["resample"]["layout_bytes"] == _MB  # the transpose
-    assert stages["harmonic"]["out_bytes"] == 64 * 4
-    # copy (op_name without a scope) + root add (no metadata) unattributed
-    assert doc["unattributed_bytes"] == _MB + 512 * 4
-    assert doc["total_bytes"] == (
-        doc["attributed_bytes"] + doc["unattributed_bytes"]
+    from boinc_app_eah_brp_tpu.models.search import (
+        init_state,
+        make_bank_step,
+        upload_bank,
     )
-    un_ops = {row["op"] for row in doc["unattributed_top"]}
-    assert un_ops == {"copy", "add"}
-    # stage rows are rendered in registry (pipeline) order
-    assert list(stages) == ["resample", "harmonic"]
+
+    S = jax.ShapeDtypeStruct
+    with pytest.MonkeyPatch.context() as mp:
+        for k, v in env.items():
+            mp.setenv(k, v)
+        for k in {"ERP_PALLAS_RESIDENT", "ERP_PALLAS_SUMSPEC"} - set(env):
+            mp.delenv(k, raising=False)
+        bank = upload_bank(tuple(np.zeros(4, np.float32) for _ in range(4)), 2)
+        ts = tuple(S((geom.n_unpadded // 2,), jnp.float32) for _ in range(2))
+        text = make_bank_step(geom, 2, **step_kw).lower(
+            ts, *(S(a.shape, a.dtype) for a in bank), S((), jnp.int32),
+            S((), jnp.int32), *jax.eval_shape(lambda: init_state(geom)),
+        ).compile().as_text()
+    return {devicecost.stage_of_op_name(v)
+            for v in devicecost.hlo_op_scopes(text).values()}
 
 
-def test_attribute_module_artifact_validates_and_collapses():
-    doc = {
-        "schema": devicecost.ATTRIB_SCHEMA,
-        "batch": 2,
-        "platform": "cpu",
-        **hlo_attrib.attribute_module(_SYNTH_HLO, batch=2),
-    }
-    assert devicecost.validate_hlo_attrib(doc) == []
-    led = hlo_attrib.ledger_stages(doc)
-    assert set(led) == {"resample", "harmonic-sum", "compiler-generated"}
-    assert led["resample"] == round(2 * _MB / 2 / 1e9, 4)
+def _geom(max_slope, lut_step):
+    from boinc_app_eah_brp_tpu.models.search import SearchGeometry
+    from boinc_app_eah_brp_tpu.oracle.pipeline import DerivedParams, SearchConfig
 
-
-def test_diff_artifacts_flags_coverage_and_stage_growth():
-    base = {
-        "attributed_fraction": 0.9,
-        "stages": {"resample": {"gb_per_template": 1.0}},
-    }
-    worse = {
-        "attributed_fraction": 0.8,  # fell > 0.02
-        "stages": {"resample": {"gb_per_template": 1.5}},  # +50%
-    }
-    problems = hlo_attrib.diff_artifacts(base, worse, threshold_pct=10.0)
-    assert any("attributed_fraction" in p for p in problems)
-    assert any("stage resample" in p for p in problems)
-    assert hlo_attrib.diff_artifacts(base, base, threshold_pct=10.0) == []
-
-
-# --- validators / metrics_report --check ------------------------------------
-
-
-def test_validate_hlo_attrib_catches_breakage():
-    assert devicecost.validate_hlo_attrib("nope") == ["not a JSON object"]
-    doc = {
-        "schema": devicecost.ATTRIB_SCHEMA,
-        "batch": 4,
-        "total_bytes": 10,
-        "attributed_bytes": 8,
-        "attributed_fraction": 0.8,
-        "stages": {"fft": {"out_bytes": 8}},
-        "unattributed_top": [],
-    }
-    assert devicecost.validate_hlo_attrib(doc) == []
-    bad = dict(doc, attributed_fraction=1.7)
-    assert any("outside [0, 1]" in e for e in devicecost.validate_hlo_attrib(bad))
-    bad = dict(doc, stages={"fft": {}})
-    assert any("out_bytes" in e for e in devicecost.validate_hlo_attrib(bad))
-
-
-def test_validate_cost_ledger():
-    doc = {
-        "schema": "erp-cost-ledger/1",
-        "rows": [
-            {
-                "file": "AOT_COST_r05.json",
-                "gb_per_template": 7.9,
-                "ideal_gb_per_template": 0.9,
-                "layout_gb_per_template": {"resample": 0.1},
-            }
-        ],
-    }
-    assert devicecost.validate_cost_ledger(doc) == []
-    bad = {"schema": "erp-cost-ledger/1", "rows": [{"file": "x"}]}
-    errs = devicecost.validate_cost_ledger(bad)
-    assert any("gb_per_template" in e for e in errs)
-
-
-def test_metrics_report_check_dispatches_new_schemas(tmp_path, capsys):
-    attrib = tmp_path / "HLO_ATTRIB_r06.json"
-    attrib.write_text(
-        json.dumps(
-            {
-                "schema": devicecost.ATTRIB_SCHEMA,
-                "batch": 4,
-                "total_bytes": 10,
-                "attributed_bytes": 8,
-                "attributed_fraction": 0.8,
-                "stages": {"fft": {"out_bytes": 8}},
-                "unattributed_top": [],
-            }
-        )
+    derived = DerivedParams.derive(1 << 13, 500.0, SearchConfig(window=200))
+    return SearchGeometry.from_derived(
+        derived, max_slope=max_slope, lut_step=lut_step
     )
-    ledger = tmp_path / "COST_LEDGER.json"
-    ledger.write_text(
-        json.dumps({"schema": "erp-cost-ledger/1", "rows": []})
+
+
+@pytest.fixture(scope="module")
+def xla_step_stages():
+    return _bank_step_stages({}, _geom(0.5, 0.05), with_health=True,
+                             allow_pallas=False)
+
+
+@pytest.fixture(scope="module")
+def pallas_step_stages():
+    # slope/LUT bounds inside the resident chain's gates (the PALFA
+    # bank's pow2-ceil'd values)
+    geom = _geom(0.00390625, 1.52587890625e-05)
+    return _bank_step_stages(
+        {"ERP_PALLAS_RESIDENT": "1", "ERP_PALLAS_SUMSPEC": "1"}, geom
     )
-    rc = metrics_report.main(["--check", str(attrib), str(ledger)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "OK (erp-hlo-attrib/1)" in out
-    assert "OK (erp-cost-ledger/1)" in out
-    # a malformed artifact of either schema fails the gate
-    attrib.write_text(json.dumps({"schema": devicecost.ATTRIB_SCHEMA}))
-    assert metrics_report.main(["--check", str(attrib)]) == 1
+
+
+@pytest.mark.parametrize(
+    "path,stage",
+    [("xla", s) for s in _XLA_STAGES] + [("pallas", s) for s in _PALLAS_STAGES],
+)
+def test_bank_step_stage_carries_its_scope(path, stage, request):
+    """Each stage the dispatched step runs is the innermost ``erp.*``
+    scope of some instruction of its compiled module: the per-stage device
+    times of the profiler trace (``resample.ms_per_t``, ``fft.ms_per_t``,
+    ``harmonic.ms_per_t``, ...) read the step's events through these
+    scopes, so a stage that lost its scope would fall silently into
+    ``step.other``."""
+    stages = request.getfixturevalue(f"{path}_step_stages")
+    assert stage in stages, sorted(s for s in stages if s)
 
 
 # --- device records in the Chrome export ----------------------------------
@@ -532,87 +449,3 @@ def test_profiler_records_is_list_like():
     full = devicecost.ProfilerRecords(records=[rec], path="p")
     assert bool(full) and len(full) == 1 and list(full) == [rec]
     assert full.warning is None
-
-
-# --- cost_ledger attribution-artifact consumption ---------------------------
-
-
-def _aot_cost(path, gb=5.0, hotspots=()):
-    doc = {
-        "batch": 2,
-        "compiler": {
-            "bytes_accessed_per_template": gb * 1e9,
-            "flops_per_template": 1e9,
-        },
-        "roofline_model": {"ideal_bytes_per_template": 1e9},
-        "bytes_vs_model": gb,
-        "layout_hotspots": list(hotspots),
-    }
-    path.write_text(json.dumps(doc))
-
-
-def _attrib(path, batch=2, stages=None):
-    stages = stages or {"resample": 2.0e9, "fft": 1.0e9}
-    doc = {
-        "schema": devicecost.ATTRIB_SCHEMA,
-        "batch": batch,
-        "total_bytes": sum(stages.values()) + 1.0e9,
-        "attributed_bytes": sum(stages.values()),
-        "attributed_fraction": 0.75,
-        "stages": {
-            k: {"out_bytes": v, "ledger_stage": devicecost.ledger_stage(k)}
-            for k, v in stages.items()
-        },
-        "unattributed_bytes": 1.0e9,
-        "unattributed_top": [],
-    }
-    doc["ledger_stages"] = {
-        **{
-            devicecost.ledger_stage(k): round(v / batch / 1e9, 4)
-            for k, v in stages.items()
-        },
-        "compiler-generated": round(1.0e9 / batch / 1e9, 4),
-    }
-    path.write_text(json.dumps(doc))
-
-
-def test_cost_ledger_prefers_attrib_sibling(tmp_path):
-    _aot_cost(
-        tmp_path / "AOT_COST_r06.json",
-        hotspots=[{"out_bytes": 4e8, "source": "resample_split"}],
-    )
-    _attrib(tmp_path / "HLO_ATTRIB_r06.json")
-    ledger = cost_ledger.build_ledger(str(tmp_path))
-    (row,) = ledger["rows"]
-    assert row["stage_source"] == "hlo-attrib"
-    assert row["layout_gb_per_template"]["resample"] == 1.0
-    assert row["layout_gb_per_template"]["compiler-generated"] == 0.5
-    assert devicecost.validate_cost_ledger(ledger) == []
-
-
-def test_cost_ledger_falls_back_to_markers(tmp_path):
-    _aot_cost(
-        tmp_path / "AOT_COST_r06.json",
-        hotspots=[{"out_bytes": 4e8, "source": "resample_split"}],
-    )
-    ledger = cost_ledger.build_ledger(str(tmp_path))
-    (row,) = ledger["rows"]
-    assert row["stage_source"] == "layout-hotspots"
-    assert row["layout_gb_per_template"] == {"resample": 0.2}
-
-
-def test_cost_ledger_stage_gate_and_methodology_guard(tmp_path):
-    # r06 marker-based, r07+r08 attribution-based with a stage regression
-    _aot_cost(tmp_path / "AOT_COST_r06.json")
-    _aot_cost(tmp_path / "AOT_COST_r07.json")
-    _attrib(tmp_path / "HLO_ATTRIB_r07.json", stages={"resample": 2.0e9})
-    _aot_cost(tmp_path / "AOT_COST_r08.json")
-    _attrib(tmp_path / "HLO_ATTRIB_r08.json", stages={"resample": 3.0e9})
-    ledger = cost_ledger.build_ledger(str(tmp_path))
-    flags = cost_ledger.flag_regressions(ledger, threshold_pct=10.0)
-    # methodology switch r06->r07 is NOT flagged; the real r07->r08
-    # growth (1.0 -> 1.5 GB/template) is, naming the stage
-    stage_flags = [f for f in flags if "stage " in f]
-    assert len(stage_flags) == 1
-    assert "stage resample" in stage_flags[0]
-    assert "AOT_COST_r08.json" in stage_flags[0]

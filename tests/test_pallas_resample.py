@@ -9,10 +9,10 @@ import pytest
 from boinc_app_eah_brp_tpu.models.search import template_params_host
 from boinc_app_eah_brp_tpu.ops.pallas_resample import (
     pallas_applicable,
-    resample_split_pallas,
+    resample_fftprep_pallas_batch,
 )
 from boinc_app_eah_brp_tpu.ops.resample import resample_split
-from fixtures import synthetic_timeseries
+from fixtures import one_bank_step, synthetic_timeseries
 
 
 # production-like slope/LUT bounds (the PALFA bank's pow2-ceil'd values)
@@ -27,6 +27,18 @@ def _mk(n, P, tau, psi, padding=1.5):
     nsamples += nsamples % 2  # parity-split needs even padded length
     t32, om, ps0, s0 = template_params_host(P, tau, psi, dt)
     return ts, dt, nsamples, (t32, om, ps0, s0)
+
+
+def _chain(ev, od, params, **kw):
+    """The resident chain over a batch of ``params`` (one
+    ``template_params_host`` tuple per template), in interpret mode."""
+    tb = tuple(
+        jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
+        for i in range(4)
+    )
+    return resample_fftprep_pallas_batch(
+        ev, od, *tb, lut_tiles=1024, interpret=True, **kw
+    )
 
 
 def test_gates():
@@ -61,11 +73,9 @@ def test_bit_parity_with_xla_path(P, tau, psi):
     want_e, want_o = resample_split(
         ev, od, t32, om, ps0, s0, use_lut=True, lut_tiles=1024, **kw
     )
-    got_e, got_o = resample_split_pallas(
-        ev, od, t32, om, ps0, s0, lut_tiles=1024, interpret=True, **kw
-    )
-    np.testing.assert_array_equal(np.asarray(got_e), np.asarray(want_e))
-    np.testing.assert_array_equal(np.asarray(got_o), np.asarray(want_o))
+    got_e, got_o = _chain(ev, od, [(t32, om, ps0, s0)], **kw)
+    np.testing.assert_array_equal(np.asarray(got_e)[0], np.asarray(want_e))
+    np.testing.assert_array_equal(np.asarray(got_o)[0], np.asarray(want_o))
 
 
 def test_bit_parity_partial_tail_block():
@@ -85,16 +95,15 @@ def test_bit_parity_partial_tail_block():
     want = resample_split(
         ev, od, t32, om, ps0, s0, use_lut=True, lut_tiles=1024, **kw
     )
-    got = resample_split_pallas(
-        ev, od, t32, om, ps0, s0, lut_tiles=1024, interpret=True, **kw
-    )
+    got = _chain(ev, od, [(t32, om, ps0, s0)], **kw)
     for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        np.testing.assert_array_equal(np.asarray(g)[0], np.asarray(w))
 
 
 def test_batched_variant_matches_vmapped_xla():
-    """resample_split_pallas_batch (one launch, (T, parity, block) grid)
-    == vmapped XLA path, bit for bit."""
+    """The chain over a batch (one launch, (T, parity, block) grid) ==
+    vmapped XLA path, with its mean fill and FFT-prep padding, bit for
+    bit."""
     import jax
 
     n = 1 << 13
@@ -108,11 +117,6 @@ def test_batched_variant_matches_vmapped_xla():
         max_slope=MAX_SLOPE,
         lut_step=LUT_STEP,
     )
-    from boinc_app_eah_brp_tpu.models.search import template_params_host
-    from boinc_app_eah_brp_tpu.ops.pallas_resample import (
-        resample_split_pallas_batch,
-    )
-
     params = [
         template_params_host(P, tau, psi, dt)
         for P, tau, psi in [(1000.0, 0.0, 0.0), (400.0, 0.1, 1.2)]
@@ -121,9 +125,7 @@ def test_batched_variant_matches_vmapped_xla():
         jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
         for i in range(4)
     )
-    pe, po = resample_split_pallas_batch(
-        ev, od, *tb, lut_tiles=1024, interpret=True, **kw
-    )
+    pe, po = _chain(ev, od, params, **kw)
     we, wo = jax.vmap(
         lambda a, b, c, d: resample_split(
             ev, od, a, b, c, d, use_lut=True, lut_tiles=1024, **kw
@@ -131,81 +133,6 @@ def test_batched_variant_matches_vmapped_xla():
     )(*tb)
     np.testing.assert_array_equal(np.asarray(pe), np.asarray(we))
     np.testing.assert_array_equal(np.asarray(po), np.asarray(wo))
-
-
-def test_model_step_with_pallas_gate(monkeypatch):
-    """ERP_PALLAS_RESAMPLE=1 routes make_batch_step through the fused
-    kernel (interpret mode under the CPU test platform is exercised via
-    the kernel's own interpret flag only in unit tests; here we assert
-    gating logic, not execution)."""
-    from boinc_app_eah_brp_tpu.models.search import (
-        SearchGeometry,
-        use_pallas_resample,
-    )
-    from boinc_app_eah_brp_tpu.oracle.pipeline import DerivedParams, SearchConfig
-
-    cfg = SearchConfig(window=200)
-    derived = DerivedParams.derive(1 << 13, 500.0, cfg)
-    geom_ok = SearchGeometry.from_derived(
-        derived, max_slope=MAX_SLOPE, lut_step=LUT_STEP
-    )
-    geom_steep = SearchGeometry.from_derived(
-        derived, max_slope=0.5, lut_step=LUT_STEP
-    )
-    monkeypatch.delenv("ERP_PALLAS_RESAMPLE", raising=False)
-    assert not use_pallas_resample(geom_ok)
-    monkeypatch.setenv("ERP_PALLAS_RESAMPLE", "1")
-    assert use_pallas_resample(geom_ok)
-    assert not use_pallas_resample(geom_steep)  # select span gate
-
-
-def test_integrated_batch_step_matches_xla_step(monkeypatch):
-    """ERP_PALLAS_RESAMPLE=1: the full batched search step (pallas
-    resample -> packed FFT -> harmonic sum -> merge) produces the
-    identical (M, T) state as the production XLA step."""
-    import jax
-
-    from boinc_app_eah_brp_tpu.models.search import (
-        SearchGeometry,
-        init_state,
-        make_batch_step,
-        prepare_ts,
-        template_params_host,
-        use_pallas_resample,
-    )
-    from boinc_app_eah_brp_tpu.oracle.pipeline import DerivedParams, SearchConfig
-
-    n = 1 << 13
-    ts = synthetic_timeseries(
-        n, f_signal=33.0, P_orb=400.0, tau=0.1, psi0=1.2, amp=7.0
-    )
-    cfg = SearchConfig(window=200, padding=1.5)
-    derived = DerivedParams.derive(n, 500.0, cfg)
-    geom = SearchGeometry.from_derived(
-        derived, max_slope=MAX_SLOPE, lut_step=LUT_STEP
-    )
-    params = [
-        template_params_host(P, tau, psi, geom.dt)
-        for P, tau, psi in [(1000.0, 0.0, 0.0), (400.0, 0.1, 1.2)]
-    ]
-    tb = tuple(
-        jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
-        for i in range(4)
-    )
-    ts_args = prepare_ts(geom, ts)
-
-    monkeypatch.delenv("ERP_PALLAS_RESAMPLE", raising=False)
-    step_xla = make_batch_step(geom)
-    M0, T0 = init_state(geom)
-    M1, T1 = step_xla(ts_args, *tb, jnp.int32(0), M0, T0)
-
-    monkeypatch.setenv("ERP_PALLAS_RESAMPLE", "1")
-    assert use_pallas_resample(geom)
-    step_pl = make_batch_step(geom)
-    M2, T2 = step_pl(ts_args, *tb, jnp.int32(0), M0, T0)
-
-    np.testing.assert_array_equal(np.asarray(M1), np.asarray(M2))
-    np.testing.assert_array_equal(np.asarray(T1), np.asarray(T2))
 
 
 # --- resident resample -> FFT-prep chain -------------------------------------
@@ -292,8 +219,8 @@ def test_resident_gate_is_on_by_default_on_tpu(monkeypatch):
         use_pallas_resident,
     )
 
-    for env in ("ERP_PALLAS_RESAMPLE", "ERP_PALLAS_RESIDENT",
-                "ERP_PALLAS_SUMSPEC", "ERP_FORCE_CASCADE"):
+    for env in ("ERP_PALLAS_RESIDENT", "ERP_PALLAS_SUMSPEC",
+                "ERP_FORCE_CASCADE"):
         monkeypatch.delenv(env, raising=False)
     geom_ok, _, _ = _prod_geom(1 << 13)
     geom_steep = dataclasses.replace(geom_ok, max_slope=0.5)
@@ -326,14 +253,13 @@ def test_fftprep_is_registered_stage():
 
 @pytest.mark.parametrize("n", [1 << 13, 10000])
 def test_resident_chain_matches_two_stage(n):
-    """resample_fftprep_pallas_batch == resample_split_pallas_batch bit
-    for bit — same head select, same mean fill, same tail — including the
-    partial-tail-block geometry (n=10000: half=5000, one full + one
-    partial raw block against a padded output grid)."""
-    from boinc_app_eah_brp_tpu.ops.pallas_resample import (
-        resample_fftprep_pallas_batch,
-        resample_split_pallas_batch,
-    )
+    """resample_fftprep_pallas_batch == the two-stage XLA form (the
+    vmapped resample_split, then its mean fill and tail) bit for bit —
+    same head select, same mean fill, same tail — over three
+    templates, including the partial-tail-block geometry (n=10000:
+    half=5000, one full + one partial raw block against a padded output
+    grid)."""
+    import jax
 
     ts, dt, nsamples, _ = _mk(n, 400.0, 0.1, 1.2)
     ev = jnp.asarray(ts[0::2].copy())
@@ -354,12 +280,12 @@ def test_resident_chain_matches_two_stage(n):
         jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
         for i in range(4)
     )
-    we, wo = resample_split_pallas_batch(
-        ev, od, *tb, lut_tiles=1024, interpret=True, **kw
-    )
-    ge, go = resample_fftprep_pallas_batch(
-        ev, od, *tb, lut_tiles=1024, interpret=True, **kw
-    )
+    we, wo = jax.vmap(
+        lambda a, b, c, d: resample_split(
+            ev, od, a, b, c, d, use_lut=True, lut_tiles=1024, **kw
+        )
+    )(*tb)
+    ge, go = _chain(ev, od, params, **kw)
     np.testing.assert_array_equal(np.asarray(ge), np.asarray(we))
     np.testing.assert_array_equal(np.asarray(go), np.asarray(wo))
 
@@ -408,12 +334,10 @@ def test_kernel_renorm_fold_matches_prescaled_series():
 
 
 def test_integrated_resident_step_matches_xla_step(monkeypatch):
-    """ERP_PALLAS_RESIDENT=1: the full batched search step (resident
-    resample -> FFT-prep -> packed FFT -> harmonic sum -> merge) produces
-    the identical (M, T) state as the production XLA step."""
+    """ERP_PALLAS_RESIDENT=1: the full bank step (resident resample ->
+    FFT-prep -> packed FFT -> harmonic sum -> merge) produces the
+    identical (M, T) state as the production XLA step."""
     from boinc_app_eah_brp_tpu.models.search import (
-        init_state,
-        make_batch_step,
         prepare_ts,
         use_pallas_resident,
     )
@@ -427,23 +351,17 @@ def test_integrated_resident_step_matches_xla_step(monkeypatch):
         template_params_host(P, tau, psi, geom.dt)
         for P, tau, psi in [(1000.0, 0.0, 0.0), (400.0, 0.1, 1.2)]
     ]
-    tb = tuple(
-        jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
-        for i in range(4)
-    )
     ts_args = prepare_ts(geom, ts)
-    M0, T0 = init_state(geom)
 
-    monkeypatch.delenv("ERP_PALLAS_RESAMPLE", raising=False)
     monkeypatch.delenv("ERP_PALLAS_RESIDENT", raising=False)
-    M1, T1 = make_batch_step(geom)(ts_args, *tb, jnp.int32(0), M0, T0)
+    M1, T1 = one_bank_step(geom, ts_args, params)
 
     monkeypatch.setenv("ERP_PALLAS_RESIDENT", "1")
     assert use_pallas_resident(geom)
-    M2, T2 = make_batch_step(geom)(ts_args, *tb, jnp.int32(0), M0, T0)
+    M2, T2 = one_bank_step(geom, ts_args, params)
 
-    np.testing.assert_array_equal(np.asarray(M1), np.asarray(M2))
-    np.testing.assert_array_equal(np.asarray(T1), np.asarray(T2))
+    np.testing.assert_array_equal(M1, M2)
+    np.testing.assert_array_equal(T1, T2)
 
 
 def test_step_deferred_renorm_matches_prescaled(monkeypatch):
@@ -453,11 +371,7 @@ def test_step_deferred_renorm_matches_prescaled(monkeypatch):
     the identical (M, T) as the prescaled series through the plain step."""
     import dataclasses
 
-    from boinc_app_eah_brp_tpu.models.search import (
-        init_state,
-        make_batch_step,
-        prepare_ts,
-    )
+    from boinc_app_eah_brp_tpu.models.search import prepare_ts
 
     n = 1 << 13
     ts = synthetic_timeseries(
@@ -471,30 +385,22 @@ def test_step_deferred_renorm_matches_prescaled(monkeypatch):
         template_params_host(P, tau, psi, geom.dt)
         for P, tau, psi in [(1000.0, 0.0, 0.0), (400.0, 0.1, 1.2)]
     ]
-    tb = tuple(
-        jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
-        for i in range(4)
-    )
-    M0, T0 = init_state(geom)
 
-    monkeypatch.delenv("ERP_PALLAS_RESAMPLE", raising=False)
     monkeypatch.delenv("ERP_PALLAS_RESIDENT", raising=False)
-    Mr, Tr = make_batch_step(geom)(
-        prepare_ts(geom, ts_scaled), *tb, jnp.int32(0), M0, T0
-    )
+    Mr, Tr = one_bank_step(geom, prepare_ts(geom, ts_scaled), params)
 
     geom_def = dataclasses.replace(geom, ts_prescaled=False)
     args_def = prepare_ts(geom_def, ts32)
     # XLA step prescales inside the step (fallback-rung semantics)
-    M1, T1 = make_batch_step(geom_def)(args_def, *tb, jnp.int32(0), M0, T0)
-    np.testing.assert_array_equal(np.asarray(M1), np.asarray(Mr))
-    np.testing.assert_array_equal(np.asarray(T1), np.asarray(Tr))
+    M1, T1 = one_bank_step(geom_def, args_def, params)
+    np.testing.assert_array_equal(M1, Mr)
+    np.testing.assert_array_equal(T1, Tr)
 
     # resident chain folds the renorm into the kernel gather
     monkeypatch.setenv("ERP_PALLAS_RESIDENT", "1")
-    M2, T2 = make_batch_step(geom_def)(args_def, *tb, jnp.int32(0), M0, T0)
-    np.testing.assert_array_equal(np.asarray(M2), np.asarray(Mr))
-    np.testing.assert_array_equal(np.asarray(T2), np.asarray(Tr))
+    M2, T2 = one_bank_step(geom_def, args_def, params)
+    np.testing.assert_array_equal(M2, Mr)
+    np.testing.assert_array_equal(T2, Tr)
 
 
 def test_whiten_defer_renorm_requires_packed_split_path(monkeypatch):
@@ -550,8 +456,8 @@ def test_step_cache_key_folds_gates(monkeypatch):
     from boinc_app_eah_brp_tpu.models.search import step_cache_key
 
     geom, _, _ = _prod_geom(1 << 13)
-    for env in ("ERP_PALLAS_RESAMPLE", "ERP_PALLAS_RESIDENT",
-                "ERP_PALLAS_SUMSPEC", "ERP_FORCE_CASCADE"):
+    for env in ("ERP_PALLAS_RESIDENT", "ERP_PALLAS_SUMSPEC",
+                "ERP_FORCE_CASCADE"):
         monkeypatch.delenv(env, raising=False)
     k0 = step_cache_key(geom, 4, False, True)
     assert k0 == step_cache_key(geom, 4, False, True)  # stable
@@ -573,7 +479,8 @@ def test_step_cache_key_folds_gates(monkeypatch):
 
     monkeypatch.delenv("ERP_FORCE_CASCADE", raising=False)
     monkeypatch.delenv("ERP_PALLAS_RESIDENT", raising=False)
-    monkeypatch.setenv("ERP_PALLAS_RESAMPLE", "1")
+    assert step_cache_key(geom, 4, False, True) == k0
+    monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")  # the fold's gate
     assert step_cache_key(geom, 4, False, True) != k0
 
 

@@ -5,11 +5,8 @@ the fold's gate (default on a TPU, ERP_PALLAS_SUMSPEC=1 elsewhere), its
 counter and cache key, the ERP_PRECISION contract, layout pinning (zero
 recompiles across dispatch windows; the v5e compile of the pinned step
 is in tests/test_tpu_compile.py),
-and named-scope attribution (the kernel's bytes must land under
-erp.sumspec, not "compiler-generated")."""
-
-import os
-import sys
+and named-scope attribution (the kernel's instructions must carry
+erp.sumspec)."""
 
 import numpy as np
 import pytest
@@ -26,7 +23,6 @@ from boinc_app_eah_brp_tpu.models.search import (
     bank_step_layouts,
     erp_precision,
     make_bank_step,
-    make_batch_step,
     state_to_natural,
     step_cache_key,
     use_pallas_sumspec,
@@ -45,11 +41,7 @@ from boinc_app_eah_brp_tpu.oracle import (
     update_toplist_from_maxima,
 )
 from boinc_app_eah_brp_tpu.runtime import devicecost, metrics
-from fixtures import small_bank, synthetic_timeseries
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "tools"))
-
+from fixtures import one_bank_step, small_bank, synthetic_timeseries
 
 # --- gating ------------------------------------------------------------------
 
@@ -142,9 +134,7 @@ def test_precision_bf16_raises_not_implemented(monkeypatch):
     monkeypatch.setenv("ERP_PRECISION", "bf16")
     with pytest.raises(NotImplementedError, match="bf16"):
         erp_precision()
-    with pytest.raises(NotImplementedError, match="ROADMAP"):
-        make_batch_step(_tiny_geom())
-    with pytest.raises(NotImplementedError, match="f32"):
+    with pytest.raises(NotImplementedError, match="ROADMAP.*f32"):
         make_bank_step(_tiny_geom(), batch_size=2)
 
 
@@ -192,11 +182,10 @@ def _tiny_geom(n=4096):
 
 
 def test_integrated_batch_step_matches_xla_step(monkeypatch):
-    """ERP_PALLAS_SUMSPEC=1: the full batched search step (resample ->
-    packed FFT -> fused fold -> merge) produces the identical (M, T)
-    state as the production XLA step."""
+    """ERP_PALLAS_SUMSPEC=1: the full bank step (resample -> packed FFT
+    -> fused fold -> merge) produces the identical (M, T) state as the
+    production XLA step."""
     from boinc_app_eah_brp_tpu.models.search import (
-        init_state,
         prepare_ts,
         template_params_host,
     )
@@ -210,21 +199,16 @@ def test_integrated_batch_step_matches_xla_step(monkeypatch):
         template_params_host(P, tau, psi, geom.dt)
         for P, tau, psi in [(1000.0, 0.0, 0.0), (400.0, 0.1, 1.2)]
     ]
-    tb = tuple(
-        jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
-        for i in range(4)
-    )
     ts_args = prepare_ts(geom, ts)
-    M0, T0 = init_state(geom)
 
     monkeypatch.delenv("ERP_PALLAS_SUMSPEC", raising=False)
-    M1, T1 = make_batch_step(geom)(ts_args, *tb, jnp.int32(0), M0, T0)
+    M1, T1 = one_bank_step(geom, ts_args, params)
     monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
     assert use_pallas_sumspec(geom)
-    M2, T2 = make_batch_step(geom)(ts_args, *tb, jnp.int32(0), M0, T0)
+    M2, T2 = one_bank_step(geom, ts_args, params)
 
-    np.testing.assert_array_equal(np.asarray(M1), np.asarray(M2))
-    np.testing.assert_array_equal(np.asarray(T1), np.asarray(T2))
+    np.testing.assert_array_equal(M1, M2)
+    np.testing.assert_array_equal(T1, T2)
 
 
 # --- golden vs the CPU oracle ------------------------------------------------
@@ -389,45 +373,35 @@ def test_run_bank_pallas_fallback_is_byte_identical(monkeypatch):
 
 
 def test_fused_bytes_attribute_to_sumspec_stage(monkeypatch):
-    """The fused kernel's traffic lands under its own erp.sumspec scope
-    in the OPTIMIZED module — not the unattributed remainder that
-    cost_ledger books as "compiler-generated"."""
-    # importing hlo_attrib forces the FFT cascade for the whole process
-    # (tools/_aot_common.py::use_cpu_backend): set it here so that it is
-    # undone after this test, and later tests in this worker keep XLA's FFT
-    monkeypatch.setenv("ERP_FORCE_CASCADE", "1")
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    import hlo_attrib
-
+    """The fold kernel's instructions in the dispatched bank step's
+    OPTIMIZED module carry its own erp.sumspec scope — not an unscoped
+    remainder the trace would book as ``step.other`` — and the stage
+    collapses into the harmonic-sum bucket."""
     monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
-    geom = _tiny_geom()
-    step = make_batch_step(geom)
     from boinc_app_eah_brp_tpu.models.search import (
+        bank_params_host,
         init_state,
         prepare_ts,
-        template_params_host,
+        upload_bank,
     )
 
-    ts_args = prepare_ts(geom, synthetic_timeseries(4096))
-    params = [
-        template_params_host(P, tau, psi, geom.dt)
-        for P, tau, psi in [(1000.0, 0.0, 0.0), (400.0, 0.1, 1.2)]
-    ]
-    tb = tuple(
-        jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
-        for i in range(4)
+    geom = _tiny_geom()
+    bank = small_bank()
+    bparams = upload_bank(
+        bank_params_host(bank.P, bank.tau, bank.psi0, geom.dt), 2
     )
-    M0, T0 = init_state(geom)
+    ts_args = prepare_ts(geom, synthetic_timeseries(4096))
     txt = (
-        jax.jit(step.__wrapped__)
-        .lower(ts_args, *tb, jnp.int32(0), M0, T0)
+        make_bank_step(geom, 2)
+        .lower(ts_args, *bparams, jnp.int32(0), jnp.int32(len(bank.P)),
+               *init_state(geom))
         .compile()
         .as_text()
     )
-    assert "erp.sumspec" in txt
-    doc = hlo_attrib.attribute_module(txt, batch=2)
-    row = doc["stages"].get("sumspec")
-    assert row is not None and row["out_bytes"] > 0
-    # and the ledger collapse books it under harmonic-sum
-    ledger = hlo_attrib.ledger_stages(doc)
-    assert ledger.get("harmonic-sum", 0) > 0
+    scopes = devicecost.hlo_op_scopes(txt)
+    sumspec = [
+        name for name, op in scopes.items()
+        if devicecost.stage_of_op_name(op) == "sumspec"
+    ]
+    assert sumspec, sorted(set(scopes.values()))[:20]
+    assert devicecost.ledger_stage("sumspec") == "harmonic-sum"

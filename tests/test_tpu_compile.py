@@ -163,32 +163,15 @@ def test_layout_pinned_bank_step(topo, monkeypatch):
     """The donated, layout-pinned bank step with the fused fold kernel:
     row-major (M, T) on both sides of the donation, so the state aliases
     through every dispatch window unchanged."""
-    from boinc_app_eah_brp_tpu.models.search import (
-        SearchGeometry,
-        bank_step_layouts,
-        init_state,
-        make_bank_step,
-        upload_bank,
-    )
+    from _aot_common import compile_step
+
+    from boinc_app_eah_brp_tpu.models.search import SearchGeometry
     from boinc_app_eah_brp_tpu.oracle.pipeline import DerivedParams, SearchConfig
 
     monkeypatch.setenv("ERP_PALLAS_SUMSPEC", "1")
     cfg = SearchConfig(window=200, padding=1.5)
     geom = SearchGeometry.from_derived(DerivedParams.derive(4096, 500.0, cfg))
-    B = 4
-    bank = upload_bank(tuple(np.zeros(8, np.float32) for _ in range(4)), B)
-    M, T = jax.eval_shape(lambda: init_state(geom))
-    S = jax.ShapeDtypeStruct
-    ts = tuple(S((geom.n_unpadded // 2,), jnp.float32) for _ in range(2))
-
-    fn = make_bank_step(geom, batch_size=B).__wrapped__
-    in_sh, out_sh = bank_step_layouts(geom, False, topo.devices[0])
-    comp = jax.jit(
-        fn, donate_argnums=(7, 8), in_shardings=in_sh, out_shardings=out_sh
-    ).lower(
-        ts, *(S(a.shape, a.dtype) for a in bank), S((), jnp.int32),
-        S((), jnp.int32), M, T,
-    ).compile()
+    comp = compile_step(geom, 4, topo.devices[0])
     text = comp.as_text()
     assert "tpu_custom_call" in text and "erp.sumspec" in text
     # the TPU's profile names events after these instructions: the text's
@@ -220,15 +203,13 @@ def test_resident_bank_step_at_production_widths(topo, monkeypatch, f0,
     (M, T) stay row-major."""
     import dataclasses
 
+    from _aot_common import compile_step
+
     from boinc_app_eah_brp_tpu.models.search import (
         SearchGeometry,
-        bank_step_layouts,
-        init_state,
         lut_step_for_bank,
-        make_bank_step,
         max_slope_for_bank,
         resident_defers_renorm,
-        upload_bank,
         use_pallas_resident,
     )
     from boinc_app_eah_brp_tpu.oracle.pipeline import DerivedParams, SearchConfig
@@ -244,19 +225,7 @@ def test_resident_bank_step_at_production_widths(topo, monkeypatch, f0,
     )
     assert use_pallas_resident(geom) and resident_defers_renorm(geom)
     geom = dataclasses.replace(geom, ts_prescaled=False)
-    bank = upload_bank(tuple(np.zeros(8, np.float32) for _ in range(4)), BATCH)
-    M, T = jax.eval_shape(lambda: init_state(geom))
-    S = jax.ShapeDtypeStruct
-    ts = tuple(S((geom.n_unpadded // 2,), jnp.float32) for _ in range(2))
-
-    fn = make_bank_step(geom, batch_size=BATCH).__wrapped__
-    in_sh, out_sh = bank_step_layouts(geom, False, topo.devices[0])
-    comp = jax.jit(
-        fn, donate_argnums=(7, 8), in_shardings=in_sh, out_shardings=out_sh
-    ).lower(
-        ts, *(S(a.shape, a.dtype) for a in bank), S((), jnp.int32),
-        S((), jnp.int32), M, T,
-    ).compile()
+    comp = compile_step(geom, BATCH, topo.devices[0])
     text = comp.as_text()
     ops, kernels = _ops_by_stage(text)
     assert {"resample", "fftprep", "sumspec"} <= kernels, kernels

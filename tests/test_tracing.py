@@ -1,7 +1,7 @@
 """Host span tracing (runtime/tracing.py): the zero-cost disabled path,
 ring/stream/Chrome-export consistency, cross-thread trace contexts, the
-validators that gate the artifacts, and the trace_report / cost_ledger
-reductions built on top."""
+validators that gate the artifacts, and the trace_report reductions
+built on top."""
 
 import json
 import os
@@ -17,7 +17,6 @@ from boinc_app_eah_brp_tpu.runtime import metrics, tracing
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-import cost_ledger  # noqa: E402
 import metrics_report  # noqa: E402
 import trace_report  # noqa: E402
 
@@ -548,56 +547,3 @@ def test_trace_report_windows_and_json(tmp_path, capsys):
     table = json.loads(out[0])
     assert table["main_lane"] == "MainThread"
     assert any("ctx" in l for l in out[1:])
-
-
-# ---------------------------------------------------------------------------
-# cost_ledger: the chip-free traffic trajectory
-
-
-def _aot_file(dirpath, n, bytes_per_template, stage_bytes=0):
-    doc = {
-        "batch": 2,
-        "compiler": {
-            "bytes_accessed_per_template": bytes_per_template,
-            "flops_per_template": 1e9,
-        },
-        "roofline_model": {"ideal_bytes_per_template": 9.437e8},
-        "bytes_vs_model": bytes_per_template / 9.437e8,
-        "layout_hotspots": [
-            {
-                "op": "copy",
-                "source": "jit(step)/vmap(jit(harmonic_sumspec))/reshape",
-                "count": 3,
-                "out_bytes": stage_bytes,
-            }
-        ],
-    }
-    path = os.path.join(dirpath, f"AOT_COST_r{n:02d}.json")
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return path
-
-
-def test_cost_ledger_reduces_committed_artifact():
-    ledger = cost_ledger.build_ledger(REPO)
-    assert ledger["rows"], "repo must carry at least one AOT_COST artifact"
-    row = ledger["rows"][0]
-    assert row["gb_per_template"] > row["ideal_gb_per_template"] > 0
-    assert "harmonic-sum" in row["layout_gb_per_template"]
-    assert "fft+power" in row["layout_gb_per_template"]
-
-
-def test_cost_ledger_strict_flags_traffic_growth(tmp_path, capsys):
-    _aot_file(tmp_path, 1, 5.0e9, stage_bytes=1_000_000_000)
-    _aot_file(tmp_path, 2, 5.1e9, stage_bytes=1_000_000_000)  # +2%: fine
-    assert cost_ledger.main(["--root", str(tmp_path), "--strict"]) == 0
-    assert os.path.exists(tmp_path / cost_ledger.LEDGER_PATH)
-    capsys.readouterr()
-    _aot_file(tmp_path, 3, 7.0e9, stage_bytes=3_000_000_000)  # +37%
-    assert cost_ledger.main(["--root", str(tmp_path), "--strict"]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "gb_per_template" in out
-    assert "harmonic-sum" in out  # the stage growth is named too
-    doc = json.load(open(tmp_path / cost_ledger.LEDGER_PATH))
-    assert doc["schema"] == cost_ledger.SCHEMA
-    assert [r["round"] for r in doc["rows"]] == [1, 2, 3]

@@ -1,9 +1,9 @@
-"""Shared plumbing for the deviceless AOT tools (aot_prewarm, aot_analyze).
+"""Shared plumbing for the deviceless AOT compiles (``tools/aot_prewarm.py``
+and ``tests/test_tpu_compile.py``).
 
-Both tools must compile EXACTLY the program the live chain runs, so the
+Both must compile EXACTLY the program the live chain runs, so the
 geometry derivation, trace-time knobs, topology resolution and
-lower/compile sequence live here once — a drifted copy would silently
-produce artifacts describing different executables.
+lower/compile sequence live here once.
 """
 
 from __future__ import annotations
@@ -17,13 +17,20 @@ import os
 PRODUCTION_BANK = (
     "/root/reference/debian/extra/einstein_bench/testwu/stochastic_full.bank"
 )
+# its template count, for hosts without it: the uploaded bank's padded
+# length is part of the step's signature (models/search.py::upload_bank)
+PRODUCTION_BANK_SIZE = 6662
 
 
 def use_cpu_backend() -> None:
     """The deviceless tools compile FOR a described TPU from the CPU
-    backend, with the FFT cascade the TPU traces.  Call BEFORE importing
-    jax."""
-    os.environ["ERP_FORCE_CASCADE"] = "1"  # mirror the TPU trace
+    backend, with what the TPU traces: the FFT cascade, the resident
+    resampler chain and the fused harmonic fold, lowered by Mosaic (not
+    interpreted).  Call BEFORE importing jax."""
+    os.environ["ERP_FORCE_CASCADE"] = "1"
+    os.environ["ERP_PALLAS_RESIDENT"] = "1"
+    os.environ["ERP_PALLAS_SUMSPEC"] = "1"
+    os.environ["ERP_PALLAS_INTERPRET"] = "0"
     os.environ["JAX_PLATFORMS"] = "cpu"
 
 
@@ -37,7 +44,8 @@ def topology_devices(topology: str = "v5e:2x2"):
 
 
 def production_geometry(nsamples: int, tsample_us: float, bank_path: str):
-    """(geom, derived) exactly as the driver derives them for the WU."""
+    """(geom, n_templates): the geometry exactly as the driver derives it
+    for the WU, and the bank's template count."""
     import numpy as np
 
     from boinc_app_eah_brp_tpu.models.search import (
@@ -54,11 +62,13 @@ def production_geometry(nsamples: int, tsample_us: float, bank_path: str):
 
         bank = read_template_bank(bank_path)
         bank_P, bank_tau = bank.P, bank.tau
+        n_templates = len(bank_P)
     else:
         # shipped PALFA bank parameter ranges, for hosts without the
         # reference checkout (same bounds the bank would produce)
         bank_P = np.array([660.0, 2231.0])
         bank_tau = np.array([0.335, 0.0])
+        n_templates = PRODUCTION_BANK_SIZE
     geom = SearchGeometry.from_derived(
         derived,
         max_slope=max_slope_for_bank(bank_P, bank_tau),
@@ -74,46 +84,40 @@ def production_geometry(nsamples: int, tsample_us: float, bank_path: str):
         import dataclasses
 
         geom = dataclasses.replace(geom, ts_prescaled=False)
-    return geom, derived
+    return geom, n_templates
 
 
-def compile_step(geom, derived, batch: int, device):
-    """Lower + compile the production batched search step for ``device``
-    (a topology device) at ``batch``; returns the Compiled object."""
+def compile_step(geom, batch: int, device, n_templates: int = 8):
+    """Lower + compile the dispatched bank step (``make_bank_step``, as
+    ``run_bank`` builds it on a TPU: donated (M, T), row-major layouts
+    pinned for ``device``) at ``batch`` over an ``n_templates`` bank, for
+    ``device`` (a topology device); returns the Compiled object."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from boinc_app_eah_brp_tpu.models.search import (
+        bank_step_layouts,
         init_state,
-        make_batch_step,
-        prepare_ts,
-        template_params_host,
+        make_bank_step,
+        upload_bank,
     )
 
-    rng = np.random.default_rng(0)
-    ts = rng.uniform(0, 15, derived.n_unpadded).astype(np.float32)
-    ts_args = prepare_ts(geom, ts)
-    M, T = init_state(geom)
-    params = [
-        template_params_host(1000.0 + t, 0.01, 0.0, geom.dt)
-        for t in range(batch)
-    ]
-    bp = tuple(
-        jnp.asarray(np.array([p[i] for p in params], dtype=np.float32))
-        for i in range(4)
+    S = jax.ShapeDtypeStruct
+    bank = upload_bank(
+        tuple(np.zeros(n_templates, np.float32) for _ in range(4)), batch
     )
-
-    def ab(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype),
-            tree,
-        )
-
-    step = make_batch_step(geom)
+    n_ts = 2 if geom.parity_split else 1
+    ts = tuple(
+        S((geom.n_unpadded // n_ts,), jnp.float32) for _ in range(n_ts)
+    )
+    M, T = jax.eval_shape(lambda: init_state(geom))
+    in_sh, out_sh = bank_step_layouts(geom, False, device)
+    step = make_bank_step(geom, batch_size=batch).__wrapped__
     return (
-        jax.jit(step, device=device)
-        .lower(ab(ts_args), *ab(bp), jax.ShapeDtypeStruct((), np.int32),
-               *ab((M, T)))
+        jax.jit(step, donate_argnums=(7, 8), in_shardings=in_sh,
+                out_shardings=out_sh)
+        .lower(ts, *(S(a.shape, a.dtype) for a in bank), S((), jnp.int32),
+               S((), jnp.int32), M, T)
         .compile()
     )

@@ -11,9 +11,12 @@ backend's, the wisdom/sweep stages start warm; if it doesn't, the
 entries are simply never read — strictly harmless.
 
 Geometry and trace-time knobs mirror the live chain exactly (shared
-plumbing in ``tools/_aot_common.py``: production PALFA bank bounds,
-``ERP_FORCE_CASCADE=1`` so the CPU default backend doesn't lower the
-native-FFT program, the CPU backend so no chip is claimed).
+plumbing in ``tools/_aot_common.py``: production PALFA bank bounds and
+size, the dispatched ``make_bank_step`` with its donation and pinned
+layouts, ``ERP_FORCE_CASCADE=1`` so the CPU default backend doesn't lower
+the native-FFT program, the resident resampler chain and the fused fold
+lowered by Mosaic as on the chip, the CPU backend so no chip is
+claimed).
 
 Whether the cache key matches is no longer guesswork: ``--record-key``
 snapshots the cache entry names (the keys) that a LIVE backend warm run
@@ -149,7 +152,7 @@ def warm_specs(batches: list[int], nsamples: int, tsample_us: float,
     from boinc_app_eah_brp_tpu.runtime import health
     from boinc_app_eah_brp_tpu.runtime.scheduler import WarmSpec
 
-    geom, _derived = production_geometry(nsamples, tsample_us, bank_path)
+    geom, _ = production_geometry(nsamples, tsample_us, bank_path)
     kw: dict = {}
     if bank_path and os.path.exists(bank_path):
         from boinc_app_eah_brp_tpu.io.templates import read_template_bank
@@ -192,8 +195,8 @@ def main() -> int:
     ap = argparse.ArgumentParser(prog="aot_prewarm")
     ap.add_argument(
         "--batches", default="16,32,64",
-        help="comma list of batch sizes (default: the sweep rungs proven "
-        "HBM-feasible on v5e, AOT_HBM_r05.json)",
+        help="comma list of batch sizes (default: the sweep rungs that "
+        "fit a v5e's HBM)",
     )
     ap.add_argument("--topology", default="v5e:2x2")
     ap.add_argument("--nsamples", type=int, default=1 << 22)
@@ -229,7 +232,7 @@ def main() -> int:
 
     devs = topology_devices(args.topology)
     print(f"topology: {len(devs)} devices, compiling on {devs[0]}")
-    geom, derived = production_geometry(
+    geom, n_templates = production_geometry(
         args.nsamples, args.tsample_us, args.bank
     )
 
@@ -239,7 +242,7 @@ def main() -> int:
         before = _cache_entries(cache)
         t0 = time.time()
         try:
-            compile_step(geom, derived, batch, devs[0])
+            compile_step(geom, batch, devs[0], n_templates)
         except Exception as e:  # noqa: BLE001 - report and continue
             print(f"batch {batch}: AOT compile FAILED after "
                   f"{time.time() - t0:.1f}s: {type(e).__name__}: {str(e)[:300]}")

@@ -57,11 +57,6 @@ METRICS = {
     "mfu": ("mfu", True),
     "whitening_s": ("whiten s", False),
     "compile_first_batch_s": ("compile s", False),
-    # the compiler's own throughput ceiling (runtime/roofline.py from the
-    # newest COST_LEDGER row): falls when fusion/layout work cuts HBM
-    # traffic, so a drop here flags a ledger regression even when the
-    # measured t/s is backend-noisy
-    "compiler_bound_templates_per_sec": ("bound t/s", True),
 }
 
 

@@ -17,9 +17,7 @@ to the ``run_report`` line it carries (the last one, if the file holds
 several runs).  ``--check`` additionally recognizes flight-recorder
 crash dumps (``erp-blackbox/1``, ``runtime/flightrec.py``) and host span
 traces (``erp-trace/1`` JSONL streams and their Chrome exports,
-``runtime/tracing.py``), scope-attribution artifacts
-(``erp-hlo-attrib/1``, ``tools/hlo_attrib.py``), the cost ledger
-(``erp-cost-ledger/1``, ``tools/cost_ledger.py``), the watchdog's
+``runtime/tracing.py``), the watchdog's
 incident sidecar (``erp-incident-log/1``, ``runtime/watchdog.py`` —
 the memory behind poison-range quarantine) and the signed quorum
 verdicts the volunteer fabric emits per validation round
@@ -51,11 +49,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from boinc_app_eah_brp_tpu.fabric.validator import (  # noqa: E402
     QUORUM_SCHEMA,
     validate_quorum_verdict,
-)
-from boinc_app_eah_brp_tpu.runtime.devicecost import (  # noqa: E402
-    ATTRIB_SCHEMA,
-    validate_cost_ledger,
-    validate_hlo_attrib,
 )
 from boinc_app_eah_brp_tpu.runtime.flightrec import (  # noqa: E402
     SCHEMA as BLACKBOX_SCHEMA,
@@ -417,15 +410,6 @@ def main(argv: list[str] | None = None) -> int:
             if isinstance(doc, dict) and doc.get("schema") == BLACKBOX_SCHEMA:
                 errs = validate_dump(doc)
                 schema = BLACKBOX_SCHEMA
-            elif isinstance(doc, dict) and doc.get("schema") == ATTRIB_SCHEMA:
-                errs = validate_hlo_attrib(doc)
-                schema = ATTRIB_SCHEMA
-            elif (
-                isinstance(doc, dict)
-                and doc.get("schema") == "erp-cost-ledger/1"
-            ):
-                errs = validate_cost_ledger(doc)
-                schema = "erp-cost-ledger/1"
             elif (
                 isinstance(doc, dict)
                 and doc.get("schema") == INCIDENT_SCHEMA

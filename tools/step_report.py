@@ -1,12 +1,8 @@
 """Model-vs-measured step-time reconciliation: ``erp-step-report/1``.
 
-The cost model's half of the observatory is bytes-first: the AOT ledger
-(``COST_LEDGER.json``) gates HBM traffic per template and
 ``devicecost.stage_time_model`` turns a roofline into per-stage time
-FRACTIONS — but neither is a measured number, and ROADMAP item 1's
-"v5e bound ~218 t/s" has had no measured counterpart.  This tool closes
-the loop (tentpole d of the measured-time observatory,
-``docs/observability.md`` layer 10):
+FRACTIONS, which are not a measured number.  This tool joins them with
+measured step times (``docs/observability.md`` layer 10):
 
 1. **fresh measured run** (default): a chip-free fixture workunit
    (16-template bank, the 4096-sample soak geometry) runs through one
@@ -14,9 +10,8 @@ the loop (tentpole d of the measured-time observatory,
    with the ``runtime/steptime.py`` bracket force-armed, leaving an
    ``erp-steptime/1`` stream and in-memory per-window records;
 2. **join**: measured per-window step times are joined against the
-   roofline stage model and the newest committed ledger row — measured
-   vs modeled templates/s and GB/s, and a per-stage table ranked by
-   measured/modeled discrepancy.  Chip-free there is no device plane to
+   roofline stage model — measured vs modeled templates/s, and a
+   per-stage table ranked by measured/modeled discrepancy.  Chip-free there is no device plane to
    measure stages from, so the per-stage measured column is the
    measured window split by the model's fractions and the artifact says
    so (``device_lane: "modeled-split"``); with a chip,
@@ -56,8 +51,6 @@ from boinc_app_eah_brp_tpu.runtime.steptime import (  # noqa: E402
     REPORT_SCHEMA,
     validate_step_report,
 )
-
-LEDGER = os.path.join(REPO, "COST_LEDGER.json")
 
 # the soak fixture class (shared with tools/fleet_bench.py), widened to
 # a 16-template bank so one session yields 8 measured windows
@@ -111,16 +104,6 @@ def build_fixture(work: str, prefix: str = "wu"):
     )
 
 
-def newest_ledger_row(path: str = LEDGER) -> dict:
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-        rows = doc.get("rows") or []
-        return rows[-1] if rows else {}
-    except (OSError, ValueError):
-        return {}
-
-
 def measure(work: str) -> tuple[dict, list[dict], object, str]:
     """Fresh measured run: (steptime summary, per-window records, geom,
     backend).  The bracket is force-armed on the default context so the
@@ -169,8 +152,8 @@ def build_report(
     summary: dict, geom, backend: str, chip: str,
     capture_stage_ms: dict | None = None,
 ) -> dict:
-    """Join measured windows against the roofline stage model and the
-    newest ledger row into one ``erp-step-report/1`` document."""
+    """Join measured windows against the roofline stage model into one
+    ``erp-step-report/1`` document."""
     from boinc_app_eah_brp_tpu.runtime.devicecost import (
         ledger_stage,
         stage_time_model,
@@ -180,10 +163,6 @@ def build_report(
         geom.nsamples, geom.n_unpadded, geom.fund_hi, geom.harm_hi,
         max_slope=geom.max_slope, chip=chip,
     )
-    ledger = newest_ledger_row()
-    layout = ledger.get("layout_gb_per_template") or {}
-    gb_per_template = ledger.get("gb_per_template")
-
     windows = summary["windows"]
     templates = summary["templates"]
     tpw = templates / windows if windows else 0.0  # templates per window
@@ -204,8 +183,6 @@ def build_report(
             measured_ms = capture_stage_ms.get(row["scope"], 0.0) / windows
         else:
             measured_ms = mean_window_ms * row["fraction"]
-        bucket = ledger_stage(row["scope"])
-        gb = layout.get(bucket)
         stages.append(
             {
                 "stage": row["stage"],
@@ -217,20 +194,10 @@ def build_report(
                 "discrepancy": round(
                     measured_ms / modeled_ms, 2
                 ) if modeled_ms > 0 else 0.0,
-                "ledger_bucket": bucket,
-                "ledger_gb_per_template": gb,
-                "measured_gb_per_sec": round(
-                    gb * tpw / (measured_ms / 1e3), 3
-                ) if gb and measured_ms > 0 else None,
+                "bucket": ledger_stage(row["scope"]),
             }
         )
     stages.sort(key=lambda s: s["discrepancy"], reverse=True)
-
-    def _gbs(tps):
-        return (
-            round(gb_per_template * tps, 3)
-            if isinstance(gb_per_template, (int, float)) and tps else None
-        )
 
     return {
         "schema": REPORT_SCHEMA,
@@ -247,16 +214,12 @@ def build_report(
             "windows": windows,
             "templates": templates,
             "templates_per_sec": measured_tps,
-            "gb_per_sec": _gbs(measured_tps),
             "step_ms": summary["step_ms"],
         },
         "modeled": {
             "templates_per_sec": modeled_tps,
             "ms_per_template": round(model_ms_per_template, 4),
-            "gb_per_sec": _gbs(modeled_tps),
-            "gb_per_template": gb_per_template,
-            "source": f"COST_LEDGER.json {ledger.get('file', '?')} + "
-                      f"stage_time_model({chip})",
+            "source": f"stage_time_model({chip})",
         },
         "ratio_measured_to_modeled": round(
             modeled_tps / measured_tps, 2
@@ -275,8 +238,7 @@ def render(doc: dict) -> str:
         f"windows (p50 {m['step_ms']['p50']} ms, p95 {m['step_ms']['p95']} "
         f"ms)",
         f"modeled:  {mo['templates_per_sec']} t/s "
-        f"({mo['ms_per_template']} ms/template roofline; "
-        f"{mo['gb_per_sec']} GB/s at ledger bytes)",
+        f"({mo['ms_per_template']} ms/template roofline)",
         f"model-over-measured: x{doc['ratio_measured_to_modeled']}",
         "",
         f"{'stage':<18} {'bound':<5} {'model ms/win':>12} "
